@@ -9,7 +9,7 @@ read the delta vs the unmodified step. Deltas are additive up to scheduling
 effects; the all-stubbed floor bounds the elementwise + dispatch residue.
 
 Usage (from /root/repo): python benchmarks/ablate_step.py
-Knobs: BENCH_RESOURCES, BENCH_BATCH, BENCH_RULES, PROF_STEPS, BENCH_PLATFORM.
+Knobs: BENCH_RESOURCES, BENCH_BATCH, BENCH_RULES, PROF_STEPS.
 """
 
 from __future__ import annotations
@@ -29,8 +29,6 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 def main() -> None:
     import jax
 
-    if os.environ.get("BENCH_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["BENCH_PLATFORM"])
     import jax.numpy as jnp
 
     import sentinel_tpu.engine.pipeline as pl

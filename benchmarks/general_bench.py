@@ -22,12 +22,12 @@ Modes (env GENERAL_MODE):
   prio      all events PRIORITIZED (origin-free): the occupy-capable fast
             variant (rules/flow.flow_check_fast_occupy) — what the
             runtime now selects for whole-prio batches; pre-r6 this
-            demoted to the sorted path (the 16x cliff, BASELINE.md)
+            demoted to the sorted path (a whole-batch cliff)
   prio_mixed  1% prioritized, 99% origin-free scalar: the occupy-aware
             per-event split (occupy-base scalar step on the bulk + fast
             occupy step on the prioritized slice)
 Knobs: BENCH_RESOURCES, BENCH_BATCH, BENCH_STEPS, BENCH_RULES,
-BENCH_REPEATS, BENCH_PLATFORM.
+BENCH_REPEATS. The backend is JAX's own choice (``JAX_PLATFORMS``).
 
 Prints one JSON line like bench.py.
 """
@@ -603,8 +603,6 @@ def measure(jax, mode: str, R: int, B: int, STEPS: int, NRULES: int,
 def main() -> None:
     import jax
 
-    if os.environ.get("BENCH_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["BENCH_PLATFORM"])
     R = int(os.environ.get("BENCH_RESOURCES", str(1 << 20)))
     B = int(os.environ.get("BENCH_BATCH", str(1 << 19)))
     STEPS = int(os.environ.get("BENCH_STEPS", "30"))

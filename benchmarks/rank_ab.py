@@ -17,9 +17,8 @@ so an `oh @ counts` matvec would silently truncate):
     counts += colsum(oh)
 
 Plus an NK-free equality-matrix variant (``ranks_eqmat_scan``). Measured
-honestly (chained scans, one readback) at bench shapes; results + the
-wire/retire decision live in BASELINE.md. Knobs: RANK_N, RANK_NK,
-RANK_STEPS, BENCH_PLATFORM.
+as chained scans with one readback at bench shapes. Knobs: RANK_N,
+RANK_NK, RANK_STEPS.
 """
 
 from __future__ import annotations
@@ -92,8 +91,6 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
 
-    if os.environ.get("BENCH_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["BENCH_PLATFORM"])
     from sentinel_tpu.ops.segments import ranks_by_key
 
     N = int(os.environ.get("RANK_N", str(1 << 19)))

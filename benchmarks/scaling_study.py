@@ -3,11 +3,10 @@
 Sweeps B (events/step) x R (resource rows) on the current device with the
 same honest measurement discipline as bench.py (chained+donated steps, one
 readback before and after the timed region), and prints one JSON line per
-cell plus a final recommendation. The committed results (BASELINE.md) feed
-bench.py's per-platform default batch size.
+cell plus a final recommendation for bench.py's default batch size.
 
 Usage (from /root/repo): python benchmarks/scaling_study.py
-Knobs: SCALE_BS / SCALE_RS (comma lists), SCALE_STEPS, BENCH_PLATFORM.
+Knobs: SCALE_BS / SCALE_RS (comma lists), SCALE_STEPS.
 """
 
 from __future__ import annotations
@@ -117,8 +116,6 @@ def one_cell(R: int, B: int, steps: int) -> dict:
 
 def main() -> None:
     import jax
-    if os.environ.get("BENCH_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["BENCH_PLATFORM"])
     bs = [int(x) for x in os.environ.get(
         "SCALE_BS", "131072,262144,524288,1048576,2097152").split(",")]
     rs = [int(x) for x in os.environ.get(

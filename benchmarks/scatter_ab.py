@@ -2,8 +2,7 @@
 kernel (ops/pallas_kernels.py) across counter-table sizes.
 
 Run on the real TPU: ``python benchmarks/scatter_ab.py``. One JSON line per
-(backend, K, N) cell plus a winner summary — the committed results live in
-BASELINE.md (VERDICT r2 #5: wire or retire, with numbers).
+(backend, K, N) cell plus a winner summary.
 
 The shapes bracket the real tables: K=4k ≈ hot-param key table /
 cluster flow rows; K=64k-1M ≈ the main resource table (where the per-tile

@@ -351,7 +351,7 @@ def main() -> int:
         "env_knobs": env_knobs(),
         # round 11: did a SENTINEL_TUNED_CONFIG artifact apply, from
         # where, under which fingerprint, with which per-knob values —
-        # so a BASELINE.md row is reproducible off-machine
+        # so a result is reproducible off-machine
         "tuned_config": tuned_provenance(),
         "defaults": {"duration_ms": DEFAULT_DURATION_MS,
                      "rate_rps": DEFAULT_RATE, "seed": DEFAULT_SEED},

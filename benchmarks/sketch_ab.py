@@ -8,8 +8,7 @@ table, exactly the shape class the round-3 scatter A/B retired the
 Pallas kernel for — so the tiering manager commits to a formulation
 only on these measurements, not on intuition. Run on the real TPU:
 ``python benchmarks/sketch_ab.py``; one JSON line per (impl, bits,
-batch) cell plus a winner summary. Committed numbers live in
-BASELINE.md ("Sketch update A/B"); CPU numbers are recorded as such
+batch) cell plus a winner summary. CPU numbers are recorded as such
 and never extrapolated to TPU (PR 10 precedent).
 
 The shapes bracket the real deployment: bits 12–16 (4k–64k counters

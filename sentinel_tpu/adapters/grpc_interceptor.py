@@ -4,7 +4,7 @@
 Resource = full gRPC method name (``/package.Service/Method``). The server
 interceptor counts inbound entries (EntryType.IN) and aborts blocked calls
 with RESOURCE_EXHAUSTED (the reference returns UNAVAILABLE-with-message; 429
-maps to RESOURCE_EXHAUSTED in gRPC's status taxonomy). The client
+maps to RESOURCE_EXHAUSTED among gRPC's status codes). The client
 interceptor guards outbound calls (EntryType.OUT) and traces non-OK
 terminations into exception stats like the reference's
 ``ForwardingClientCallListener.onClose(status != OK)``.

@@ -90,11 +90,6 @@ class SentinelConfig:
     # observability (dashboard threadNum) at ~20% step-floor cost.
     thread_gauge_always: bool = False
 
-    # Persistent XLA compilation-cache directory (cold-start story,
-    # core/compile_cache.py). None/"" = the default
-    # ~/.cache/sentinel_tpu/xla; SENTINEL_COMPILE_CACHE=off disables.
-    compile_cache_dir: str = ""
-
     def __post_init__(self) -> None:
         if not 1 <= self.max_rules_per_resource <= 31:
             # the per-rule cluster-fallback mask is an int32 bitmask over

@@ -51,10 +51,7 @@ class PendingResult:
 
 def start_host_copy(arrays) -> None:
     """Kick off async device→host copies so a later ``np.asarray`` finds
-    the data already (or nearly) resident instead of paying the full RTT
-    at materialization time. Backends without async D2H just sync later."""
+    the data already (or nearly) resident instead of paying the full
+    transfer at materialization time."""
     for a in arrays:
-        try:
-            a.copy_to_host_async()
-        except (AttributeError, RuntimeError):
-            pass
+        a.copy_to_host_async()

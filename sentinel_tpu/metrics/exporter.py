@@ -25,7 +25,7 @@ Self-telemetry families (from ``Sentinel.obs`` — obs/; absent while
     sentinel_split_route_total{route=...}  dispatch-path decisions
     sentinel_compile_cache_hits_total      program-fetch cache hits
     sentinel_compile_cache_misses_total
-    sentinel_compile_cache_first_fetch_retries_total
+    sentinel_compile_cache_first_fetch_retries_total  retired: always 0
     sentinel_block_reason_total{reason=...} denials by verdict code name
     sentinel_occupy_bookings_total{event=...} granted/carried/settled/evicted
     sentinel_pipeline_total{event=...}     depth/stall/leaked_handles/
@@ -137,7 +137,7 @@ class SentinelCollector:
             "Decide-program fetch cache misses (first dispatches)")
         retries = CounterMetricFamily(
             f"{ns}_compile_cache_first_fetch_retries",
-            "Guarded first-fetch stall retries")
+            "Retired (the first-fetch guard is gone): always 0")
         blocks = CounterMetricFamily(
             f"{ns}_block_reason",
             "Denials by verdict reason name", labels=["reason"])

@@ -4,13 +4,16 @@ The device compute path is JAX/XLA; this package holds the host-side pieces
 where Python-level overhead caps throughput — currently the string-interning
 registry feeding resource names into the batched device step (SURVEY §7 hard
 part 5). Everything here has a pure-Python fallback: the native library is
-compiled on demand with g++ (no pip installs) and cached next to its source;
+compiled on demand with g++ (no pip installs) and cached next to its source
+under a name that carries a hash of that source, so a binary built from
+another revision of ``registry.cpp`` can never load;
 ``SENTINEL_TPU_NATIVE=0`` disables it.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,26 +23,34 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 _SRC = Path(__file__).parent / "src" / "registry.cpp"
-_LIB = Path(__file__).parent / "src" / "_sentinel_native.so"
+
+
+def _lib_path() -> Path:
+    """``_sentinel_native.<sha12 of registry.cpp>.so`` beside the source."""
+    sha = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:12]
+    return _SRC.with_name(f"_sentinel_native.{sha}.so")
+
 
 _lib_handle = None
 _lib_lock = threading.Lock()
 
 
 def _build() -> Optional[Path]:
-    """Compile the shared library if missing/stale; None on failure.
-    Compiles to a per-pid temp path and renames into place so concurrent
-    processes never load a half-written ELF."""
+    """Compile the shared library unless the one for this source hash is
+    already there; None on failure. Compiles to a per-pid temp path and
+    renames into place so concurrent processes never load a half-written
+    ELF."""
     try:
-        if _LIB.exists() and _LIB.stat().st_mtime >= _SRC.stat().st_mtime:
-            return _LIB
-        tmp = _LIB.with_suffix(f".{os.getpid()}.tmp.so")
+        lib = _lib_path()
+        if lib.exists():
+            return lib
+        tmp = lib.with_suffix(f".{os.getpid()}.tmp.so")
         subprocess.run(
             ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
              str(_SRC), "-o", str(tmp)],
             check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _LIB)          # atomic on POSIX
-        return _LIB
+        os.replace(tmp, lib)           # atomic on POSIX
+        return lib
     except (OSError, subprocess.SubprocessError):
         return None
 
@@ -247,10 +258,10 @@ class NativeRegistry:
             buflen = -n
         out = []
         off = 0
+        raw = buf.raw       # once: every .raw access copies the whole buffer
         for i in range(n):
             ln = int(lens[i])
-            out.append((buf.raw[off:off + ln].decode("utf-8"),
-                        int(ids[i])))
+            out.append((raw[off:off + ln].decode("utf-8"), int(ids[i])))
             off += ln
         return out
 

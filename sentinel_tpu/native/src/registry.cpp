@@ -9,7 +9,8 @@
 // C ABI only (loaded via ctypes): no CPython API, so the GIL is naturally
 // released for the duration of every call made through ctypes.
 //
-// Build: g++ -O2 -std=c++17 -shared -fPIC registry.cpp -o _sentinel_native.so
+// Build (native/__init__.py does it, and names the output by source hash):
+//   g++ -O2 -std=c++17 -shared -fPIC registry.cpp -o _sentinel_native.<sha12>.so
 
 #include <cstdint>
 #include <cstring>

@@ -66,13 +66,9 @@ def trace_sample_rate() -> float:
 
 def trace_annotation(name: str):
     """A ``jax.profiler.TraceAnnotation`` context (names the enclosed
-    dispatch in profiler/XProf timelines), or a no-op context when the
-    profiler surface is unavailable."""
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    except Exception:   # pragma: no cover - profiler-less jax build
-        return _NULL_CTX
+    dispatch in profiler/XProf timelines)."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
 
 
 class RuntimeObs:

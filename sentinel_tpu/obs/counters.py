@@ -21,8 +21,9 @@ dispatch stays within the 2% observability budget (benchmarks/ci_gate.py
   whose step fell back to the sorted branch (ops/sortfree.py); sustained
   growth means the bucket table is undersized for the key distribution.
 * ``compile_cache.*`` — first-dispatch program accounting per (variant,
-  geometry, statics) combo: ``hit`` / ``miss`` /
-  ``first_fetch_retry`` (the guarded-fetch stall retries).
+  geometry, statics) combo: ``hit`` / ``miss``. ``first_fetch_retry`` is
+  retired (nothing ticks it); it keeps its catalog slot because the
+  wire-order manifest is append-only.
 * ``occupy.*`` — priority booking lifecycle: ``granted`` (PriorityWait
   admissions), ``carried`` / ``settled`` (bookings surviving /
   landing at rule reload), ``evicted`` (cleared by row eviction).
@@ -87,7 +88,7 @@ ROUTE_SPLIT = "split_route.split_fired"
 
 CACHE_HIT = "compile_cache.hit"
 CACHE_MISS = "compile_cache.miss"
-CACHE_RETRY = "compile_cache.first_fetch_retry"
+CACHE_RETRY = "compile_cache.first_fetch_retry"    # retired, slot kept
 
 OCCUPY_GRANTED = "occupy.granted"
 OCCUPY_CARRIED = "occupy.carried"

@@ -57,7 +57,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from sentinel_tpu.core.registry import ENTRY_NODE_ROW
@@ -66,21 +66,6 @@ from sentinel_tpu.obs import resource_hist
 from sentinel_tpu.stats import events as ev
 from sentinel_tpu.stats import window
 from sentinel_tpu.parallel.local_shard import MESH_AXIS, topk_layout
-
-try:  # jax >= 0.6 exposes shard_map at top level (kwarg: check_vma)
-    from jax import shard_map as _shard_map_impl
-    _SM_CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover — older jax (kwarg: check_rep)
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _SM_CHECK_KW = "check_rep"
-
-
-def _shard_map(body, *, mesh, in_specs, out_specs, check_vma=True):
-    """shard_map across jax versions: ``check_vma`` (≥ 0.6) and its
-    predecessor ``check_rep`` are the same switch under different names."""
-    return _shard_map_impl(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs,
-                           **{_SM_CHECK_KW: check_vma})
 
 
 TELEMETRY_K_ENV = "SENTINEL_TELEMETRY_K"
@@ -152,8 +137,8 @@ def _sharded_topk(load: jnp.ndarray, k: int, mesh,
         mv, mi = lax.top_k(vals.reshape(-1), k)
         return mv, rows.reshape(-1)[mi]
 
-    return _shard_map(body, mesh=mesh, in_specs=P(MESH_AXIS),
-                      out_specs=(P(), P()), check_vma=False)(load)
+    return shard_map(body, mesh=mesh, in_specs=P(MESH_AXIS),
+                     out_specs=(P(), P()), check_vma=False)(load)
 
 
 def telemetry_tick(second_spec: window.WindowSpec,

@@ -10,9 +10,9 @@ matmul accumulation on the MXU::
 tiled over (K, N) grid cells with VMEM one-hots and ``jnp.dot``
 accumulation — no atomics, deterministic (SURVEY §2.8.1 → §7 Phase 1).
 
-**Measured outcome (round 3, real v5 lite chip, honest-mode timing — see
-BASELINE.md "Scatter A/B"): XLA's native scatter wins at every product
-shape**, 1.2× at K=1k-4k and up to 55× at K=1M, because each K-tile of the
+**Measured outcome (round 3, one v5 lite chip; the figures themselves now
+live in git history): XLA's native scatter won at every product
+shape**, by more the larger K, because each K-tile of the
 one-hot kernel must scan the whole event stream (O(K/tile · N) MACs vs
 XLA's O(N)). :func:`scatter_add` therefore dispatches to XLA everywhere;
 the kernel stays as a tested reference implementation and the benchmark
@@ -142,8 +142,8 @@ def scatter_add_pallas(counters: jnp.ndarray, keys: jnp.ndarray,
 def scatter_add(counters: jnp.ndarray, keys: jnp.ndarray,
                 events: jnp.ndarray, amounts: jnp.ndarray) -> jnp.ndarray:
     """Backend dispatch — currently XLA scatter on every backend: the
-    round-3 A/B on real TPU hardware (BASELINE.md "Scatter A/B") measured
-    XLA ahead at all product shapes, so the MXU kernel is not selected.
+    round-3 A/B on real TPU hardware measured XLA ahead at all product
+    shapes, so the MXU kernel is not selected.
     Kept as the dispatch seam so a future measurement can flip it
     per-shape without touching callers."""
     return scatter_add_xla(counters, keys, events, amounts)

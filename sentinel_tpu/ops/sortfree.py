@@ -2,8 +2,8 @@
 
 The general admission path's only superlinear stage is the composite-key
 sort that groups (rule, stat-row) pairs into segments
-(``ops/segments.py`` ``sort_by_keys`` — n·log n, ~11 ms of the 40.5 ms
-general step at B=512k per BASELINE.md's round-5 ablation). Everything
+(``ops/segments.py`` ``sort_by_keys`` — n·log n; its share of the general
+step on a host-attached chip: not measured). Everything
 downstream of the sort — prefix sums, greedy fixed point, unsorts — is
 linear. This module removes the sort:
 
@@ -41,10 +41,6 @@ linear. This module removes the sort:
    ``flow_check_scalar``'s parity contract), so segment ORDER cannot
    change any admitted bit.
 
-The bucket histograms ride :func:`ops.pallas_kernels.scatter_add` (the
-XLA-scatter/Pallas-tile dispatch seam), so a future TPU measurement can
-move them onto the MXU tile kernel without touching callers.
-
 Env knobs: ``SENTINEL_SORTFREE`` (runtime routing — see runtime.py),
 ``SENTINEL_SORTFREE_BITS`` (claim-table size override, mainly for the
 collision-forcing tests), ``SENTINEL_SORTFREE_CHUNK`` (scan chunk).
@@ -58,8 +54,6 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from sentinel_tpu.ops.pallas_kernels import scatter_add
 
 # Claim rounds: 3 independent hashes drive the per-key settle probability
 # low enough that overflow is a counter-visible rarity at the default
@@ -208,13 +202,10 @@ def build_key_plan(key: jnp.ndarray, sentinel_mask: jnp.ndarray,
 
 
 def bucket_histogram(bucket: jnp.ndarray, num_buckets: int) -> jnp.ndarray:
-    """Per-bucket element counts → int32[num_buckets], through the
-    :func:`ops.pallas_kernels.scatter_add` dispatch seam (single event
-    lane)."""
-    counters = jnp.zeros((num_buckets, 1), jnp.int32)
-    events = jnp.zeros(bucket.shape, jnp.int32)
-    ones = jnp.ones(bucket.shape, jnp.int32)
-    return scatter_add(counters, bucket, events, ones)[:, 0]
+    """Per-bucket element counts → int32[num_buckets]; out-of-range
+    buckets (>= num_buckets, e.g. padding) are dropped."""
+    return jnp.zeros((num_buckets,), jnp.int32).at[bucket].add(
+        1, mode="drop")
 
 
 def scatter_ranks(bucket: jnp.ndarray, num_buckets: int,

@@ -41,23 +41,8 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at top level (kwarg: check_vma)
-    from jax import shard_map as _shard_map_impl
-    _SM_CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover — older jax (kwarg: check_rep)
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _SM_CHECK_KW = "check_rep"
-
-
-def _shard_map(body, *, mesh, in_specs, out_specs, check_vma=True):
-    """shard_map across jax versions: ``check_vma`` (≥ 0.6) and its
-    predecessor ``check_rep`` are the same switch under different names."""
-    return _shard_map_impl(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs,
-                           **{_SM_CHECK_KW: check_vma})
 
 from sentinel_tpu.core.pending import PendingResult, start_host_copy
 from sentinel_tpu.ops import segments as seg
@@ -470,7 +455,7 @@ class ClusterEngine:
             params=WindowState(*([row_spec] * 4)))
         table_specs = ClusterRuleTable(*([row_spec] * 6))
         batch_specs = TokenBatch(*([row_spec] * 7))
-        sm = _shard_map(
+        sm = shard_map(
             body, mesh=mesh,
             in_specs=(table_specs, state_specs, batch_specs, P(), P(), P(), P()),
             out_specs=(state_specs, TokenVerdicts(row_spec, row_spec, row_spec)),

@@ -1,9 +1,9 @@
 """Depth-k dispatch pipelining over the runtime's nowait tier.
 
 The synchronous serving loop — ``entry_batch_nowait(...).result()`` per
-step — pays the full host dispatch cost (~2.4 ms measured floor,
-BENCH_r05) on every batch: the host prepares batch N, dispatches it,
-then idles until N's verdicts materialize before touching N+1.
+step — pays the full host dispatch cost on every batch (the floor on a
+host-attached chip: not measured): the host prepares batch N, dispatches
+it, then idles until N's verdicts materialize before touching N+1.
 :class:`DispatchPipeline` keeps up to ``depth`` batches in flight:
 ``submit`` dispatches batch N+1 while N still runs on device and
 settles N-k only when the window is full, so the host's prep/dispatch
@@ -30,6 +30,7 @@ row-sharded over a mesh) counters. Knob: ``SENTINEL_PIPELINE_DEPTH``
 from __future__ import annotations
 
 import collections
+import logging
 import threading
 from typing import Optional
 
@@ -39,6 +40,7 @@ from sentinel_tpu.runtime import (   # noqa: F401 - re-exported knob
 )
 
 _MISSING = object()
+_log = logging.getLogger("sentinel_tpu.serving")
 
 
 class PipelinedVerdicts:
@@ -265,6 +267,9 @@ class CadenceScheduler:
         self._poll_s = max(0.02, min(self._tel_ms, self._tier_ms) / 2000.0)
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        #: polls the daemon survived by catching an exception (each one
+        #: is logged; a healthy engine keeps this at 0)
+        self.errors = 0
         reg = getattr(sentinel, "register_shutdown", None)
         if reg is not None:
             reg(self)
@@ -311,7 +316,8 @@ class CadenceScheduler:
                 try:
                     self.poll()
                 except Exception:  # pragma: no cover — keep daemon alive
-                    pass
+                    self.errors += 1
+                    _log.exception("cadence poll failed")
 
         self._thread = threading.Thread(target=loop, daemon=True,
                                         name="sentinel-cadence")

@@ -16,7 +16,7 @@ table. Three implementations of the identical math live behind
 (masked one-hot reduce-max, the MXU-shaped candidate) and ``segment``
 (``jax.ops.segment_max``) — and ``benchmarks/sketch_ab.py`` times them
 on the real device before a kernel is committed. On every shape
-measured so far the XLA scatter path wins (BASELINE.md round 15), so
+measured so far (CPU only) the XLA scatter path wins, so
 ``DEFAULT_IMPL = "scatter"`` and no Pallas kernel ships; the seam stays
 so a future chip profile can flip one string.
 

@@ -167,8 +167,8 @@ def resolve_startup(spec=None, mesh=None, environ=None):
 def provenance(spec=None, mesh=None, environ=None) -> Dict:
     """The bench-artifact provenance block (round-11 satellite): did a
     tuned config apply, from where, under which fingerprint, and which
-    per-knob values — so a BASELINE.md row is reproducible without the
-    machine it ran on."""
+    per-knob values — so a result is reproducible without the machine
+    it ran on."""
     env = os.environ if environ is None else environ
     path = env.get(TUNED_CONFIG_ENV, "")
     block: Dict = {"tuned": False, "artifact": path or None}

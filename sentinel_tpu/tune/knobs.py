@@ -227,9 +227,8 @@ OPERATIONAL_ENVS: Dict[str, Optional[type]] = {
     "SENTINEL_CONTROL_DISABLE": None,
     "SENTINEL_TIERING_DISABLE": None,
     "SENTINEL_TIER_COLD_MAX": int,
-    "SENTINEL_FIRST_LOAD_TIMEOUT_S": float,
-    "SENTINEL_FIRST_LOAD_RETRIES": int,
-    "SENTINEL_COMPILE_CACHE": None,
+    "SENTINEL_COMPILE_CACHE": None,     # "off" only; the directory is
+    # JAX_COMPILATION_CACHE_DIR's to place (core/compile_cache.py)
     "SENTINEL_INIT_MODE": None,
     "SENTINEL_INIT_WAIT_TIMEOUT_S": float,
     "SENTINEL_COORDINATOR": None,

@@ -127,3 +127,18 @@ def test_very_long_names_roundtrip():
     rid = r.get_or_create(long_name)
     assert r.name_of(rid) == long_name
     assert dict(r.items())[long_name] == rid
+
+
+def test_items_is_linear_in_the_table():
+    """At the 1M-row product geometry ``items()`` runs on every telemetry
+    drain. It used to re-copy the whole name buffer per entry (ctypes
+    ``.raw`` inside the loop) — quadratic: minutes at 100k names, never
+    finishing at 1M. Linear is a fraction of a second here."""
+    import time
+    n = 100_000
+    r = NativeRegistry(n)
+    r.get_or_create_batch([f"resource-name-{i}" for i in range(n)])
+    t0 = time.perf_counter()
+    items = r.items()
+    assert time.perf_counter() - t0 < 10.0
+    assert len(items) == n and len(dict(items)) == n
