@@ -30,6 +30,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from sentinel_tpu.cluster import codec
 from sentinel_tpu.core.clock import Clock
+from sentinel_tpu.obs import counters as obs_keys
 from sentinel_tpu.parallel.cluster import (
     ClusterEngine, ClusterFlowRule, ClusterParamFlowRule,
 )
@@ -104,9 +105,9 @@ class ClusterTokenServer:
         # transport-config rollback would otherwise signal the wrong loop)
         self._epoch = 0
         self._state_lock = threading.Lock()
-        # micro-batch queues: (request, conn, future-resolution callback)
-        self._flow_q: List[Tuple[codec.Request, _Conn]] = []
-        self._param_q: List[Tuple[codec.Request, _Conn]] = []
+        # micro-batch queues: (request, conn, perf_counter_ns when queued)
+        self._flow_q: List[Tuple[codec.Request, _Conn, int]] = []
+        self._param_q: List[Tuple[codec.Request, _Conn, int]] = []
         self._q_event: Optional[asyncio.Event] = None
 
     # ------------------------------------------------------------------
@@ -327,10 +328,10 @@ class ClusterTokenServer:
                 req.xid, t, codec.RESPONSE_STATUS_OK,
                 len(self._ns_conns.get(ns, ()))))
         elif t == codec.MSG_TYPE_FLOW:
-            self._flow_q.append((req, conn))
+            self._flow_q.append((req, conn, time.perf_counter_ns()))
             self._q_event.set()
         elif t == codec.MSG_TYPE_PARAM_FLOW:
-            self._param_q.append((req, conn))
+            self._param_q.append((req, conn, time.perf_counter_ns()))
             self._q_event.set()
         elif t == codec.MSG_TYPE_CONCURRENT_FLOW_ACQUIRE:
             flow_id, count, _prio = req.data
@@ -357,6 +358,13 @@ class ClusterTokenServer:
     # ------------------------------------------------------------------
 
     async def _batch_loop(self) -> None:
+        """One cycle: collect for a batching window, decide the flow and
+        the param requests in one engine call each, answer. The cycle's
+        phases (``server.collect`` / ``.step`` / ``.respond``) and
+        counters record into ``engine.obs``, per cycle, never per
+        request."""
+        obs = self.engine.obs
+        t_idle = obs.spans.now_ns()
         while True:
             await self._q_event.wait()
             # collect for one batching window, then decide in one device step
@@ -366,30 +374,44 @@ class ClusterTokenServer:
             flow_q, self._flow_q = self._flow_q, []
             param_q, self._param_q = self._param_q, []
             now_ms = self.clock.now_ms()
+            if obs.enabled:
+                t_take = time.perf_counter_ns()     # the stamps' clock
+                taken = len(flow_q) + len(param_q)
+                # a server with nothing to do is idle by definition: a
+                # span, and no annotation to name the device's gap
+                obs.spans.record(obs.request_trace(), "server.collect",
+                                 t_idle, obs.spans.now_ns(), n=taken)
+                obs.counters.add(obs_keys.CLUSTER_SERVER_CYCLES)
+                obs.counters.add(obs_keys.CLUSTER_SERVER_TAKEN, taken)
+                obs.counters.add(
+                    obs_keys.CLUSTER_SERVER_QUEUE_WAIT_US,
+                    sum(t_take - t for q in (flow_q, param_q)
+                        for _, _, t in q) // 1000)
             if flow_q:
-                reqs = [r for r, _ in flow_q]
-                res = await asyncio.to_thread(
-                    self.engine.request_tokens,
-                    [r.data[0] for r in reqs], [r.data[1] for r in reqs],
-                    [r.data[2] for r in reqs], now_ms=now_ms)
-                for (req, conn), (status, wait_ms, remaining) in zip(flow_q, res):
-                    self.stat_log.log(f"flow-{req.data[0]}",
-                                      "pass" if status in (0, 2) else "block",
-                                      origin=conn.namespace or "")
-                    await self._send(conn, codec.Response(
-                        req.xid, req.type, status, (remaining, wait_ms)))
+                await self._decide_and_respond(
+                    flow_q, self.engine.request_tokens, "flow", now_ms)
             if param_q:
-                reqs = [r for r, _ in param_q]
-                res = await asyncio.to_thread(
-                    self.engine.request_param_tokens,
-                    [r.data[0] for r in reqs], [r.data[1] for r in reqs],
-                    [r.data[2] for r in reqs], now_ms=now_ms)
-                for (req, conn), (status, wait_ms, remaining) in zip(param_q, res):
-                    self.stat_log.log(f"param-{req.data[0]}",
-                                      "pass" if status in (0, 2) else "block",
-                                      origin=conn.namespace or "")
-                    await self._send(conn, codec.Response(
-                        req.xid, req.type, status, (remaining, wait_ms)))
+                await self._decide_and_respond(
+                    param_q, self.engine.request_param_tokens, "param",
+                    now_ms)
+            t_idle = obs.spans.now_ns()
+
+    async def _decide_and_respond(self, queue, decide, kind: str,
+                                  now_ms: int) -> None:
+        obs = self.engine.obs
+        reqs = [r for r, _, _ in queue]
+        with obs.phase("server.step", n=len(reqs)):    # thread hop included
+            res = await asyncio.to_thread(
+                decide, [r.data[0] for r in reqs], [r.data[1] for r in reqs],
+                [r.data[2] for r in reqs], now_ms=now_ms)
+        with obs.phase("server.respond", n=len(reqs)):
+            for (req, conn, _), (status, wait_ms, remaining) in zip(queue,
+                                                                  res):
+                self.stat_log.log(f"{kind}-{req.data[0]}",
+                                  "pass" if status in (0, 2) else "block",
+                                  origin=conn.namespace or "")
+                await self._send(conn, codec.Response(
+                    req.xid, req.type, status, (remaining, wait_ms)))
 
     async def _sweep_loop(self) -> None:
         """RegularExpireStrategy: reclaim expired concurrent leases."""

@@ -90,7 +90,8 @@ def active_cache_dir() -> Optional[str]:
     return jax.config.jax_compilation_cache_dir
 
 
-def program_key(kind: str, step_id: int, geometry, statics: Mapping) -> tuple:
+def program_key(kind: str, step_id: int, geometry, statics: Mapping,
+                columns=()) -> tuple:
     """Hashable identity of one compiled program variant for first-dispatch
     bookkeeping (``Sentinel._fetched_programs`` / ``compile_cache.hit`` /
     ``.miss`` counters).
@@ -100,6 +101,9 @@ def program_key(kind: str, step_id: int, geometry, statics: Mapping) -> tuple:
     (rule reload, geometry change) key fresh; ``geometry`` is the padded
     batch-shape tuple (one entry for decide, ``(b_entry, b_exit)`` for
     the fused decide+exit program); ``statics`` the static-arg flags the
-    variant was specialized on."""
+    variant was specialized on; ``columns`` which of the batch's optional
+    columns are present (``None`` or an array is part of the pytree
+    structure jit specializes on: two batches that differ only there are
+    two programs)."""
     return (kind, int(step_id), tuple(geometry),
-            tuple(sorted(statics.items())))
+            tuple(sorted(statics.items())), tuple(columns))

@@ -305,6 +305,15 @@ def _stat_targets(spec: EngineSpec, rows, origin_rows, chain_rows, valid,
             jnp.concatenate([alt_o, alt_c]))
 
 
+def _scoped(name: str, fn, *args, **kwargs):
+    """``fn(*args, **kwargs)`` under ``jax.named_scope(name)``: the stage's
+    name lands in every HLO operation's ``op_name`` (metadata only, no
+    run-time cost), so a device trace or the compiled text says which
+    stage owns an operation."""
+    with jax.named_scope(name):
+        return fn(*args, **kwargs)
+
+
 def decide_entries(
     spec: EngineSpec,
     rules: RuleSet,
@@ -427,22 +436,20 @@ def decide_entries(
         deg_bk = jnp.where(in_r, joint[:, Kf:], NDs)
     sf_ovf = jnp.int32(0)
     if scalar_flow:
-        flow_dyn, flow_ok, wait_ms = flow_mod.flow_check_scalar(
-            rules.flow_table, state.flow_dyn, rules.flow_idx, spec.second,
-            state.second, state.threads, batch.rows, batch.acquire, live2,
-            now_idx_s, rel_now_ms,
-            minute_spec=spec.minute,
+        flow_dyn, flow_ok, wait_ms = _scoped(
+            "decide.flow", flow_mod.flow_check_scalar, rules.flow_table,
+            state.flow_dyn, rules.flow_idx, spec.second, state.second,
+            state.threads, batch.rows, batch.acquire, live2, now_idx_s,
+            rel_now_ms, minute_spec=spec.minute,
             main_minute=state.minute if spec.minute else None,
-            now_idx_m=now_idx_m,
-            has_rate_limiter=scalar_has_rl,
-            rules_bk=flow_bk,
-            occupy_base=enable_occupy,
-            sortfree=sortfree)
+            now_idx_m=now_idx_m, has_rate_limiter=scalar_has_rl,
+            rules_bk=flow_bk, occupy_base=enable_occupy, sortfree=sortfree)
         occupied = jnp.zeros_like(flow_ok)
         live3 = live2 & flow_ok
-        breakers, deg_ok = deg_mod.degrade_entry_check_scalar(
-            rules.deg_table, state.breakers, rules.deg_idx, batch.rows,
-            live3, rel_now_ms, rules_bk=deg_bk)
+        breakers, deg_ok = _scoped(
+            "decide.degrade", deg_mod.degrade_entry_check_scalar,
+            rules.deg_table, state.breakers, rules.deg_idx, batch.rows, live3,
+            rel_now_ms, rules_bk=deg_bk)
     elif fast_flow:
         # fast general path: per-pair origin/row selection stays live, the
         # admission machinery collapses to rank closed forms; the degrade
@@ -457,19 +464,16 @@ def decide_entries(
         if enable_occupy:
             fn_occ = (flow_mod.flow_check_fast_occupy_sortfree if sortfree
                       else flow_mod.flow_check_fast_occupy)
-            out = fn_occ(
-                rules.flow_table, state.flow_dyn, rules.flow_idx,
-                spec.second, state.second, state.alt_second,
-                state.threads, state.alt_threads, fview, now_idx_s,
-                rel_now_ms,
+            out = _scoped(
+                "decide.flow", fn_occ, rules.flow_table, state.flow_dyn,
+                rules.flow_idx, spec.second, state.second, state.alt_second,
+                state.threads, state.alt_threads, fview, now_idx_s, rel_now_ms,
                 minute_spec=spec.minute,
                 main_minute=state.minute if spec.minute else None,
-                now_idx_m=now_idx_m,
-                in_win_ms=in_win_ms,
+                now_idx_m=now_idx_m, in_win_ms=in_win_ms,
                 occupy_timeout_ms=spec.occupy_timeout_ms,
                 has_rate_limiter=scalar_has_rl,
-                has_thread_rules=not skip_threads,
-                rules_bk=flow_bk)
+                has_thread_rules=not skip_threads, rules_bk=flow_bk)
             if sortfree:
                 flow_dyn, flow_ok, wait_ms, occupied, sf_ovf = out
             else:
@@ -477,16 +481,14 @@ def decide_entries(
         else:
             fn_plain = (flow_mod.flow_check_fast_sortfree if sortfree
                         else flow_mod.flow_check_fast)
-            out = fn_plain(
-                rules.flow_table, state.flow_dyn, rules.flow_idx, spec.second,
-                state.second, state.alt_second, state.threads,
-                state.alt_threads, fview, now_idx_s, rel_now_ms,
+            out = _scoped(
+                "decide.flow", fn_plain, rules.flow_table, state.flow_dyn,
+                rules.flow_idx, spec.second, state.second, state.alt_second,
+                state.threads, state.alt_threads, fview, now_idx_s, rel_now_ms,
                 minute_spec=spec.minute,
                 main_minute=state.minute if spec.minute else None,
-                now_idx_m=now_idx_m,
-                has_rate_limiter=scalar_has_rl,
-                has_thread_rules=not skip_threads,
-                rules_bk=flow_bk)
+                now_idx_m=now_idx_m, has_rate_limiter=scalar_has_rl,
+                has_thread_rules=not skip_threads, rules_bk=flow_bk)
             if sortfree:
                 flow_dyn, flow_ok, wait_ms, sf_ovf = out
             else:
@@ -495,7 +497,8 @@ def decide_entries(
         live3 = live2 & flow_ok
         # occupied (PriorityWait) events bypass the degrade slot — see the
         # general branch below
-        breakers, deg_ok = deg_mod.degrade_entry_check_scalar(
+        breakers, deg_ok = _scoped(
+            "decide.degrade", deg_mod.degrade_entry_check_scalar,
             rules.deg_table, state.breakers, rules.deg_idx, batch.rows,
             live3 & ~occupied, rel_now_ms, rules_bk=deg_bk)
         deg_ok = deg_ok | occupied
@@ -509,17 +512,15 @@ def decide_entries(
             prioritized=batch.prioritized, cluster_fallback=cl_fb)
         fcheck = (flow_mod.flow_check_sortfree if sortfree
                   else flow_mod.flow_check)
-        out = fcheck(
-            rules.flow_table, state.flow_dyn, rules.flow_idx, spec.second,
-            state.second, state.alt_second, state.threads, state.alt_threads,
-            fview, now_idx_s, rel_now_ms,
+        out = _scoped(
+            "decide.flow", fcheck, rules.flow_table, state.flow_dyn,
+            rules.flow_idx, spec.second, state.second, state.alt_second,
+            state.threads, state.alt_threads, fview, now_idx_s, rel_now_ms,
             minute_spec=spec.minute,
             main_minute=state.minute if spec.minute else None,
-            now_idx_m=now_idx_m,
-            in_win_ms=in_win_ms,
+            now_idx_m=now_idx_m, in_win_ms=in_win_ms,
             occupy_timeout_ms=spec.occupy_timeout_ms,
-            enable_occupy=enable_occupy,
-            has_thread_rules=not skip_threads)
+            enable_occupy=enable_occupy, has_thread_rules=not skip_threads)
         if sortfree:
             flow_dyn, flow_ok, wait_ms, occupied, sf_ovf = out
         else:
@@ -529,9 +530,10 @@ def decide_entries(
         # occupied (PriorityWait) events bypass the degrade slot entirely —
         # in the reference the PriorityWaitException aborts the slot chain
         # before DegradeSlot.entry runs, and the booking is already committed
-        breakers, deg_ok = deg_mod.degrade_entry_check(
-            rules.deg_table, state.breakers, rules.deg_idx, batch.rows,
-            live3 & ~occupied, rel_now_ms)
+        breakers, deg_ok = _scoped(
+            "decide.degrade", deg_mod.degrade_entry_check, rules.deg_table,
+            state.breakers, rules.deg_idx, batch.rows, live3 & ~occupied,
+            rel_now_ms)
         deg_ok = deg_ok | occupied
 
     # ---- user DeviceSlots (slot-chain SPI analog; STATIC: compiles to
@@ -615,7 +617,9 @@ def decide_entries(
         jnp.sum(jnp.where(blocked_rec & ein, acq, 0)))
 
     if spec.second.buckets >= 2:
-        second = refresh_all(spec.second, state.second, now_idx_s)
+        second = _scoped(
+            "decide.refresh.second", refresh_all, spec.second, state.second,
+            now_idx_s)
     else:   # B=1: full restamp would erase untouched rows' prev window
         # ENTRY joins the refresh list only when this batch actually lands
         # something on it — an idle/all-outbound batch restamping ENTRY
@@ -624,14 +628,15 @@ def decide_entries(
         # all-zero vector on the unrefreshed bucket is a no-op.
         entry_refresh = jnp.where(jnp.any(entry_vec != 0),
                                   jnp.int32(ENTRY_NODE_ROW), pad_r)
-        second = refresh_rows(
-            spec.second, state.second,
-            jnp.concatenate([main_rec1, entry_refresh[None]]),
-            now_idx_s)
-    second = add_rows_multi(spec.second, second, main_rec1, ev_ids1,
-                            rec_amt1, now_idx_s)
-    second = add_one_row(spec.second, second, ENTRY_NODE_ROW, entry_vec,
-                         now_idx_s)
+        second = _scoped(
+            "decide.refresh.second", refresh_rows, spec.second, state.second,
+            jnp.concatenate([main_rec1, entry_refresh[None]]), now_idx_s)
+    second = _scoped(
+        "decide.record.second", add_rows_multi, spec.second, second, main_rec1,
+        ev_ids1, rec_amt1, now_idx_s)
+    second = _scoped(
+        "decide.record.second", add_one_row, spec.second, second,
+        ENTRY_NODE_ROW, entry_vec, now_idx_s)
 
     # alt rows (origin + chain hashes): no OCCUPIED lane on alt (as before)
     if record_alt:
@@ -640,34 +645,42 @@ def decide_entries(
         ev_ids2 = jnp.concatenate([ev_ids1, ev_ids1])
         alt_rec = jnp.where(alt_mask2, alt_targets, pad_a)
         if spec.second.buckets >= 2:
-            alt_second = refresh_all(spec.second, state.alt_second,
-                                     now_idx_s)
+            alt_second = _scoped(
+                "decide.refresh.alt_second", refresh_all, spec.second,
+                state.alt_second, now_idx_s)
         else:
-            alt_second = refresh_rows(spec.second, state.alt_second,
-                                      alt_targets, now_idx_s)
+            alt_second = _scoped(
+                "decide.refresh.alt_second", refresh_rows, spec.second,
+                state.alt_second, alt_targets, now_idx_s)
         if fast_flow and RA <= 4096 and hist_add_fits(2 * batch.rows.shape[0]):
             # the [2B]-index scatter collides massively on the small alt
             # table; the histogram matmul is ~3x cheaper on the MXU, and
             # fast_flow's host-verified uniform acquire makes its int32
             # post-scaling bit-exact (see stats.window.add_rows_hist)
             a_uni = jnp.max(jnp.where(batch.valid, acq, 0))
-            alt_second = add_rows_hist(spec.second, alt_second, alt_rec,
-                                       ev_ids2, a_uni, now_idx_s)
+            alt_second = _scoped(
+                "decide.record.alt_second", add_rows_hist, spec.second,
+                alt_second, alt_rec, ev_ids2, a_uni, now_idx_s)
         else:
             acq2 = jnp.concatenate([acq, acq])
             alt_amt = jnp.where(alt_mask2, acq2, 0)
-            alt_second = add_rows_multi(spec.second, alt_second, alt_rec,
-                                        ev_ids2, alt_amt, now_idx_s)
+            alt_second = _scoped(
+                "decide.record.alt_second", add_rows_multi, spec.second,
+                alt_second, alt_rec, ev_ids2, alt_amt, now_idx_s)
     else:
         alt_second = state.alt_second
 
     minute = state.minute
     if spec.minute:
-        minute = refresh_all(spec.minute, state.minute, now_idx_m)
-        minute = add_rows_multi(spec.minute, minute, main_rec1, ev_ids1,
-                                rec_amt1, now_idx_m)
-        minute = add_one_row(spec.minute, minute, ENTRY_NODE_ROW, entry_vec,
-                             now_idx_m)
+        minute = _scoped(
+            "decide.refresh.minute", refresh_all, spec.minute, state.minute,
+            now_idx_m)
+        minute = _scoped(
+            "decide.record.minute", add_rows_multi, spec.minute, minute,
+            main_rec1, ev_ids1, rec_amt1, now_idx_m)
+        minute = _scoped(
+            "decide.record.minute", add_one_row, spec.minute, minute,
+            ENTRY_NODE_ROW, entry_vec, now_idx_m)
 
     if skip_threads:
         # nothing loaded reads the gauges: the scatters (+ the alt half)
@@ -757,44 +770,52 @@ def record_exits(
     entry_rt_min = jnp.min(jnp.where(ein, rt1, jnp.iinfo(jnp.int32).max))
 
     if spec.second.buckets >= 2:
-        second = refresh_all(spec.second, state.second, now_idx_s)
+        second = _scoped(
+            "exit.refresh.second", refresh_all, spec.second, state.second,
+            now_idx_s)
     else:
         # B=1: same ENTRY gating as decide_entries — only refresh the
         # entry row when an IN event actually lands on it this batch
         entry_refresh = jnp.where(jnp.any(ein),
                                   jnp.int32(ENTRY_NODE_ROW), pad_r)
-        second = refresh_rows(
-            spec.second, state.second,
-            jnp.concatenate([main_rows, entry_refresh[None]]),
-            now_idx_s)
-    second = add_rows_vec(spec.second, second, main_rows, payload,
-                          now_idx_s, rt_ms=rt1, rt_valid=batch.valid)
-    second = add_one_row(spec.second, second, ENTRY_NODE_ROW, entry_vec,
-                         now_idx_s, rt_add=entry_rt_add,
-                         rt_min=entry_rt_min)
+        second = _scoped(
+            "exit.refresh.second", refresh_rows, spec.second, state.second,
+            jnp.concatenate([main_rows, entry_refresh[None]]), now_idx_s)
+    second = _scoped(
+        "exit.record.second", add_rows_vec, spec.second, second, main_rows,
+        payload, now_idx_s, rt_ms=rt1, rt_valid=batch.valid)
+    second = _scoped(
+        "exit.record.second", add_one_row, spec.second, second, ENTRY_NODE_ROW,
+        entry_vec, now_idx_s, rt_add=entry_rt_add, rt_min=entry_rt_min)
     if record_alt:
         if spec.second.buckets >= 2:
-            alt_second = refresh_all(spec.second, state.alt_second,
-                                     now_idx_s)
+            alt_second = _scoped(
+                "exit.refresh.alt_second", refresh_all, spec.second,
+                state.alt_second, now_idx_s)
         else:
-            alt_second = refresh_rows(spec.second, state.alt_second,
-                                      alt_targets, now_idx_s)
+            alt_second = _scoped(
+                "exit.refresh.alt_second", refresh_rows, spec.second,
+                state.alt_second, alt_targets, now_idx_s)
         rt2 = jnp.concatenate([rt1, rt1])
         valid2 = jnp.concatenate([batch.valid, batch.valid])
-        alt_second = add_rows_vec(spec.second, alt_second, alt_targets,
-                                  payload2, now_idx_s, rt_ms=rt2,
-                                  rt_valid=valid2)
+        alt_second = _scoped(
+            "exit.record.alt_second", add_rows_vec, spec.second, alt_second,
+            alt_targets, payload2, now_idx_s, rt_ms=rt2, rt_valid=valid2)
     else:
         alt_second = state.alt_second
 
     minute = state.minute
     if spec.minute:
-        minute = refresh_all(spec.minute, state.minute, now_idx_m)
-        minute = add_rows_vec(spec.minute, minute, main_rows, payload,
-                              now_idx_m, rt_ms=rt1, rt_valid=batch.valid)
-        minute = add_one_row(spec.minute, minute, ENTRY_NODE_ROW, entry_vec,
-                             now_idx_m, rt_add=entry_rt_add,
-                             rt_min=entry_rt_min)
+        minute = _scoped(
+            "exit.refresh.minute", refresh_all, spec.minute, state.minute,
+            now_idx_m)
+        minute = _scoped(
+            "exit.record.minute", add_rows_vec, spec.minute, minute, main_rows,
+            payload, now_idx_m, rt_ms=rt1, rt_valid=batch.valid)
+        minute = _scoped(
+            "exit.record.minute", add_one_row, spec.minute, minute,
+            ENTRY_NODE_ROW, entry_vec, now_idx_m, rt_add=entry_rt_add,
+            rt_min=entry_rt_min)
 
     if skip_threads:
         threads = state.threads
@@ -908,22 +929,33 @@ def record_blocks(
     amt = jnp.where(valid, acquire, 0)
     amt2 = jnp.concatenate([amt, amt])
     if spec.second.buckets >= 2:
-        second = refresh_all(spec.second, state.second, now_idx_s)
-        alt_second = refresh_all(spec.second, state.alt_second, now_idx_s)
+        second = _scoped(
+            "blocks.refresh.second", refresh_all, spec.second, state.second,
+            now_idx_s)
+        alt_second = _scoped(
+            "blocks.refresh.alt_second", refresh_all, spec.second,
+            state.alt_second, now_idx_s)
     else:
-        second = refresh_rows(spec.second, state.second, main_targets,
-                              now_idx_s)
-        alt_second = refresh_rows(spec.second, state.alt_second, alt_targets,
-                                  now_idx_s)
-    second = add_rows(spec.second, second, main_targets, ev.BLOCK, amt2,
-                      now_idx_s)
-    alt_second = add_rows(spec.second, alt_second, alt_targets, ev.BLOCK,
-                          amt2, now_idx_s)
+        second = _scoped(
+            "blocks.refresh.second", refresh_rows, spec.second, state.second,
+            main_targets, now_idx_s)
+        alt_second = _scoped(
+            "blocks.refresh.alt_second", refresh_rows, spec.second,
+            state.alt_second, alt_targets, now_idx_s)
+    second = _scoped(
+        "blocks.record.second", add_rows, spec.second, second, main_targets,
+        ev.BLOCK, amt2, now_idx_s)
+    alt_second = _scoped(
+        "blocks.record.alt_second", add_rows, spec.second, alt_second,
+        alt_targets, ev.BLOCK, amt2, now_idx_s)
     minute = state.minute
     if spec.minute:
-        minute = refresh_all(spec.minute, state.minute, now_idx_m)
-        minute = add_rows(spec.minute, minute, main_targets, ev.BLOCK, amt2,
-                          now_idx_m)
+        minute = _scoped(
+            "blocks.refresh.minute", refresh_all, spec.minute, state.minute,
+            now_idx_m)
+        minute = _scoped(
+            "blocks.record.minute", add_rows, spec.minute, minute,
+            main_targets, ev.BLOCK, amt2, now_idx_m)
     return state._replace(second=second, alt_second=alt_second, minute=minute)
 
 

@@ -402,7 +402,8 @@ class AdaptiveBatcher:
             if tr:
                 obs.spans.record(tr, "frontend.enqueue", t0,
                                  obs.spans.now_ns(),
-                                 note=f"depth={len(self.queue)}")
+                                 note=f"depth={len(self.queue)}",
+                                 request=True)
         self._wake.set()
         return await req.future
 
@@ -502,34 +503,36 @@ class AdaptiveBatcher:
         obs = self._s.obs
         obs_on = obs.enabled
         tr = obs.request_trace() if obs_on else 0
-        t0 = obs.spans.now_ns() if tr else 0
-        if obs_on:
-            obs.counters.add(_FLUSH_KEY[reason])
-        if tr:
-            # fan-in: every request trace joins this batch's trace (the
-            # causal edges chain(request_id) walks to reach the
-            # pipeline/device spans)
-            for r in reqs:
-                if r.trace_id:
-                    obs.spans.link(r.trace_id, tr, "flush")
-        if self.flush_log is not None:
-            self.flush_log.append({
-                "reason": reason,
-                "resources": [r.resource for r in reqs],
-                "counts": [r.count for r in reqs],
-                "prioritized": [r.prioritized for r in reqs],
-                "origins": [r.origin for r in reqs],
-            })
-        self._inflight += len(reqs)
-        # free pipeline slot BEFORE dispatching: the semaphore (released
-        # from the pipeline's on_settle hook) bounds in-flight batches at
-        # `depth` without DispatchPipeline.submit ever stalling — a stall
-        # would block a worker thread on a device readback mid-dispatch
-        await self._slots.acquire()
-        ticket = await asyncio.to_thread(self._dispatch, reqs, tr)
-        if tr:
-            obs.spans.record(tr, "frontend.flush", t0, obs.spans.now_ns(),
-                             n=len(reqs), note=reason)
+        n = len(reqs)
+        with obs.phase("frontend.flush", n=n, trace=tr, note=reason):
+            if obs_on:
+                obs.counters.add(_FLUSH_KEY[reason])
+            if tr:
+                # fan-in: every request trace joins this batch's trace
+                # (the causal edges chain(request_id) walks to reach the
+                # pipeline/device spans)
+                for r in reqs:
+                    if r.trace_id:
+                        obs.spans.link(r.trace_id, tr, "flush")
+            if self.flush_log is not None:
+                self.flush_log.append({
+                    "reason": reason,
+                    "resources": [r.resource for r in reqs],
+                    "counts": [r.count for r in reqs],
+                    "prioritized": [r.prioritized for r in reqs],
+                    "origins": [r.origin for r in reqs],
+                })
+            self._inflight += n
+            # free pipeline slot BEFORE dispatching: the semaphore
+            # (released from the pipeline's on_settle hook) bounds
+            # in-flight batches at `depth` without
+            # DispatchPipeline.submit ever stalling — a stall would block
+            # a worker thread on a device readback mid-dispatch
+            with obs.phase("frontend.slot_wait", n=n, trace=tr):
+                await self._slots.acquire()
+            ticket = await asyncio.to_thread(
+                self._in_phase, "frontend.dispatch", n, tr,
+                self._dispatch, reqs, tr)
         self._inflight_reqs.append(reqs)
         await self._settle_q.put((ticket, reqs, tr))
 
@@ -593,45 +596,59 @@ class AdaptiveBatcher:
         obs = self._s.obs
         while True:
             ticket, reqs, batch_tr = await self._settle_q.get()
-            verdicts = await asyncio.to_thread(ticket.result)
+            verdicts = await asyncio.to_thread(
+                self._in_phase, "frontend.result_wait", len(reqs), batch_tr,
+                ticket.result)
             if self._inflight_reqs and self._inflight_reqs[0] is reqs:
                 self._inflight_reqs.popleft()
             self._inflight -= len(reqs)
-            obs_on = obs.enabled
-            t_end = obs.spans.now_ns() if obs_on else 0
-            now_ms = self._s.clock.now_ms() if obs_on else 0
-            worst = None              # worst deadline overrun this batch
-            allow = np.asarray(verdicts.allow)
-            reason = np.asarray(verdicts.reason)
-            wait = np.asarray(verdicts.wait_ms)
-            for i, r in enumerate(reqs):
-                lat_ns = (t_end - r.t0_ns) if obs_on else 0
-                if obs_on:
-                    obs.hist_request.record(lat_ns)
-                    if r.trace_id:
-                        # fan-out: the batch settles THIS request (the
-                        # flow arrow back), then the request's terminal
-                        # span closes its chain
-                        if batch_tr:
-                            obs.spans.link(batch_tr, r.trace_id, "verdict")
-                        obs.spans.record(r.trace_id, "frontend.settle",
-                                         r.t0_ns, t_end, n=1)
-                    if now_ms > r.deadline_ms and (
-                            worst is None or worst[1] < now_ms
-                            - r.deadline_ms):
-                        worst = (r.trace_id, now_ms - r.deadline_ms)
-                if not r.future.done():
-                    r.future.set_result(RequestVerdict(
-                        bool(allow[i]), int(reason[i]), int(wait[i]),
-                        lat_ns / 1e6, r.trace_id))
+            with obs.phase("frontend.fanout", n=len(reqs), trace=batch_tr):
+                self._fan_out(reqs, verdicts, batch_tr)
+
+    def _in_phase(self, name: str, n: int, trace: int, fn, *args):
+        """``fn(*args)`` as one phase of the batch, in its worker thread
+        (``asyncio.to_thread`` carries the loop's open phase in)."""
+        with self._s.obs.phase(name, n=n, trace=trace):
+            return fn(*args)
+
+    def _fan_out(self, reqs: List[_Pending], verdicts, batch_tr: int) -> None:
+        """Resolve one settled batch's request futures (loop thread)."""
+        obs = self._s.obs
+        obs_on = obs.enabled
+        t_end = obs.spans.now_ns() if obs_on else 0
+        now_ms = self._s.clock.now_ms() if obs_on else 0
+        worst = None              # worst deadline overrun this batch
+        allow = np.asarray(verdicts.allow)
+        reason = np.asarray(verdicts.reason)
+        wait = np.asarray(verdicts.wait_ms)
+        for i, r in enumerate(reqs):
+            lat_ns = (t_end - r.t0_ns) if obs_on else 0
             if obs_on:
-                if worst is not None:
-                    # SLO trigger: pin the worst-overrun request's chain
-                    # (rate-limited per kind inside the recorder)
-                    obs.flight.trigger("deadline_miss", root=worst[0],
-                                       note=f"overrun_ms={worst[1]}",
-                                       worst_ms=worst[1])
-                obs.flight.note_requests(len(reqs))
+                obs.hist_request.record(lat_ns)
+                if r.trace_id:
+                    # fan-out: the batch settles THIS request (the
+                    # flow arrow back), then the request's terminal
+                    # span closes its chain
+                    if batch_tr:
+                        obs.spans.link(batch_tr, r.trace_id, "verdict")
+                    obs.spans.record(r.trace_id, "frontend.settle",
+                                     r.t0_ns, t_end, n=1, request=True)
+                if now_ms > r.deadline_ms and (
+                        worst is None or worst[1] < now_ms
+                        - r.deadline_ms):
+                    worst = (r.trace_id, now_ms - r.deadline_ms)
+            if not r.future.done():
+                r.future.set_result(RequestVerdict(
+                    bool(allow[i]), int(reason[i]), int(wait[i]),
+                    lat_ns / 1e6, r.trace_id))
+        if obs_on:
+            if worst is not None:
+                # SLO trigger: pin the worst-overrun request's chain
+                # (rate-limited per kind inside the recorder)
+                obs.flight.trigger("deadline_miss", root=worst[0],
+                                   note=f"overrun_ms={worst[1]}",
+                                   worst_ms=worst[1])
+            obs.flight.note_requests(len(reqs))
 
     # ------------------------------------------------------------------
     # lifecycle

@@ -213,6 +213,13 @@ class SentinelCollector:
             "tail_signal (ticks where per-resource p99 deltas fed the "
             "degrade policy) (control/loop.py)",
             labels=["action"])
+        cluster_srv = CounterMetricFamily(
+            f"{ns}_cluster_server_total",
+            "Cluster token server cycle: cycles (batching windows that "
+            "took requests) / taken (requests handed to the engine) / "
+            "queue_wait_us (their summed wait in the server's queue) "
+            "(cluster/server.py)",
+            labels=["event"])
         if not describe_only and obs is not None and obs.enabled:
             from sentinel_tpu.obs import counters as ck
             counts = obs.counters.snapshot()
@@ -294,6 +301,11 @@ class SentinelCollector:
                             (ck.CONTROL_DROPPED, "admission_dropped"),
                             (ck.CONTROL_TAIL_SIGNAL, "tail_signal")):
                 control.add_metric([ev], counts.get(key, 0))
+            for key, ev in ((ck.CLUSTER_SERVER_CYCLES, "cycles"),
+                            (ck.CLUSTER_SERVER_TAKEN, "taken"),
+                            (ck.CLUSTER_SERVER_QUEUE_WAIT_US,
+                             "queue_wait_us")):
+                cluster_srv.add_metric([ev], counts.get(key, 0))
             # bounded by construction: at most telemetry.k ≤ MAX_K labels
             # (×3 quantile labels for res_rt — still top-K-bounded)
             telemetry = getattr(self.sentinel, "telemetry", None)
@@ -309,7 +321,8 @@ class SentinelCollector:
         yield from (p99, quant, req_quant, route, hits, misses, retries,
                     blocks, occupy, pipeline, frontend, fe_flush, wraps,
                     flight_pinned, flight_trig, sf_ovf, tune,
-                    res_qps, res_rt, telem, label_ovf, tier, control)
+                    res_qps, res_rt, telem, label_ovf, tier, control,
+                    cluster_srv)
 
     def collect(self):
         ns = self.namespace

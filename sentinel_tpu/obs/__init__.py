@@ -32,7 +32,6 @@ coordinator-side counter aggregation in multihost/obs_agg.py.
 
 from __future__ import annotations
 
-import contextlib
 import os
 from typing import Dict, Optional
 
@@ -41,12 +40,34 @@ from sentinel_tpu.obs.counters import CounterSet
 from sentinel_tpu.obs.eventlog import BlockEventLog
 from sentinel_tpu.obs.flight import FlightRecorder
 from sentinel_tpu.obs.hist import LogHistogram, bucket_bounds_ns
-from sentinel_tpu.obs.spans import SpanRecorder
+from sentinel_tpu.obs.spans import OPEN_PHASE, SpanRecorder
 
 OBS_DISABLE_ENV = "SENTINEL_OBS_DISABLE"
 TRACE_SAMPLE_ENV = "SENTINEL_TRACE_SAMPLE"
 
-_NULL_CTX = contextlib.nullcontext()
+
+
+class _NullPhase:
+    """The shared no-op context of a disabled bundle: what ``annotate``
+    and ``phase`` return then. Sites that set ``n``/``note`` on an open
+    phase write to it unharmed."""
+
+    n = 0
+    note = ""
+
+    def start(self) -> "_NullPhase":
+        return self
+
+    def stop(self) -> None:
+        pass
+
+    __enter__ = start
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NULL_CTX = _NullPhase()
 
 
 def obs_disabled() -> bool:
@@ -64,20 +85,75 @@ def trace_sample_rate() -> float:
         return 1.0
 
 
-def trace_annotation(name: str):
+def trace_annotation(name: str, n: int = 0):
     """A ``jax.profiler.TraceAnnotation`` context (names the enclosed
-    dispatch in profiler/XProf timelines)."""
+    dispatch in profiler/XProf timelines); ``n`` rides as a stat of the
+    event (how many requests or events the call carried)."""
     from jax.profiler import TraceAnnotation
-    return TraceAnnotation(name)
+    return TraceAnnotation(name, n=n) if n else TraceAnnotation(name)
+
+
+class _Phase:
+    """One open :meth:`RuntimeObs.phase`: the same enter and exit write
+    the interval to the profiler's trace and to the span recorder, so
+    the two records of a phase can never disagree about its extent.
+    ``n`` and ``note`` may be set while it is open (a route or a count
+    known only at the end)."""
+
+    __slots__ = ("spans", "name", "n", "trace", "note", "id", "parent",
+                 "_obs", "_t0", "_prev", "_ann")
+
+    def __init__(self, obs: "RuntimeObs", name: str, n: int, trace: int,
+                 note: str) -> None:
+        self._obs, self.spans = obs, obs.spans
+        self.name, self.n, self.trace, self.note = name, n, trace, note
+        self.id = self.parent = 0
+
+    def start(self) -> "_Phase":
+        """``with`` does this; a site whose phase ends inside a block it
+        cannot wrap (the wait for a lock ends inside the lock) calls
+        ``start``/``stop`` itself."""
+        spans = self.spans
+        prev = self._prev = OPEN_PHASE.get()
+        if prev is not None and prev.spans is spans:
+            self.parent = prev.id
+            self.trace = self.trace or prev.trace
+        elif not self.trace:
+            self.trace = self._obs.request_trace()
+        self.id = spans.next_span_id()
+        OPEN_PHASE.set(self)
+        # the profiler stamps an annotation when it is made: the span's
+        # start is read first and its end last, so it holds the annotation
+        self._t0 = spans.now_ns()
+        self._ann = trace_annotation("sentinel_tpu." + self.name, self.n)
+        self._ann.__enter__()
+        return self
+
+    def stop(self) -> None:
+        self._ann.__exit__(None, None, None)
+        spans = self.spans
+        end = spans.now_ns()
+        # not reset(token): the exit may run in another context than the
+        # enter did (a phase handed across threads), where a token raises
+        OPEN_PHASE.set(self._prev)
+        spans.record(self.trace, self.name, self._t0, end, n=self.n,
+                     note=self.note, span_id=self.id, parent=self.parent)
+
+    __enter__ = start
+
+    def __exit__(self, *exc) -> bool:
+        self.stop()
+        return False
 
 
 class RuntimeObs:
-    """The per-``Sentinel`` telemetry bundle.
+    """The per-``Sentinel`` (and per-``ClusterEngine``) telemetry bundle.
 
     Attributes the runtime's instrumentation sites touch directly:
     ``enabled`` (the one hot-path guard), ``spans``, ``counters``,
     ``hist_entry`` (entry→verdict ns), ``hist_dispatch``
-    (dispatch→verdict-ready device ns), ``hist_request`` (per-REQUEST
+    (host ns from the dispatch returning to the caller's read of the
+    verdicts — not device time), ``hist_request`` (per-REQUEST
     ingest→verdict ns through the serving front end — the end-to-end
     latency a service owner sees; recorded by frontend/batcher.py),
     ``block_events``."""
@@ -117,12 +193,29 @@ class RuntimeObs:
             return self.spans.mint()
         return self.spans.maybe_trace()
 
-    def annotate(self, name: str):
+    def annotate(self, name: str, n: int = 0):
         """Profiler trace annotation for a jitted step — a shared no-op
         context when disabled (one truthiness check, no allocation)."""
         if not self.enabled:
             return _NULL_CTX
-        return trace_annotation(name)
+        return trace_annotation(name, n)
+
+    def phase(self, name: str, n: int = 0, trace: int = 0,
+              note: str = ""):
+        """One named interval of host work, from ONE call site to both
+        sinks: the profiler annotation ``sentinel_tpu.<name>`` (an event
+        in the same ``.xplane.pb``, on the same clock, as the device's
+        operations — it names the idle gaps under it) and a span
+        ``name`` with ``id``/``parent`` (the phase open in this task or
+        thread when it began; ``asyncio.to_thread`` carries it into the
+        worker). ``trace`` is the batch's trace id; without one the
+        phase joins the enclosing phase's trace, or asks
+        :meth:`request_trace` for a fresh one. Per engine call and per
+        batch, never per request. The shared no-op context when
+        disabled."""
+        if not self.enabled:
+            return _NULL_CTX
+        return _Phase(self, name, n, trace, note)
 
     # ---- export surface ---------------------------------------------
 

@@ -47,8 +47,15 @@ dispatch stays within the 2% observability budget (benchmarks/ci_gate.py
   by the int8 verdict codes (``exception_name_for`` /
   ``slot_name_for_code`` for custom slots).
 * ``obs.span_ring_wrap`` — spans/links lost to per-thread ring wrap
-  (capacity 2048 too small for the sustained span rate; previously a
-  silent overwrite).
+  (a ring too small for the sustained span rate; previously a silent
+  overwrite).
+* ``cluster.server.*`` — the token server's cycle (cluster/server.py
+  ``_batch_loop``; they live in the ENGINE's bundle, ``engine.obs``):
+  ``cycles`` (batching windows that took requests), ``taken`` (requests
+  handed to the engine) and ``queue_wait_us`` (summed at the take: each
+  request's wait since ``_dispatch`` queued it). Mean queue wait =
+  ``queue_wait_us / taken``; requests per engine call ≈ ``taken /
+  cycles``.
 * ``flight.*`` — the SLO flight recorder (obs/flight.py): ``pinned``
   (chains persisted to the ``<app>-trace`` log) and
   ``trigger.{deadline_miss, shed, p99, block_burst}`` (which SLO
@@ -225,6 +232,12 @@ CONTROL_DROPPED = "control.admission_dropped"
 TELEMETRY_HIST_TICK = "telemetry.hist_tick"
 CONTROL_TAIL_SIGNAL = "control.tail_signal"
 
+# PR 26 — the cluster token server's cycle, counted where the work
+# happens (cluster/server.py ``_batch_loop``, into ``engine.obs``).
+CLUSTER_SERVER_CYCLES = "cluster.server.cycles"
+CLUSTER_SERVER_TAKEN = "cluster.server.taken"
+CLUSTER_SERVER_QUEUE_WAIT_US = "cluster.server.queue_wait_us"
+
 #: Fixed aggregation catalog (order is the wire format of the multihost
 #: counter vector — append only, never reorder).
 CATALOG = (
@@ -256,6 +269,8 @@ CATALOG = (
     CONTROL_TICK, CONTROL_SHED_ACTION, CONTROL_RETUNE_ACTION,
     CONTROL_DEGRADE_ACTION, CONTROL_DROPPED,
     TELEMETRY_HIST_TICK, CONTROL_TAIL_SIGNAL,
+    CLUSTER_SERVER_CYCLES, CLUSTER_SERVER_TAKEN,
+    CLUSTER_SERVER_QUEUE_WAIT_US,
 )
 
 

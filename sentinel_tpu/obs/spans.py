@@ -25,7 +25,18 @@ Timestamps are integer nanoseconds. Under a real clock they come from
 Span schema (``snapshot()`` dicts — docs/OBSERVABILITY.md):
 ``trace`` (sampled trace id), ``name``, ``start_ns``, ``end_ns``,
 ``dur_ns``, ``thread`` (ident), ``n`` (event count the span covered),
-``note`` (free-form: route taken, sub-batch sizes, ...).
+``note`` (free-form: route taken, sub-batch sizes, ...), ``id`` (this
+span's own id, unique per recorder) and ``parent`` (the id of the
+:meth:`RuntimeObs.phase` that was open when it began, 0 at the root).
+A layer's SELF time is its span's duration less what the spans naming
+it as ``parent`` cover of that interval.
+
+Two rings per thread: per-BATCH spans (``record``) and per-REQUEST
+spans (``record(..., request=True)``: the front end's
+``frontend.enqueue`` / ``frontend.settle``). A serving thread records
+thousands of request spans a second and a few dozen batch spans; in one
+ring the first evicted the second within a second. The read side merges
+both.
 
 Causal links (PR 8): traces relate across the fan-in/fan-out points of
 the serving stack — many request traces coalesce into one batch trace at
@@ -41,19 +52,29 @@ pull every sibling request of the batch into every request's chain.
 
 Ring overflow is an explicit signal (PR 8): every overwritten span/link
 fires ``on_wrap`` (wired by RuntimeObs to the ``obs.span_ring_wrap``
-counter) so operators can see when capacity 2048 is too small instead of
+counter) so operators can see when a ring is too small instead of
 silently losing the tail.
 """
 
 from __future__ import annotations
 
+import contextvars
 import itertools
 import threading
 import time
 from typing import Dict, List, Optional
 
-DEFAULT_CAPACITY = 2048
+#: per-batch ring: holds a 20 s window of per-batch phases whole (the
+#: token server's loop thread records ~220 a second)
+DEFAULT_CAPACITY = 8192
+#: per-request ring (``record(..., request=True)``)
+REQUEST_CAPACITY = 2048
 LINK_CAPACITY = 4096
+
+#: the innermost open ``RuntimeObs.phase`` of this task (asyncio) or
+#: thread; ``asyncio.to_thread`` copies it into the worker
+OPEN_PHASE: contextvars.ContextVar = contextvars.ContextVar(
+    "sentinel_tpu_open_phase", default=None)
 
 #: link kinds (the causal-edge vocabulary; docs/OBSERVABILITY.md)
 LINK_FLUSH = "flush"        # request trace → the batch trace that took it
@@ -72,14 +93,16 @@ class SpanRecorder:
     def __init__(self, capacity: int = DEFAULT_CAPACITY,
                  sample: float = 1.0, time_ns=None, on_wrap=None) -> None:
         self.capacity = max(16, int(capacity))
+        self.request_capacity = min(REQUEST_CAPACITY, self.capacity)
         # rate → stride: 1.0 records every trace, 0.01 every 100th, ≤0 none
         self._stride = 0 if sample <= 0 else max(1, round(1.0 / sample))
         self.sample = 0.0 if sample <= 0 else 1.0 / self._stride
         self._time_ns = time_ns or time.perf_counter_ns
         self._dispatch_seq = itertools.count()   # sampling stride counter
         self._trace_seq = itertools.count(1)     # issued trace ids
+        self._span_seq = itertools.count(1)      # issued span ids
         self._tls = threading.local()
-        self._rings: List[_Ring] = []
+        self._rings: List[_Ring] = []            # batch AND request rings
         self._link_rings: List[_Ring] = []
         self._rings_lock = threading.Lock()
         # fired once per OVERWRITTEN span/link (ring wrapped past a live
@@ -120,23 +143,39 @@ class SpanRecorder:
             return 0
         return next(self._trace_seq)
 
+    def next_span_id(self) -> int:
+        return next(self._span_seq)
+
     def record(self, trace_id: int, name: str, start_ns: int, end_ns: int,
-               n: int = 0, note: str = "") -> None:
+               n: int = 0, note: str = "", *, span_id: int = 0,
+               parent: Optional[int] = None, request: bool = False) -> None:
+        """One finished span. ``parent`` defaults to the phase open in
+        this context (of this recorder); ``request=True`` files a
+        per-request span in the thread's request ring, parentless."""
         if not trace_id or not self.enabled:
             return
+        if request:
+            attr, cap, parent = "req_ring", self.request_capacity, 0
+        else:
+            attr, cap = "ring", self.capacity
+            if parent is None:
+                open_ = OPEN_PHASE.get()
+                parent = (open_.id if open_ is not None
+                          and open_.spans is self else 0)
         try:
-            ring = self._tls.ring
+            ring = getattr(self._tls, attr)
         except AttributeError:
             ring = _Ring()
-            self._tls.ring = ring
+            setattr(self._tls, attr, ring)
             with self._rings_lock:
                 self._rings.append(ring)
         entry = (trace_id, name, int(start_ns), int(end_ns),
-                 threading.get_ident(), int(n), note)
-        if len(ring.buf) < self.capacity:
+                 threading.get_ident(), int(n), note,
+                 span_id or next(self._span_seq), parent)
+        if len(ring.buf) < cap:
             ring.buf.append(entry)
         else:
-            ring.buf[ring.idx % self.capacity] = entry
+            ring.buf[ring.idx % cap] = entry
             if self.on_wrap is not None:
                 self.on_wrap()
         ring.idx += 1
@@ -179,7 +218,8 @@ class SpanRecorder:
             spans = spans[-limit:]
         return [{"trace": s[0], "name": s[1], "start_ns": s[2],
                  "end_ns": s[3], "dur_ns": s[3] - s[2], "thread": s[4],
-                 "n": s[5], "note": s[6]} for s in spans]
+                 "n": s[5], "note": s[6], "id": s[7], "parent": s[8]}
+                for s in spans]
 
     def links_snapshot(self, limit: Optional[int] = None) -> List[Dict]:
         """All recorded causal edges, ts-ordered."""

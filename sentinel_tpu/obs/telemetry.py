@@ -420,8 +420,11 @@ class HotTelemetry:
             batch = list(self._pending)
             self._pending.clear()
         for now_ms, sec, append, outs in batch:
-            self._land(now_ms, sec, append,
-                       tuple(np.asarray(o) for o in outs))
+            host = tuple(np.asarray(o) for o in outs)
+            # the host pass over the resident names, once per tick
+            with self._obs.phase("telemetry.land",
+                                 n=len(self._sentinel.resources)):
+                self._land(now_ms, sec, append, host)
         return len(batch)
 
     def _land(self, now_ms: int, sec: int, append: int, outs) -> None:

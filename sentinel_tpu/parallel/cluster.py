@@ -45,6 +45,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sentinel_tpu.core.pending import PendingResult, start_host_copy
+from sentinel_tpu.obs import RuntimeObs
 from sentinel_tpu.ops import segments as seg
 from sentinel_tpu.parallel import shard_math
 from sentinel_tpu.stats import events as ev
@@ -156,132 +157,137 @@ def _shard_step(
     active = table.active[rows] & batch.valid
     ns_req = jnp.where(active, table.ns_id[rows], NS)  # NS = inapplicable seg
 
-    # ---- GlobalRequestLimiter: pod-global per-namespace request QPS (psum) ----
-    ns_local = window_sum_all(w, state.ns, ev.PASS, now_idx).astype(jnp.float32)
-    ns_global = lax.psum(ns_local, "shard")                       # [NS]
-    ns_base = jnp.concatenate([ns_global, jnp.zeros((1,), jnp.float32)])
-    ns_lim = jnp.concatenate([ns_limit, jnp.full((1,), jnp.inf, jnp.float32)])
+    # named scopes: HLO metadata only (op_name), so a device trace or the
+    # compiled text says which stage owns an operation
+    with jax.named_scope("token.ns"):
+        # ---- GlobalRequestLimiter: pod-global per-namespace request QPS (psum) ----
+        ns_local = window_sum_all(w, state.ns, ev.PASS, now_idx).astype(jnp.float32)
+        ns_global = lax.psum(ns_local, "shard")                       # [NS]
+        ns_base = jnp.concatenate([ns_global, jnp.zeros((1,), jnp.float32)])
+        ns_lim = jnp.concatenate([ns_limit, jnp.full((1,), jnp.inf, jnp.float32)])
 
-    order_ns = seg.sort_by_keys(ns_req)
-    ns_s = ns_req[order_ns]
-    starts_ns = seg.segment_starts(ns_s, jnp.zeros_like(ns_s))
-    leader_ns = seg.segment_leader_index(starts_ns)
-    ones = jnp.where(active, 1.0, 0.0)[order_ns]
-    limiter_ok_s = seg.greedy_admit(ns_base[ns_s], ones, ns_lim[ns_s],
-                                    starts_ns, leader_ns)
-    limiter_ok = seg.unsort(order_ns, limiter_ok_s.astype(jnp.int32)).astype(jnp.bool_)
-    proceed = active & limiter_ok
+        order_ns = seg.sort_by_keys(ns_req)
+        ns_s = ns_req[order_ns]
+        starts_ns = seg.segment_starts(ns_s, jnp.zeros_like(ns_s))
+        leader_ns = seg.segment_leader_index(starts_ns)
+        ones = jnp.where(active, 1.0, 0.0)[order_ns]
+        limiter_ok_s = seg.greedy_admit(ns_base[ns_s], ones, ns_lim[ns_s],
+                                        starts_ns, leader_ns)
+        limiter_ok = seg.unsort(order_ns, limiter_ok_s.astype(jnp.int32)).astype(jnp.bool_)
+        proceed = active & limiter_ok
 
-    # ---- per-flow admission (ClusterFlowChecker.acquireClusterToken) ----
-    flow_req = proceed & ~batch.is_param
-    latest = window_sum_all(w, state.flows, ev.PASS, now_idx).astype(jnp.float32)  # [L]
-    conn = connected[jnp.minimum(table.ns_id, NS - 1)]
-    thr_rule = table.count * jnp.where(table.is_global, 1.0, conn) * table.exceed  # [L]
+    with jax.named_scope("token.decide"):
+        # ---- per-flow admission (ClusterFlowChecker.acquireClusterToken) ----
+        flow_req = proceed & ~batch.is_param
+        latest = window_sum_all(w, state.flows, ev.PASS, now_idx).astype(jnp.float32)  # [L]
+        conn = connected[jnp.minimum(table.ns_id, NS - 1)]
+        thr_rule = table.count * jnp.where(table.is_global, 1.0, conn) * table.exceed  # [L]
 
-    seg_rows = jnp.where(flow_req, rows, L)  # L = never-blocking sentinel segment
-    order = seg.sort_by_keys(seg_rows)
-    rows_s = seg_rows[order]
-    starts = seg.segment_starts(rows_s, jnp.zeros_like(rows_s))
-    leader = seg.segment_leader_index(starts)
-    acq_s = jnp.where(flow_req, batch.acquire, 0).astype(jnp.float32)[order]
-    safe_rows_s = jnp.minimum(rows_s, L - 1)
-    base_s = latest[safe_rows_s]
-    lim_s = jnp.where(rows_s < L, thr_rule[safe_rows_s], jnp.inf)
-    admit_s = seg.greedy_admit(base_s, acq_s, lim_s, starts, leader)
-    excl_s, _ = seg.segment_prefix_sum(jnp.where(admit_s, acq_s, 0.0), starts, leader)
-    remaining_s = lim_s - base_s - excl_s - acq_s
-    admitted = seg.unsort(order, admit_s.astype(jnp.int32)).astype(jnp.bool_) & flow_req
-    remaining = jnp.where(jnp.isfinite(remaining_s), remaining_s, 0.0)
-    remaining = seg.unsort(order, remaining.astype(jnp.int32))
+        seg_rows = jnp.where(flow_req, rows, L)  # L = never-blocking sentinel segment
+        order = seg.sort_by_keys(seg_rows)
+        rows_s = seg_rows[order]
+        starts = seg.segment_starts(rows_s, jnp.zeros_like(rows_s))
+        leader = seg.segment_leader_index(starts)
+        acq_s = jnp.where(flow_req, batch.acquire, 0).astype(jnp.float32)[order]
+        safe_rows_s = jnp.minimum(rows_s, L - 1)
+        base_s = latest[safe_rows_s]
+        lim_s = jnp.where(rows_s < L, thr_rule[safe_rows_s], jnp.inf)
+        admit_s = seg.greedy_admit(base_s, acq_s, lim_s, starts, leader)
+        excl_s, _ = seg.segment_prefix_sum(jnp.where(admit_s, acq_s, 0.0), starts, leader)
+        remaining_s = lim_s - base_s - excl_s - acq_s
+        admitted = seg.unsort(order, admit_s.astype(jnp.int32)).astype(jnp.bool_) & flow_req
+        remaining = jnp.where(jnp.isfinite(remaining_s), remaining_s, 0.0)
+        remaining = seg.unsort(order, remaining.astype(jnp.int32))
 
-    # ---- occupy: prioritized deficit pre-books future windows ----
-    denied = flow_req & ~admitted
-    waiting_sum = window_sum_all(w, state.flows, ev.WAITING, now_idx).astype(jnp.float32)
-    occupy_open = waiting_sum[rows] <= table.max_occupy[rows] * thr_rule[rows]
-    # expiry scan: waiting until bucket k (stamp s_k) rotates out frees its
-    # PASS count at wait = (s_k - now_idx + B)·win - in_win_ms
-    stamps_req = state.flows.stamps[rows]                       # [Bl, B]
-    pass_req = state.flows.counters[rows, :, ev.PASS]           # [Bl, B]
-    live = valid_mask(w, stamps_req, now_idx)
-    delta = jnp.where(live, stamps_req - now_idx, jnp.int32(0))  # [-B+1, 0]
-    # freed(k) = sum of pass in buckets expiring no later than bucket k
-    freed = jnp.sum(
-        jnp.where(live[:, None, :] & (delta[:, None, :] <= delta[:, :, None]),
-                  pass_req[:, None, :], 0), axis=2).astype(jnp.float32)  # [Bl, B]
-    total_pass = latest[rows][:, None]
-    fits = (total_pass - freed + batch.acquire[:, None].astype(jnp.float32)
-            <= thr_rule[rows][:, None]) & live
-    wait_k = (delta + w.buckets) * w.win_ms - in_win_ms          # [Bl, B]
-    wait_k = jnp.where(fits & (wait_k > 0), wait_k, jnp.int32(2 ** 30))
-    best_wait = jnp.min(wait_k, axis=1)
-    should_wait = (denied & batch.prioritized & occupy_open
-                   & (best_wait < 2 ** 30))
-    wait_ms = jnp.where(should_wait, best_wait, 0)
+        # ---- occupy: prioritized deficit pre-books future windows ----
+        denied = flow_req & ~admitted
+        waiting_sum = window_sum_all(w, state.flows, ev.WAITING, now_idx).astype(jnp.float32)
+        occupy_open = waiting_sum[rows] <= table.max_occupy[rows] * thr_rule[rows]
+        # expiry scan: waiting until bucket k (stamp s_k) rotates out frees its
+        # PASS count at wait = (s_k - now_idx + B)·win - in_win_ms
+        stamps_req = state.flows.stamps[rows]                       # [Bl, B]
+        pass_req = state.flows.counters[rows, :, ev.PASS]           # [Bl, B]
+        live = valid_mask(w, stamps_req, now_idx)
+        delta = jnp.where(live, stamps_req - now_idx, jnp.int32(0))  # [-B+1, 0]
+        # freed(k) = sum of pass in buckets expiring no later than bucket k
+        freed = jnp.sum(
+            jnp.where(live[:, None, :] & (delta[:, None, :] <= delta[:, :, None]),
+                      pass_req[:, None, :], 0), axis=2).astype(jnp.float32)  # [Bl, B]
+        total_pass = latest[rows][:, None]
+        fits = (total_pass - freed + batch.acquire[:, None].astype(jnp.float32)
+                <= thr_rule[rows][:, None]) & live
+        wait_k = (delta + w.buckets) * w.win_ms - in_win_ms          # [Bl, B]
+        wait_k = jnp.where(fits & (wait_k > 0), wait_k, jnp.int32(2 ** 30))
+        best_wait = jnp.min(wait_k, axis=1)
+        should_wait = (denied & batch.prioritized & occupy_open
+                       & (best_wait < 2 ** 30))
+        wait_ms = jnp.where(should_wait, best_wait, 0)
 
-    blocked = denied & ~should_wait
+        blocked = denied & ~should_wait
 
-    # ---- hot-param admission (ClusterParamFlowChecker.acquireClusterToken) ----
-    # Per-value avg vs calcGlobalThreshold; a request passes iff EVERY carried
-    # value fits, and only then are all its values counted (reference
-    # semantics; the host resolves per-item threshold overrides into
-    # ``param_count``). Values are hashed onto PK local key rows; within one
-    # batch step concurrent requests on a shared key over-admit — the same
-    # check-then-act class the reference tolerates across threads.
-    PK = spec.param_keys_per_shard
-    is_p = proceed & batch.is_param
-    pstate = state.params
-    if PK:
-        latest_p = window_sum_all(w, pstate, ev.PASS, now_idx).astype(jnp.float32)
-        prow = batch.param_rows                               # [Bl, PV]
-        live = (prow >= 0) & (prow < PK) & is_p[:, None]
-        thr_p = batch.param_count * jnp.where(
-            table.is_global[rows], 1.0, conn[rows])[:, None]  # [Bl, PV]
-        acq_f = batch.acquire.astype(jnp.float32)[:, None]
+    with jax.named_scope("token.param"):
+        # ---- hot-param admission (ClusterParamFlowChecker.acquireClusterToken) ----
+        # Per-value avg vs calcGlobalThreshold; a request passes iff EVERY carried
+        # value fits, and only then are all its values counted (reference
+        # semantics; the host resolves per-item threshold overrides into
+        # ``param_count``). Values are hashed onto PK local key rows; within one
+        # batch step concurrent requests on a shared key over-admit — the same
+        # check-then-act class the reference tolerates across threads.
+        PK = spec.param_keys_per_shard
+        is_p = proceed & batch.is_param
+        pstate = state.params
+        if PK:
+            latest_p = window_sum_all(w, pstate, ev.PASS, now_idx).astype(jnp.float32)
+            prow = batch.param_rows                               # [Bl, PV]
+            live = (prow >= 0) & (prow < PK) & is_p[:, None]
+            thr_p = batch.param_count * jnp.where(
+                table.is_global[rows], 1.0, conn[rows])[:, None]  # [Bl, PV]
+            acq_f = batch.acquire.astype(jnp.float32)[:, None]
 
-        # within-batch exact admission: greedy segment admit over flattened
-        # (request × value) rows sharing a key, like the flow path. A value
-        # row admitted for a request that ultimately fails on ANOTHER value
-        # still reserves quota within this batch (bounded under-admission) —
-        # but its count is never recorded, so nothing leaks across steps.
-        flat_keys = jnp.where(live, prow, PK).reshape(-1)     # [Bl·PV]
-        order_p = seg.sort_by_keys(flat_keys)
-        keys_s = flat_keys[order_p]
-        starts_p = seg.segment_starts(keys_s, jnp.zeros_like(keys_s))
-        leader_p = seg.segment_leader_index(starts_p)
-        acq_flat_s = jnp.where(live, acq_f, 0.0).reshape(-1)[order_p]
-        safe_keys_s = jnp.minimum(keys_s, PK - 1)
-        base_s = latest_p[safe_keys_s]
-        lim_s = jnp.where(keys_s < PK, thr_p.reshape(-1)[order_p], jnp.inf)
-        ok_s = seg.greedy_admit(base_s, acq_flat_s, lim_s, starts_p, leader_p)
-        excl_p, _ = seg.segment_prefix_sum(
-            jnp.where(ok_s, acq_flat_s, 0.0), starts_p, leader_p)
-        rem_flat_s = lim_s - base_s - excl_p - acq_flat_s
-        row_ok = seg.unsort(order_p, ok_s.astype(jnp.int32)).reshape(
-            (Bl, -1)).astype(jnp.bool_)
-        rem_flat = seg.unsort(
-            order_p, jnp.where(jnp.isfinite(rem_flat_s), rem_flat_s, 0.0)
-        ).reshape((Bl, -1))
+            # within-batch exact admission: greedy segment admit over flattened
+            # (request × value) rows sharing a key, like the flow path. A value
+            # row admitted for a request that ultimately fails on ANOTHER value
+            # still reserves quota within this batch (bounded under-admission) —
+            # but its count is never recorded, so nothing leaks across steps.
+            flat_keys = jnp.where(live, prow, PK).reshape(-1)     # [Bl·PV]
+            order_p = seg.sort_by_keys(flat_keys)
+            keys_s = flat_keys[order_p]
+            starts_p = seg.segment_starts(keys_s, jnp.zeros_like(keys_s))
+            leader_p = seg.segment_leader_index(starts_p)
+            acq_flat_s = jnp.where(live, acq_f, 0.0).reshape(-1)[order_p]
+            safe_keys_s = jnp.minimum(keys_s, PK - 1)
+            base_s = latest_p[safe_keys_s]
+            lim_s = jnp.where(keys_s < PK, thr_p.reshape(-1)[order_p], jnp.inf)
+            ok_s = seg.greedy_admit(base_s, acq_flat_s, lim_s, starts_p, leader_p)
+            excl_p, _ = seg.segment_prefix_sum(
+                jnp.where(ok_s, acq_flat_s, 0.0), starts_p, leader_p)
+            rem_flat_s = lim_s - base_s - excl_p - acq_flat_s
+            row_ok = seg.unsort(order_p, ok_s.astype(jnp.int32)).reshape(
+                (Bl, -1)).astype(jnp.bool_)
+            rem_flat = seg.unsort(
+                order_p, jnp.where(jnp.isfinite(rem_flat_s), rem_flat_s, 0.0)
+            ).reshape((Bl, -1))
 
-        any_live = jnp.any(live, axis=1)
-        all_ok = jnp.all(row_ok | ~live, axis=1)
-        param_pass = is_p & (all_ok | ~any_live)
-        param_block = is_p & any_live & ~all_ok
-        # remaining meaningful only for single-value requests (host packs
-        # values densely from column 0); multi-value → -1 like the reference
-        nlive = jnp.sum(live.astype(jnp.int32), axis=1)
-        rem1 = jnp.maximum(rem_flat[:, 0], 0.0)
-        rem_p = jnp.where(nlive == 1, rem1, -1.0).astype(jnp.int32)
+            any_live = jnp.any(live, axis=1)
+            all_ok = jnp.all(row_ok | ~live, axis=1)
+            param_pass = is_p & (all_ok | ~any_live)
+            param_block = is_p & any_live & ~all_ok
+            # remaining meaningful only for single-value requests (host packs
+            # values densely from column 0); multi-value → -1 like the reference
+            nlive = jnp.sum(live.astype(jnp.int32), axis=1)
+            rem1 = jnp.maximum(rem_flat[:, 0], 0.0)
+            rem_p = jnp.where(nlive == 1, rem1, -1.0).astype(jnp.int32)
 
-        from sentinel_tpu.stats.window import add_rows as _add, refresh_rows as _refresh
-        flat = jnp.where(live & param_pass[:, None], prow, PK).reshape(-1)
-        pstate = _refresh(w, pstate, flat, now_idx)
-        pstate = _add(w, pstate, flat, ev.PASS,
-                      jnp.where(live & param_pass[:, None],
-                                batch.acquire[:, None], 0).reshape(-1), now_idx)
-    else:
-        param_pass = is_p          # param slot disabled: empty-values → OK
-        param_block = jnp.zeros_like(is_p)
-        rem_p = jnp.full((Bl,), -1, jnp.int32)
+            from sentinel_tpu.stats.window import add_rows as _add, refresh_rows as _refresh
+            flat = jnp.where(live & param_pass[:, None], prow, PK).reshape(-1)
+            pstate = _refresh(w, pstate, flat, now_idx)
+            pstate = _add(w, pstate, flat, ev.PASS,
+                          jnp.where(live & param_pass[:, None],
+                                    batch.acquire[:, None], 0).reshape(-1), now_idx)
+        else:
+            param_pass = is_p          # param slot disabled: empty-values → OK
+            param_block = jnp.zeros_like(is_p)
+            rem_p = jnp.full((Bl,), -1, jnp.int32)
 
     # ---- record (post-decision, like StatisticSlot ordering) ----
     pad = jnp.int32(L)
@@ -290,25 +296,38 @@ def _shard_step(
 
     flows = state.flows
     from sentinel_tpu.stats.window import add_rows, refresh_rows
-    flows = refresh_rows(w, flows, tgt(proceed), now_idx)
     acq = batch.acquire
-    flows = add_rows(w, flows, tgt(admitted), ev.PASS, jnp.where(admitted, acq, 0), now_idx)
-    flows = add_rows(w, flows, tgt(admitted), ev.PASS_REQUEST,
-                     jnp.where(admitted, 1, 0), now_idx)
-    flows = add_rows(w, flows, tgt(admitted & batch.prioritized), ev.OCCUPIED_PASS,
-                     jnp.where(admitted & batch.prioritized, acq, 0), now_idx)
-    flows = add_rows(w, flows, tgt(blocked), ev.BLOCK, jnp.where(blocked, acq, 0), now_idx)
-    flows = add_rows(w, flows, tgt(blocked), ev.BLOCK_REQUEST,
-                     jnp.where(blocked, 1, 0), now_idx)
-    flows = add_rows(w, flows, tgt(should_wait), ev.WAITING,
-                     jnp.where(should_wait, acq, 0), now_idx)
+    with jax.named_scope("token.refresh"):
+        flows = refresh_rows(w, flows, tgt(proceed), now_idx)
+    with jax.named_scope("token.add.pass"):
+        flows = add_rows(w, flows, tgt(admitted), ev.PASS,
+                         jnp.where(admitted, acq, 0), now_idx)
+        flows = add_rows(w, flows, tgt(admitted), ev.PASS_REQUEST,
+                         jnp.where(admitted, 1, 0), now_idx)
+        flows = add_rows(w, flows, tgt(admitted & batch.prioritized),
+                         ev.OCCUPIED_PASS,
+                         jnp.where(admitted & batch.prioritized, acq, 0),
+                         now_idx)
+    with jax.named_scope("token.add.block"):
+        flows = add_rows(w, flows, tgt(blocked), ev.BLOCK,
+                         jnp.where(blocked, acq, 0), now_idx)
+        flows = add_rows(w, flows, tgt(blocked), ev.BLOCK_REQUEST,
+                         jnp.where(blocked, 1, 0), now_idx)
+    with jax.named_scope("token.add.wait"):
+        flows = add_rows(w, flows, tgt(should_wait), ev.WAITING,
+                         jnp.where(should_wait, acq, 0), now_idx)
 
-    ns_state = state.ns
-    ns_state = refresh_rows(w, ns_state, ns_req, now_idx)
-    ns_state = add_rows(w, ns_state, jnp.where(proceed, ns_req, jnp.int32(NS)),
-                        ev.PASS, jnp.where(proceed, 1, 0), now_idx)
-    ns_state = add_rows(w, ns_state, jnp.where(active & ~limiter_ok, ns_req, jnp.int32(NS)),
-                        ev.BLOCK, jnp.where(active & ~limiter_ok, 1, 0), now_idx)
+    with jax.named_scope("token.ns"):
+        ns_state = state.ns
+        ns_state = refresh_rows(w, ns_state, ns_req, now_idx)
+        ns_state = add_rows(w, ns_state,
+                            jnp.where(proceed, ns_req, jnp.int32(NS)),
+                            ev.PASS, jnp.where(proceed, 1, 0), now_idx)
+        ns_state = add_rows(w, ns_state,
+                            jnp.where(active & ~limiter_ok, ns_req,
+                                      jnp.int32(NS)),
+                            ev.BLOCK, jnp.where(active & ~limiter_ok, 1, 0),
+                            now_idx)
 
     status = jnp.full((Bl,), STATUS_FAIL, jnp.int32)
     status = jnp.where(batch.valid & ~table.active[rows], STATUS_NO_RULE_EXISTS, status)
@@ -385,8 +404,13 @@ class ClusterEngine:
     """
 
     def __init__(self, spec: ClusterSpec, mesh: Optional[Mesh] = None,
-                 default_ns_qps: float = 30_000.0):
+                 default_ns_qps: float = 30_000.0,
+                 obs: Optional[RuntimeObs] = None):
         self.spec = spec
+        # phases of the engine call (token.route / put / dispatch /
+        # readback / gather; docs/OBSERVABILITY.md) and the token
+        # server's cycle record here: ClusterTokenServer uses engine.obs
+        self.obs = obs if obs is not None else RuntimeObs()
         if mesh is None:
             devs = jax.devices()[:spec.n_shards]
             if len(devs) < spec.n_shards:
@@ -696,13 +720,17 @@ class ClusterEngine:
     def _gather_results(self, verdicts, per_shard, results, S, blp):
         """Deferred readback: materialize the verdict arrays and scatter
         them back into request order (shared by flow + param paths)."""
-        st = self._to_host(verdicts.status).reshape(S, blp)
-        wt = self._to_host(verdicts.wait_ms).reshape(S, blp)
-        rm = self._to_host(verdicts.remaining).reshape(S, blp)
-        for s in range(S):
-            for k, i in enumerate(per_shard[s]):
-                results[i] = (int(st[s, k]), int(wt[s, k]), int(rm[s, k]))
-        return [r or (STATUS_FAIL, 0, 0) for r in results]
+        n = len(results)
+        with self.obs.phase("token.readback", n=n):
+            st = self._to_host(verdicts.status).reshape(S, blp)
+            wt = self._to_host(verdicts.wait_ms).reshape(S, blp)
+            rm = self._to_host(verdicts.remaining).reshape(S, blp)
+        with self.obs.phase("token.gather", n=n):
+            for s in range(S):
+                for k, i in enumerate(per_shard[s]):
+                    results[i] = (int(st[s, k]), int(wt[s, k]),
+                                  int(rm[s, k]))
+            return [r or (STATUS_FAIL, 0, 0) for r in results]
 
     def _alloc_row(self) -> int:
         L = self.spec.flows_per_shard
@@ -784,43 +812,44 @@ class ClusterEngine:
         L = self.spec.flows_per_shard
 
         with self._lock:
-            vec = self._vector_prep(flow_ids, acquire, prioritized, n, S, L)
-            if vec is not None:
-                prep, gather = vec
-                if prep is None:        # nothing routable: results are final
-                    return PendingTokenResults(lambda: gather)
-                rows, acq, prio, valid, blp = prep
-            else:
-                if prioritized is None:     # numpy arrays: no truthiness
-                    prioritized = [False] * n
-                per_shard: List[List[int]] = [[] for _ in range(S)]
-                results: List[Optional[Tuple[int, int, int]]] = [None] * n
-                for i, fid in enumerate(flow_ids):
-                    row = self._flow_to_row.get(int(fid))
-                    if acquire[i] <= 0:
-                        # DefaultTokenService.requestToken count validation
-                        results[i] = (STATUS_BAD_REQUEST, 0, 0)
-                    elif row is None:
-                        results[i] = (STATUS_NO_RULE_EXISTS, 0, 0)
-                    else:
-                        per_shard[row // L].append(i)
+            with self.obs.phase("token.route", n=n):
+                vec = self._vector_prep(flow_ids, acquire, prioritized, n, S, L)
+                if vec is not None:
+                    prep, gather = vec
+                    if prep is None:        # nothing routable: results are final
+                        return PendingTokenResults(lambda: gather)
+                    rows, acq, prio, valid, blp = prep
+                else:
+                    if prioritized is None:     # numpy arrays: no truthiness
+                        prioritized = [False] * n
+                    per_shard: List[List[int]] = [[] for _ in range(S)]
+                    results: List[Optional[Tuple[int, int, int]]] = [None] * n
+                    for i, fid in enumerate(flow_ids):
+                        row = self._flow_to_row.get(int(fid))
+                        if acquire[i] <= 0:
+                            # DefaultTokenService.requestToken count validation
+                            results[i] = (STATUS_BAD_REQUEST, 0, 0)
+                        elif row is None:
+                            results[i] = (STATUS_NO_RULE_EXISTS, 0, 0)
+                        else:
+                            per_shard[row // L].append(i)
 
-                bl = max((len(p) for p in per_shard), default=0)
-                if bl == 0:
-                    out = [r or (STATUS_FAIL, 0, 0) for r in results]
-                    return PendingTokenResults(lambda: out)
-                blp = pad_pow2(bl)
+                    bl = max((len(p) for p in per_shard), default=0)
+                    if bl == 0:
+                        out = [r or (STATUS_FAIL, 0, 0) for r in results]
+                        return PendingTokenResults(lambda: out)
+                    blp = pad_pow2(bl)
 
-                rows = np.zeros((S, blp), np.int32)
-                acq = np.zeros((S, blp), np.int32)
-                prio = np.zeros((S, blp), np.bool_)
-                valid = np.zeros((S, blp), np.bool_)
-                for s in range(S):
-                    for k, i in enumerate(per_shard[s]):
-                        rows[s, k] = self._flow_to_row[int(flow_ids[i])] % L
-                        acq[s, k] = acquire[i]
-                        prio[s, k] = bool(prioritized[i])
-                        valid[s, k] = True
+                    rows = np.zeros((S, blp), np.int32)
+                    acq = np.zeros((S, blp), np.int32)
+                    prio = np.zeros((S, blp), np.bool_)
+                    valid = np.zeros((S, blp), np.bool_)
+                    for s in range(S):
+                        for k, i in enumerate(per_shard[s]):
+                            rows[s, k] = self._flow_to_row[int(flow_ids[i])] % L
+                            acq[s, k] = acquire[i]
+                            prio[s, k] = bool(prioritized[i])
+                            valid[s, k] = True
 
             verdicts = self.step_routed(rows, acq, prio, valid, blp,
                                         now_ms=now_ms)
@@ -847,34 +876,43 @@ class ClusterEngine:
         S = self.spec.n_shards
         PV = self.spec.max_params
         PK = self.spec.param_keys_per_shard
+        obs = self.obs
+        lanes = S * blp
         with self._lock:
-            batch = self._put_rows(TokenBatch(
-                local_rows=rows.reshape(-1).astype(np.int32),
-                acquire=acq.reshape(-1).astype(np.int32),
-                prioritized=prio.reshape(-1).astype(np.bool_),
-                valid=valid.reshape(-1).astype(np.bool_),
-                is_param=np.zeros((S * blp,), np.bool_),
-                param_rows=np.full((S * blp, PV), PK, np.int32),
-                param_count=np.zeros((S * blp, PV), np.float32)))
+            with obs.phase("token.put", n=lanes):
+                batch = self._put_rows(TokenBatch(
+                    local_rows=rows.reshape(-1).astype(np.int32),
+                    acquire=acq.reshape(-1).astype(np.int32),
+                    prioritized=prio.reshape(-1).astype(np.bool_),
+                    valid=valid.reshape(-1).astype(np.bool_),
+                    is_param=np.zeros((lanes,), np.bool_),
+                    param_rows=np.full((lanes, PV), PK, np.int32),
+                    param_count=np.zeros((lanes, PV), np.float32)))
 
-            w = self.spec.window
-            if self._multiprocess:
-                # scalars must be placed on every process's local devices
-                # (an uncommitted single-device array is not addressable
-                # by the other hosts of the global mesh)
-                now_idx = jax.device_put(
-                    np.int32(w.index_of(now_ms)), self._sh_rep)
-                in_win = jax.device_put(
-                    np.int32(now_ms % w.win_ms), self._sh_rep)
-            else:
-                now_idx = jnp.int32(w.index_of(now_ms))
-                in_win = jnp.int32(now_ms % w.win_ms)
-            self.state, verdicts = self._step(
-                self._table, self.state, batch,
-                jax.device_put(jnp.asarray(self._connected), self._sh_rep),
-                jax.device_put(jnp.asarray(self._ns_limit), self._sh_rep),
-                now_idx, in_win)
-        self._maybe_start_host_copy(verdicts)
+                w = self.spec.window
+                if self._multiprocess:
+                    # scalars must be placed on every process's local
+                    # devices (an uncommitted single-device array is not
+                    # addressable by the other hosts of the global mesh)
+                    now_idx = jax.device_put(
+                        np.int32(w.index_of(now_ms)), self._sh_rep)
+                    in_win = jax.device_put(
+                        np.int32(now_ms % w.win_ms), self._sh_rep)
+                else:
+                    now_idx = jnp.int32(w.index_of(now_ms))
+                    in_win = jnp.int32(now_ms % w.win_ms)
+                connected = jax.device_put(
+                    jnp.asarray(self._connected), self._sh_rep)
+                ns_limit = jax.device_put(
+                    jnp.asarray(self._ns_limit), self._sh_rep)
+            # the verdicts' host copy starts under the lock now: the one
+            # single-process caller holds this reentrant lock around the
+            # call already, and multi-process readback starts no copy
+            with obs.phase("token.dispatch", n=lanes):
+                self.state, verdicts = self._step(
+                    self._table, self.state, batch, connected, ns_limit,
+                    now_idx, in_win)
+                self._maybe_start_host_copy(verdicts)
         return verdicts
 
     def _put_rows(self, tree):
@@ -940,10 +978,14 @@ class ClusterEngine:
 
     def _gather_results_vec(self, verdicts, plan, blp):
         """Vectorized inverse of :meth:`_vector_prep`'s grouping."""
-        return shard_math.scatter_verdicts(
-            plan, blp, self._to_host(verdicts.status),
-            self._to_host(verdicts.wait_ms),
-            self._to_host(verdicts.remaining), self.spec.n_shards)
+        n = plan.status0.shape[0]
+        with self.obs.phase("token.readback", n=n):
+            status = self._to_host(verdicts.status)
+            wait_ms = self._to_host(verdicts.wait_ms)
+            remaining = self._to_host(verdicts.remaining)
+        with self.obs.phase("token.gather", n=n):
+            return shard_math.scatter_verdicts(
+                plan, blp, status, wait_ms, remaining, self.spec.n_shards)
 
     def top_params(self, flow_id: int, *, now_ms: int,
                    top_n: int = 10) -> Dict[object, int]:

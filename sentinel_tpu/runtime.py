@@ -2217,57 +2217,56 @@ class Sentinel:
         obs_on = obs.enabled
         tr = (trace_id or obs.spans.maybe_trace()) if obs_on else 0
         t0 = obs.spans.now_ns() if obs_on else 0
-        if isinstance(resources, np.ndarray) and resources.dtype.kind in "iu":
-            rows = np.ascontiguousarray(resources, np.int32)
-            resources = None
-        else:
-            batch_intern = getattr(self.resources, "get_or_create_batch",
-                                   None)
-            if batch_intern is not None:  # native table: one FFI call, no GIL
-                rows = batch_intern(resources)
+        with obs.phase("entry.prep", n=n, trace=tr):
+            if isinstance(resources, np.ndarray) and resources.dtype.kind in "iu":
+                rows = np.ascontiguousarray(resources, np.int32)
+                resources = None
             else:
-                rows = np.fromiter(
-                    (self.resources.get_or_create(r) for r in resources),
-                    np.int32, count=n)
-            # tiering: classify hot hit / cold miss and queue promotions
-            # for any re-interned cold keys (restored in this dispatch's
-            # eviction drain, before its decide)
-            self.tiering.note_interned(resources, rows)
-        if resources is None and (self._host_gates  # graftlint: disable=LOCK002 -- hot-path feature gate: a stale read routes one batch through the exact device path, never unsafely
-                                  or self._cluster_rules_by_row
-                                  or self._cluster_param_rules_by_row):
-            # gates and cluster delegation are name-keyed SPI surfaces;
-            # materialize names once for the whole batch (rare combination)
-            resources = [self.resources.name_of(int(r)) or "" for r in rows]
-        param_rules = param_keys = None
-        param_gen = -1
-        with self._lock:
-            compiled = self._param
-            registry = self.param_key_registry
-            gen = self._param_gen
-        origin_ids = np.zeros(n, np.int32)
-        origin_rows = np.full(n, self.spec.alt_rows, np.int32)
-        context_ids = np.zeros(n, np.int32)
-        chain_rows = np.full(n, self.spec.alt_rows, np.int32)
-        if origins is not None:
-            for i, o in enumerate(origins):
-                if o:
-                    oid = self.origins.get_or_create(o)
-                    origin_ids[i] = oid
-                    origin_rows[i] = self._alt_row(int(rows[i]), 0, oid)
-        if contexts is not None:
-            for i, c in enumerate(contexts):
-                if c and c != "sentinel_default_context":
-                    cid = self.contexts.get_or_create(c)
-                    context_ids[i] = cid
-                    chain_rows[i] = self._alt_row(int(rows[i]), 1, cid)
-        acq = np.asarray(acquire, np.int32) if acquire is not None else np.ones(n, np.int32)
-        is_in = (np.asarray(entry_types, np.int32) == ENTRY_TYPE_IN) \
-            if entry_types is not None else np.ones(n, np.bool_)
-        prio = np.asarray(prioritized, np.bool_) if prioritized is not None \
-            else np.zeros(n, np.bool_)
-        if tr:
-            obs.spans.record(tr, "entry.prep", t0, obs.spans.now_ns(), n=n)
+                batch_intern = getattr(self.resources, "get_or_create_batch",
+                                       None)
+                if batch_intern is not None:  # native table: one FFI call, no GIL
+                    rows = batch_intern(resources)
+                else:
+                    rows = np.fromiter(
+                        (self.resources.get_or_create(r) for r in resources),
+                        np.int32, count=n)
+                # tiering: classify hot hit / cold miss and queue promotions
+                # for any re-interned cold keys (restored in this dispatch's
+                # eviction drain, before its decide)
+                self.tiering.note_interned(resources, rows)
+            if resources is None and (self._host_gates  # graftlint: disable=LOCK002 -- hot-path feature gate: a stale read routes one batch through the exact device path, never unsafely
+                                      or self._cluster_rules_by_row
+                                      or self._cluster_param_rules_by_row):
+                # gates and cluster delegation are name-keyed SPI surfaces;
+                # materialize names once for the whole batch (rare combination)
+                resources = [self.resources.name_of(int(r)) or "" for r in rows]
+            param_rules = param_keys = None
+            param_gen = -1
+            with self._lock:
+                compiled = self._param
+                registry = self.param_key_registry
+                gen = self._param_gen
+            origin_ids = np.zeros(n, np.int32)
+            origin_rows = np.full(n, self.spec.alt_rows, np.int32)
+            context_ids = np.zeros(n, np.int32)
+            chain_rows = np.full(n, self.spec.alt_rows, np.int32)
+            if origins is not None:
+                for i, o in enumerate(origins):
+                    if o:
+                        oid = self.origins.get_or_create(o)
+                        origin_ids[i] = oid
+                        origin_rows[i] = self._alt_row(int(rows[i]), 0, oid)
+            if contexts is not None:
+                for i, c in enumerate(contexts):
+                    if c and c != "sentinel_default_context":
+                        cid = self.contexts.get_or_create(c)
+                        context_ids[i] = cid
+                        chain_rows[i] = self._alt_row(int(rows[i]), 1, cid)
+            acq = np.asarray(acquire, np.int32) if acquire is not None else np.ones(n, np.int32)
+            is_in = (np.asarray(entry_types, np.int32) == ENTRY_TYPE_IN) \
+                if entry_types is not None else np.ones(n, np.bool_)
+            prio = np.asarray(prioritized, np.bool_) if prioritized is not None \
+                else np.zeros(n, np.bool_)
 
         # user host gates veto first (slot-chain SPI tier 1); denials are
         # logged in the gate runner and device-recorded batched below.
@@ -2761,127 +2760,129 @@ class Sentinel:
                     count_thread=count_thread, record_block=record_block,
                     now=now, trace_id=tr)
 
-        staged: list = []
-        batch = self._build_entry_batch(
-            rows, origin_ids, origin_rows, context_ids, chain_rows,
-            acquire, is_in, prioritized, vfull, param_rules, param_keys,
-            cluster_fallback, count_thread, record_block, staged=staged)
-        # no_alt_rows (computed above) is about ROWS only: batches with no
-        # real origin/chain rows take the *_noalt step variants (the
-        # alt-table scatters compile away; origin ids without rows are
-        # fine for the elision — the fast path matches them by ID)
-        times = self._time_scalars(now)
-        load1, cpu = self._cpu.sample()
-        sys_scalars = jnp.asarray(np.array([load1, cpu], np.float32))
-        with self._lock:
-            # gen check must happen under the same lock that guards reloads,
-            # or a reload racing here could land stale pairs on the new table
-            if batch.param_rules is not None and param_gen != self._param_gen:
-                batch = batch._replace(param_rules=None, param_keys=None)
-            now, times = self._restamp_if_stale_locked(at_ms, now, times)
-            self._drain_evictions_locked()
-            # hot-set sketch observe (tiering): single-dispatch engines
-            # fuse the scatter-max INTO the decide program below (round
-            # 16 — the sketch rides as a donated operand); the legacy
-            # standalone dispatch stays as the disabled/fallback path.
-            # Padding lanes are valid=False no-ops either way.
-            sd_sketch = (self.tiering.sketch_for_fuse_locked()
-                         if self._single_dispatch else None)
-            observed = False
-            if sd_sketch is None:
-                observed = self.tiering.observe_locked(batch.rows,
-                                                       batch.valid)
-            self._seen_idx = max(self._seen_idx,
-                                 self.spec.second.index_of(now))
-            # static occupy variant: the occupy-aware pipeline runs only
-            # when this batch is prioritized OR a previous booking can
-            # still be live (bookings last ≤ B+1 windows); everything else
-            # compiles to a pipeline with zero occupy code
-            if any_prio:
-                self._occupy_live_until_ms = now + (
-                    (self.spec.second.buckets + 1)
-                    * self.spec.second.win_ms)
-            use_occ = any_prio or now < self._occupy_live_until_ms
-            if no_alt_rows:
-                decide = (self._jit_decide_prio_noalt if use_occ
-                          else self._jit_decide_noalt)
-            else:
-                decide = (self._jit_decide_prio if use_occ
-                          else self._jit_decide)
-            flags = {"skip_auth": self._skip_auth,
-                     "skip_sys": self._skip_sys,
-                     "skip_threads": self._skip_threads}
-            if self._sortfree:
-                # conditional key presence: with sortfree disabled the
-                # flags dict — hence every cached program key — is
-                # byte-identical to pre-round-10 builds
-                flags["sortfree"] = True
-            if (no_alt_rows and no_origin_ids and not any_prio
-                    and cluster_fallback is None and acq_uniform):
-                # scalar admission path (rules/flow.flow_check_scalar);
-                # requires the row-based no_alt (the step variant must be
-                # record_alt=False for the scalar assertion). Live occupy
-                # bookings are fine: the occupy step variant folds them
-                # into the QPS base (occupy_base) — this path never books
-                flags["scalar_flow"] = True
-                flags["scalar_has_rl"] = self._scalar_has_rl
-            elif acq_uniform and key_fits:
-                # fast general path: origins/alt rows/fallback bits live,
-                # rank closed-form admission (rules/flow.flow_check_fast);
-                # with prioritized events or live bookings the occupy-
-                # capable variant runs (flow_check_fast_occupy) — no more
-                # whole-batch demotion to the sorted path
-                flags["fast_flow"] = True
-                flags["scalar_has_rl"] = self._scalar_has_rl
-            if sd_sketch is not None:
-                dec_sd = self._sd_steps_locked()["decide"][
-                    (2 if no_alt_rows else 0) + (1 if use_occ else 0)]
-                self._note_program_locked("decide_sd", dec_sd, batch, flags)
-                with obs.annotate("sentinel_tpu.decide"):
-                    state, verdicts, new_sketch = dec_sd(
-                        self._ruleset, self._state, sd_sketch, batch,
-                        times, sys_scalars, **flags)
-                self.tiering.set_sketch_locked(new_sketch)
-            else:
-                self._note_program_locked("decide", decide, batch, flags)
-                with obs.annotate("sentinel_tpu.decide"):
-                    state, verdicts = decide(
-                        self._ruleset, self._state, batch, times,
-                        sys_scalars, **flags)
-            self._state = state
-            # breaker observers: ride the existing readback (seq taken
-            # under the dispatch lock so diffs land in dispatch order)
-            brk = None
-            if self._breaker_observers:
-                self._breaker_seq += 1
-                brk = (self._breaker_seq, self._deg.rules,
-                       self._breaker_snapshot_locked())
-        start_host_copy((verdicts.allow, verdicts.reason, verdicts.wait_ms)
-                        + ((brk[2],) if brk else ()))
-        t_disp = 0
-        if obs_on:
-            # which path this whole batch took (flags/use_occ were fixed
-            # under the dispatch lock)
-            if "scalar_flow" in flags:
-                route = obs_keys.ROUTE_SCALAR
-            elif "fast_flow" in flags:
-                route = (obs_keys.ROUTE_FAST_OCCUPY if use_occ
-                         else obs_keys.ROUTE_FAST)
-            else:
-                route = obs_keys.ROUTE_GENERAL
-            obs.counters.add(route)
-            if "sortfree" in flags:
-                obs.counters.add(obs_keys.ROUTE_SORTFREE)
-            if self.mesh is not None:
-                obs.counters.add(obs_keys.ROUTE_MESHED)
-            obs.counters.add(obs_keys.PIPE_DISPATCH,
-                             2 if observed else 1)
-            if sd_sketch is not None:
-                obs.counters.add(obs_keys.ROUTE_SINGLE_DISPATCH)
-            t_disp = obs.spans.now_ns()
-            if tr:
-                obs.spans.record(tr, "decide.dispatch", t_d0, t_disp, n=n,
-                                 note=route.split(".", 1)[1])
+        # the whole-batch route: from the batch build to the dispatch
+        # returning (the split route above records split.dispatch)
+        with obs.phase("decide.dispatch", n=n, trace=tr) as dispatch:
+            staged: list = []
+            batch = self._build_entry_batch(
+                rows, origin_ids, origin_rows, context_ids, chain_rows,
+                acquire, is_in, prioritized, vfull, param_rules, param_keys,
+                cluster_fallback, count_thread, record_block, staged=staged)
+            # no_alt_rows (computed above) is about ROWS only: batches with no
+            # real origin/chain rows take the *_noalt step variants (the
+            # alt-table scatters compile away; origin ids without rows are
+            # fine for the elision — the fast path matches them by ID)
+            times = self._time_scalars(now)
+            load1, cpu = self._cpu.sample()
+            sys_scalars = jnp.asarray(np.array([load1, cpu], np.float32))
+            lock_wait = obs.phase("engine.lock_wait", n=n, trace=tr).start()
+            with self._lock:
+                lock_wait.stop()
+                # gen check must happen under the same lock that guards reloads,
+                # or a reload racing here could land stale pairs on the new table
+                if batch.param_rules is not None and param_gen != self._param_gen:
+                    batch = batch._replace(param_rules=None, param_keys=None)
+                now, times = self._restamp_if_stale_locked(at_ms, now, times)
+                self._drain_evictions_locked()
+                # hot-set sketch observe (tiering): single-dispatch engines
+                # fuse the scatter-max INTO the decide program below (round
+                # 16 — the sketch rides as a donated operand); the legacy
+                # standalone dispatch stays as the disabled/fallback path.
+                # Padding lanes are valid=False no-ops either way.
+                sd_sketch = (self.tiering.sketch_for_fuse_locked()
+                             if self._single_dispatch else None)
+                observed = False
+                if sd_sketch is None:
+                    observed = self.tiering.observe_locked(batch.rows,
+                                                           batch.valid)
+                self._seen_idx = max(self._seen_idx,
+                                     self.spec.second.index_of(now))
+                # static occupy variant: the occupy-aware pipeline runs only
+                # when this batch is prioritized OR a previous booking can
+                # still be live (bookings last ≤ B+1 windows); everything else
+                # compiles to a pipeline with zero occupy code
+                if any_prio:
+                    self._occupy_live_until_ms = now + (
+                        (self.spec.second.buckets + 1)
+                        * self.spec.second.win_ms)
+                use_occ = any_prio or now < self._occupy_live_until_ms
+                if no_alt_rows:
+                    decide = (self._jit_decide_prio_noalt if use_occ
+                              else self._jit_decide_noalt)
+                else:
+                    decide = (self._jit_decide_prio if use_occ
+                              else self._jit_decide)
+                flags = {"skip_auth": self._skip_auth,
+                         "skip_sys": self._skip_sys,
+                         "skip_threads": self._skip_threads}
+                if self._sortfree:
+                    # conditional key presence: with sortfree disabled the
+                    # flags dict — hence every cached program key — is
+                    # byte-identical to pre-round-10 builds
+                    flags["sortfree"] = True
+                if (no_alt_rows and no_origin_ids and not any_prio
+                        and cluster_fallback is None and acq_uniform):
+                    # scalar admission path (rules/flow.flow_check_scalar);
+                    # requires the row-based no_alt (the step variant must be
+                    # record_alt=False for the scalar assertion). Live occupy
+                    # bookings are fine: the occupy step variant folds them
+                    # into the QPS base (occupy_base) — this path never books
+                    flags["scalar_flow"] = True
+                    flags["scalar_has_rl"] = self._scalar_has_rl
+                elif acq_uniform and key_fits:
+                    # fast general path: origins/alt rows/fallback bits live,
+                    # rank closed-form admission (rules/flow.flow_check_fast);
+                    # with prioritized events or live bookings the occupy-
+                    # capable variant runs (flow_check_fast_occupy) — no more
+                    # whole-batch demotion to the sorted path
+                    flags["fast_flow"] = True
+                    flags["scalar_has_rl"] = self._scalar_has_rl
+                if sd_sketch is not None:
+                    dec_sd = self._sd_steps_locked()["decide"][
+                        (2 if no_alt_rows else 0) + (1 if use_occ else 0)]
+                    self._note_program_locked("decide_sd", dec_sd, batch, flags)
+                    with obs.annotate("sentinel_tpu.decide"):
+                        state, verdicts, new_sketch = dec_sd(
+                            self._ruleset, self._state, sd_sketch, batch,
+                            times, sys_scalars, **flags)
+                    self.tiering.set_sketch_locked(new_sketch)
+                else:
+                    self._note_program_locked("decide", decide, batch, flags)
+                    with obs.annotate("sentinel_tpu.decide"):
+                        state, verdicts = decide(
+                            self._ruleset, self._state, batch, times,
+                            sys_scalars, **flags)
+                self._state = state
+                # breaker observers: ride the existing readback (seq taken
+                # under the dispatch lock so diffs land in dispatch order)
+                brk = None
+                if self._breaker_observers:
+                    self._breaker_seq += 1
+                    brk = (self._breaker_seq, self._deg.rules,
+                           self._breaker_snapshot_locked())
+            start_host_copy((verdicts.allow, verdicts.reason, verdicts.wait_ms)
+                            + ((brk[2],) if brk else ()))
+            if obs_on:
+                # which path this whole batch took (flags/use_occ were fixed
+                # under the dispatch lock)
+                if "scalar_flow" in flags:
+                    route = obs_keys.ROUTE_SCALAR
+                elif "fast_flow" in flags:
+                    route = (obs_keys.ROUTE_FAST_OCCUPY if use_occ
+                             else obs_keys.ROUTE_FAST)
+                else:
+                    route = obs_keys.ROUTE_GENERAL
+                obs.counters.add(route)
+                if "sortfree" in flags:
+                    obs.counters.add(obs_keys.ROUTE_SORTFREE)
+                if self.mesh is not None:
+                    obs.counters.add(obs_keys.ROUTE_MESHED)
+                obs.counters.add(obs_keys.PIPE_DISPATCH,
+                                 2 if observed else 1)
+                if sd_sketch is not None:
+                    obs.counters.add(obs_keys.ROUTE_SINGLE_DISPATCH)
+                dispatch.note = route.split(".", 1)[1]
+        t_disp = obs.spans.now_ns() if obs_on else 0
         prio_np_full = prio_np if any_prio else None
 
         def _read() -> Verdicts:
@@ -2927,9 +2928,11 @@ class Sentinel:
         but are different executables (``decide`` / ``decide_sd`` /
         ``fused`` / ``fused_sd`` / ``fused_sd_epi``)."""
         geometry = (int(batch.rows.shape[0]),)
+        columns = tuple(c is not None for c in batch)
         if xbatch is not None:
             geometry += (int(xbatch.rows.shape[0]),)
-        key = program_key(kind, id(step), geometry, flags)
+            columns += tuple(c is not None for c in xbatch)
+        key = program_key(kind, id(step), geometry, flags, columns)
         hit = key in self._fetched_programs
         if not hit:
             self._fetched_programs.add(key)
@@ -3088,7 +3091,9 @@ class Sentinel:
         times = self._time_scalars(now)
         load1, cpu = self._cpu.sample()
         sys_scalars = jnp.asarray(np.array([load1, cpu], np.float32))
+        lock_wait = obs.phase("engine.lock_wait", n=n, trace=tr).start()
         with self._lock:
+            lock_wait.stop()
             if bs.param_rules is not None and param_gen != self._param_gen:
                 bs = bs._replace(param_rules=None, param_keys=None)
                 bg = bg._replace(param_rules=None, param_keys=None)
@@ -3319,7 +3324,9 @@ class Sentinel:
         times = self._time_scalars(now)
         load1, cpu = self._cpu.sample()
         sys_scalars = jnp.asarray(np.array([load1, cpu], np.float32))
+        lock_wait = obs.phase("engine.lock_wait", n=n, trace=tr).start()
         with self._lock:
+            lock_wait.stop()
             now, times = self._restamp_if_stale_locked(at_ms, now, times)
             self._drain_evictions_locked()
             # hot-set sketch observe (tiering): see decide_raw_nowait.
@@ -3483,69 +3490,68 @@ class Sentinel:
         n = rows.shape[0]
         obs = self.obs
         tr = obs.spans.maybe_trace() if obs.enabled else 0
-        t0 = obs.spans.now_ns() if tr else 0
-        b = self._pad(n)
-        batch = ExitBatch(
-            rows=_pad_to(rows, b, self.spec.rows, np.int32),
-            origin_rows=_pad_to(origin_rows, b, self.spec.alt_rows, np.int32),
-            chain_rows=_pad_to(chain_rows, b, self.spec.alt_rows, np.int32),
-            acquire=_pad_to(acquire, b, 0, np.int32),
-            rt_ms=_pad_to(rt_ms, b, 0, np.int32),
-            error=_pad_to(error, b, False, np.bool_),
-            is_in=_pad_to(is_in, b, False, np.bool_),
-            valid=_pad_to(np.ones(n, np.bool_), b, False, np.bool_),
-            param_rules=self._pad_pairs(param_rules, b, self.cfg.max_param_rules),
-            param_keys=self._pad_pairs(param_keys, b, self.spec.param_keys),
-            count_thread=(_pad_to(count_thread, b, False, np.bool_)
-                          if count_thread is not None else None),
-        )
-        batch = self._place_batch(batch)
-        now = self.clock.now_ms() if at_ms is None else at_ms
-        times = self._time_scalars(now)
-        with self._lock:
-            now, times = self._restamp_if_stale_locked(at_ms, now, times)
-            if self.tiering.enabled:
-                # tiering only: a key demoted between entry and exit must
-                # promote back before this decrement, or the exit would
-                # land on a recycled (or invalidated) row. Tiering-off
-                # keeps the historical no-drain exit path.
-                self._drain_evictions_locked()
-            self._seen_idx = max(self._seen_idx,
-                                 self.spec.second.index_of(now))
-            unpin = None
-            if batch.param_rules is not None:
-                if param_gen != self._param_gen:
-                    # state was reset by a reload: neither decrement nor unpin
-                    # (the pins live on the discarded registry)
-                    batch = batch._replace(param_rules=None, param_keys=None)
-                else:
-                    unpin = (self.param_key_registry,
-                             pf_mod.thread_key_rows(self._param, param_rules,
-                                                    param_keys))
-            exit_step = (self._jit_exit_noalt
-                         if self._batch_has_no_alt(origin_rows, chain_rows)
-                         else self._jit_exit)
-            with self.obs.annotate("sentinel_tpu.exit"):
-                self._state = exit_step(self._ruleset, self._state, batch,
-                                        times,
-                                        skip_threads=self._skip_threads)
-            # exit feeds resolve probes / trip breakers: with observers
-            # registered, this call pays one small state read so the
-            # observer fires within the exit call that caused the arc
-            brk = None
-            if self._breaker_observers:
-                self._breaker_seq += 1
-                brk = (self._breaker_seq, self._deg.rules,
-                       self._breaker_snapshot_locked())
-        # unpin only AFTER the device-side decrement is enqueued (entry-side
-        # pin discipline: resolve→pin, decide, exit-decrement→unpin)
-        if unpin is not None:
-            unpin[0].unpin_rows(unpin[1])
-        if obs.enabled:
-            obs.counters.add(obs_keys.PIPE_DISPATCH)
-        if tr:
-            obs.spans.record(tr, "exit.dispatch", t0, obs.spans.now_ns(),
-                             n=n)
+        with obs.phase("exit.dispatch", n=n, trace=tr):
+            b = self._pad(n)
+            batch = ExitBatch(
+                rows=_pad_to(rows, b, self.spec.rows, np.int32),
+                origin_rows=_pad_to(origin_rows, b, self.spec.alt_rows, np.int32),
+                chain_rows=_pad_to(chain_rows, b, self.spec.alt_rows, np.int32),
+                acquire=_pad_to(acquire, b, 0, np.int32),
+                rt_ms=_pad_to(rt_ms, b, 0, np.int32),
+                error=_pad_to(error, b, False, np.bool_),
+                is_in=_pad_to(is_in, b, False, np.bool_),
+                valid=_pad_to(np.ones(n, np.bool_), b, False, np.bool_),
+                param_rules=self._pad_pairs(param_rules, b, self.cfg.max_param_rules),
+                param_keys=self._pad_pairs(param_keys, b, self.spec.param_keys),
+                count_thread=(_pad_to(count_thread, b, False, np.bool_)
+                              if count_thread is not None else None),
+            )
+            batch = self._place_batch(batch)
+            now = self.clock.now_ms() if at_ms is None else at_ms
+            times = self._time_scalars(now)
+            lock_wait = obs.phase("engine.lock_wait", n=n, trace=tr).start()
+            with self._lock:
+                lock_wait.stop()
+                now, times = self._restamp_if_stale_locked(at_ms, now, times)
+                if self.tiering.enabled:
+                    # tiering only: a key demoted between entry and exit must
+                    # promote back before this decrement, or the exit would
+                    # land on a recycled (or invalidated) row. Tiering-off
+                    # keeps the historical no-drain exit path.
+                    self._drain_evictions_locked()
+                self._seen_idx = max(self._seen_idx,
+                                     self.spec.second.index_of(now))
+                unpin = None
+                if batch.param_rules is not None:
+                    if param_gen != self._param_gen:
+                        # state was reset by a reload: neither decrement nor unpin
+                        # (the pins live on the discarded registry)
+                        batch = batch._replace(param_rules=None, param_keys=None)
+                    else:
+                        unpin = (self.param_key_registry,
+                                 pf_mod.thread_key_rows(self._param, param_rules,
+                                                        param_keys))
+                exit_step = (self._jit_exit_noalt
+                             if self._batch_has_no_alt(origin_rows, chain_rows)
+                             else self._jit_exit)
+                with self.obs.annotate("sentinel_tpu.exit"):
+                    self._state = exit_step(self._ruleset, self._state, batch,
+                                            times,
+                                            skip_threads=self._skip_threads)
+                # exit feeds resolve probes / trip breakers: with observers
+                # registered, this call pays one small state read so the
+                # observer fires within the exit call that caused the arc
+                brk = None
+                if self._breaker_observers:
+                    self._breaker_seq += 1
+                    brk = (self._breaker_seq, self._deg.rules,
+                           self._breaker_snapshot_locked())
+            # unpin only AFTER the device-side decrement is enqueued (entry-side
+            # pin discipline: resolve→pin, decide, exit-decrement→unpin)
+            if unpin is not None:
+                unpin[0].unpin_rows(unpin[1])
+            if obs.enabled:
+                obs.counters.add(obs_keys.PIPE_DISPATCH)
         if brk is not None:
             self._diff_and_fire_breakers(
                 brk[0], brk[1], np.asarray(brk[2][:-1]).tolist())

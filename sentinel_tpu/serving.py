@@ -187,13 +187,8 @@ class DispatchPipeline:
     def _settle_oldest_locked(self) -> None:
         seq, handle, tr = self._inflight.popleft()
         obs = self._s.obs
-        if not obs.enabled:
-            tr = 0
-        t0 = obs.spans.now_ns() if tr else 0
-        self._results[seq] = handle.result()
-        if tr:
-            obs.spans.record(tr, "pipeline.settle", t0, obs.spans.now_ns(),
-                             note=f"seq={seq}")
+        with obs.phase("pipeline.settle", trace=tr, note=f"seq={seq}"):
+            self._results[seq] = handle.result()
         if self._on_settle is not None:
             self._on_settle(seq, self._results[seq])
 

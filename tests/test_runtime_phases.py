@@ -1,0 +1,191 @@
+"""The phases of the embedded runtime and its front end: per-batch spans
+outlive the per-request ones, each phase names its parent, the lock wait
+is its own phase, and the compile-cache accounting tells apart programs
+that differ only in an optional batch column."""
+
+import asyncio
+import threading
+
+import numpy as np
+import pytest
+
+import sentinel_tpu as stpu
+from sentinel_tpu.core.clock import ManualClock
+from sentinel_tpu.obs import counters as ck
+
+pytestmark = pytest.mark.quick
+
+
+@pytest.fixture
+def clk():
+    return ManualClock(start_ms=1_785_000_000_000)
+
+
+def make(clk, **over):
+    kw = dict(max_resources=64, max_origins=32, max_flow_rules=16,
+              max_degrade_rules=16, max_authority_rules=16)
+    kw.update(over)
+    return stpu.Sentinel(config=stpu.load_config(**kw), clock=clk)
+
+
+def _by_name(spans):
+    out = {}
+    for s in spans:
+        out.setdefault(s["name"], []).append(s)
+    return out
+
+
+def test_the_first_flush_outlives_more_requests_than_the_request_ring_holds(
+        clk):
+    """With the flight recorder on every request records two spans on the
+    loop's thread. They fill a ring of their own: the first flush's
+    per-batch phases are still there after it has wrapped."""
+    sph = make(clk)
+    sph.obs.spans.request_capacity = 64      # wraps after 32 requests
+    fe = sph.frontend(batch_max=8, idle_ms=0.0, queue_max=1024)
+    total = 160
+
+    async def drive():
+        first = await fe.submit("api")
+        rest = await asyncio.gather(*(fe.submit("api")
+                                      for _ in range(total - 1)))
+        await fe.drain()
+        return [first] + rest
+
+    verdicts = asyncio.run(drive())
+    assert len(verdicts) == total and all(v.trace_id for v in verdicts)
+    by = _by_name(sph.obs.spans.snapshot())
+    assert sph.obs.counters.get(ck.SPAN_RING_WRAP) >= 2 * total - 64
+    assert len(by.get("frontend.enqueue", ())) \
+        + len(by["frontend.settle"]) == 64           # the last ones only
+    flushes = sph.obs.counters.get(ck.FE_FLUSH_FULL) \
+        + sph.obs.counters.get(ck.FE_FLUSH_IDLE) \
+        + sph.obs.counters.get(ck.FE_FLUSH_DEADLINE)
+    # one of each per flush, the very first among them
+    for name in ("frontend.flush", "frontend.slot_wait",
+                 "frontend.dispatch", "frontend.result_wait",
+                 "frontend.fanout"):
+        assert len(by[name]) == flushes, name
+    first = min(by["frontend.flush"], key=lambda s: s["start_ns"])
+    assert first["n"] == 1                   # the lone first request
+    assert sum(s["n"] for s in by["frontend.fanout"]) == total
+    sph.close()
+
+
+def test_the_front_ends_phases_name_their_parents_across_the_thread_hop(clk):
+    sph = make(clk)
+    sph.load_flow_rules([stpu.FlowRule(resource="api", count=3.0)])
+    fe = sph.frontend(batch_max=4, idle_ms=0.0)
+
+    async def drive():
+        out = await asyncio.gather(*(fe.submit("api") for _ in range(4)))
+        await fe.drain()
+        return out
+
+    verdicts = asyncio.run(drive())
+    assert sum(v.allow for v in verdicts) == 3
+    spans = sph.obs.spans.snapshot()
+    by = _by_name(spans)
+    ids = {s["id"]: s for s in spans}
+
+    def parent(name):
+        return ids[by[name][0]["parent"]]["name"]
+
+    assert by["frontend.flush"][0]["parent"] == 0
+    assert parent("frontend.slot_wait") == "frontend.flush"
+    assert parent("frontend.dispatch") == "frontend.flush"
+    assert by["frontend.dispatch"][0]["thread"] \
+        != by["frontend.flush"][0]["thread"]
+    assert parent("pipeline.enqueue") == "frontend.dispatch"
+    # (a plain record, not a phase: it is nobody's parent)
+    assert parent("entry.prep") == "frontend.dispatch"
+    assert parent("decide.dispatch") == "frontend.dispatch"
+    assert parent("engine.lock_wait") == "decide.dispatch"
+    assert parent("pipeline.settle") == "frontend.result_wait"
+    assert by["frontend.fanout"][0]["parent"] == 0
+    # one batch, one trace: every per-batch span of it shares the id
+    batch = by["frontend.flush"][0]["trace"]
+    for name in ("frontend.slot_wait", "frontend.dispatch", "entry.prep",
+                 "decide.dispatch", "engine.lock_wait", "pipeline.settle",
+                 "frontend.result_wait", "frontend.fanout"):
+        assert {s["trace"] for s in by[name]} == {batch}, name
+    assert by["decide.dispatch"][0]["note"] in (
+        "scalar", "fast", "fast_occupy", "general_sorted")
+    # the request's chain still reaches the batch's spans
+    chain = {s["name"] for s in sph.obs.spans.chain(verdicts[0].trace_id)}
+    assert {"frontend.enqueue", "frontend.flush", "entry.prep",
+            "frontend.settle"} <= chain
+    sph.close()
+
+
+def test_the_lock_wait_is_the_time_from_asking_to_holding(clk):
+    """A thread holds the engine lock for ~80 ms while an exit asks for
+    it: ``engine.lock_wait`` reads that wait, inside ``exit.dispatch``."""
+    import time
+    sph = make(ManualClock(start_ms=1_785_000_000_000))
+    sph.obs.spans._time_ns = time.perf_counter_ns    # real durations
+    rows = np.asarray(sph.intern_resources(["api"]), np.int32)
+    pad = np.full(1, sph.spec.alt_rows, np.int32)
+    held = threading.Event()
+
+    def hold():
+        with sph._lock:
+            held.set()
+            time.sleep(0.08)
+
+    t = threading.Thread(target=hold)
+    t.start()
+    assert held.wait(timeout=10)
+    sph.exit_batch(rows=rows, origin_rows=pad, chain_rows=pad,
+                   acquire=np.ones(1, np.int32), rt_ms=np.ones(1, np.int32),
+                   error=np.zeros(1, bool), is_in=np.ones(1, bool))
+    t.join(timeout=10)
+    assert not t.is_alive()
+    by = _by_name(sph.obs.spans.snapshot())
+    (wait,), (exit_,) = by["engine.lock_wait"], by["exit.dispatch"]
+    assert wait["parent"] == exit_["id"] and wait["n"] == exit_["n"] == 1
+    assert 40e6 < wait["dur_ns"] <= exit_["dur_ns"]
+    sph.close()
+
+
+def test_telemetry_land_is_a_phase_of_the_telemetry_tick(clk):
+    sph = make(clk)
+    sph.entry_batch(["api"] * 8)
+    clk.advance_ms(1100)
+    assert sph.telemetry.poll() >= 1
+    (land,) = _by_name(sph.obs.spans.snapshot())["telemetry.land"]
+    assert land["n"] == len(sph.resources) and land["parent"] == 0
+    sph.close()
+
+
+def test_an_optional_batch_column_is_part_of_the_programs_identity(clk):
+    """``count_thread`` present or ``None`` is pytree structure: jit
+    compiles two programs, and ``compile_cache.miss`` counts both."""
+    sph = make(clk)
+    n = 8
+    rows = np.asarray(sph.intern_resources(["api"] * n), np.int32)
+    zeros = np.zeros(n, np.int32)
+    pad = np.full(n, sph.spec.alt_rows, np.int32)
+    args = (rows, zeros, pad, zeros, pad, np.ones(n, np.int32),
+            np.ones(n, bool), np.zeros(n, bool))
+
+    def misses():
+        return sph.obs.counters.get(ck.CACHE_MISS)
+
+    sph.decide_raw_nowait(*args).result()
+    one = misses()
+    sph.decide_raw_nowait(*args).result()
+    assert misses() == one                              # the same program
+    sph.decide_raw_nowait(*args, count_thread=np.ones(n, bool)).result()
+    assert misses() == one + 1                          # one more column
+    sph.decide_raw_nowait(*args, count_thread=np.ones(n, bool)).result()
+    assert misses() == one + 1
+    sph.close()
+
+
+def test_program_key_tells_column_patterns_apart():
+    from sentinel_tpu.core.compile_cache import program_key
+    base = program_key("decide", 1, (64,), {"a": True})
+    assert base == program_key("decide", 1, (64,), {"a": True}, ())
+    assert program_key("decide", 1, (64,), {"a": True}, (True, False)) \
+        != program_key("decide", 1, (64,), {"a": True}, (True, True))
