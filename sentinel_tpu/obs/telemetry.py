@@ -214,6 +214,9 @@ class HotTelemetry:
     lock; :meth:`poll` is the ticker-thread body. All reads
     (:meth:`snapshot`, :meth:`hot_entries`) serve from the last drained
     host view under a telemetry-local lock — never from device state.
+    Landing a readback costs O(K), whatever the registry holds: the
+    device's top-K names the candidate rows and the host asks the
+    registry for those rows' names only.
     """
 
     def __init__(self, sentinel, *, k: Optional[int] = None,
@@ -415,25 +418,27 @@ class HotTelemetry:
     def drain(self) -> int:
         """Resolve every queued device readback into the host view (and
         the ``<app>-metric`` log); → entries drained. Runs OFF the engine
-        lock: ``np.asarray`` here blocks only the telemetry thread."""
+        lock: ``np.asarray`` here blocks only the telemetry thread. Each
+        landing is one ``telemetry.land`` phase whose ``n`` is the rows
+        it resolved to names (the loaded top-K candidates, ≤ K)."""
         with self._lock:
             batch = list(self._pending)
             self._pending.clear()
         for now_ms, sec, append, outs in batch:
             host = tuple(np.asarray(o) for o in outs)
-            # the host pass over the resident names, once per tick
             with self._obs.phase("telemetry.land",
-                                 n=len(self._sentinel.resources)):
+                                 n=int(np.count_nonzero(host[0] > 0))):
                 self._land(now_ms, sec, append, host)
         return len(batch)
 
     def _land(self, now_ms: int, sec: int, append: int, outs) -> None:
+        """Land one readback into the host view. O(K): only the loaded
+        candidate rows of the device's top-K are resolved to names
+        (``name_of``, at land time), never the resident names."""
         (vals, rows, roll_lanes, sec_lanes, sec_rt,
          entry_lanes, entry_rt, hist_k, q_k) = outs
         has_hist = hist_k.shape[1] > 0
-        names = dict((row, name)
-                     for name, row in self._sentinel.resources.items())
-        rtypes = dict(self._sentinel.resource_types)
+        name_of = self._sentinel.resources.name_of
         interval_s = self._sentinel.spec.second.interval_ms / 1000.0
         hot: List[Dict] = []
         for i in range(len(vals)):
@@ -441,7 +446,7 @@ class HotTelemetry:
             if load <= 0:
                 continue
             row = int(rows[i])
-            name = names.get(row)
+            name = name_of(row)
             if name is None:        # stale row (evicted since the tick)
                 continue
             lanes = roll_lanes[i]
@@ -482,6 +487,7 @@ class HotTelemetry:
             }
             if self.writer is not None:
                 from sentinel_tpu.metrics.node import MetricNode
+                rtypes = self._sentinel.resource_types
                 for i, h in enumerate(hot):
                     c = sec_lanes[i]
                     if not (c[ev.PASS] or c[ev.BLOCK] or c[ev.SUCCESS]

@@ -149,12 +149,21 @@ def test_the_lock_wait_is_the_time_from_asking_to_holding(clk):
 
 
 def test_telemetry_land_is_a_phase_of_the_telemetry_tick(clk):
+    """``n`` is the work the landing did — the top-K rows it resolved to
+    names — not what the registry holds."""
     sph = make(clk)
-    sph.entry_batch(["api"] * 8)
-    clk.advance_ms(1100)
+    sph.intern_resources([f"idle-{i}" for i in range(20)])
+    clk.advance_ms(600)                 # stay inside the rolling second
+    sph.entry_batch(["api"] * 8 + ["web"] * 3)
+    clk.advance_ms(450)
     assert sph.telemetry.poll() >= 1
     (land,) = _by_name(sph.obs.spans.snapshot())["telemetry.land"]
-    assert land["n"] == len(sph.resources) and land["parent"] == 0
+    assert [h["resource"] for h in sph.telemetry.hot_entries()] \
+        == ["api", "web"]
+    assert land["n"] == 2 < len(sph.resources) and land["parent"] == 0
+    clk.advance_ms(2000)                # the window empties: nothing to name
+    assert sph.telemetry.poll() >= 1
+    assert _by_name(sph.obs.spans.snapshot())["telemetry.land"][-1]["n"] == 0
     sph.close()
 
 

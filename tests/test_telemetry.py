@@ -290,3 +290,171 @@ def test_flight_trigger_pins_hot_set():
     assert rec["hot"][0]["resource"].startswith("res-")
     assert all(set(h) == {"resource", "qps"} for h in rec["hot"])
     s.close()
+
+
+# ---------------------------------------------------------------------------
+# the landing is O(K): it asks the registry for the top-K rows' names and
+# for nothing else, on either registry (PR 27)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(params=["python", "native"])
+def registry_kind(request, monkeypatch):
+    if request.param == "python":
+        monkeypatch.setenv("SENTINEL_TPU_NATIVE", "0")
+    else:
+        from sentinel_tpu.native import native_available
+        if not native_available():
+            pytest.skip("the native registry does not build here")
+    return request.param
+
+
+def _check_kind(s, kind):
+    from sentinel_tpu.core.registry import Registry
+    assert isinstance(s.resources, Registry) == (kind == "python")
+
+
+class _ResidentView:
+    """What the landing used before PR 27: a row → name dict built from a
+    snapshot of every resident name."""
+
+    def __init__(self, registry):
+        self._names = dict((row, name) for name, row in registry.items())
+
+    def name_of(self, row):
+        return self._names.get(row)
+
+
+class _Written:
+    """Stands in for the ``<app>-metric`` writer: keeps what it is given."""
+
+    def __init__(self):
+        self.calls = []
+
+    def write(self, ts, nodes):
+        self.calls.append((ts, list(nodes)))
+
+
+def _drive_second(s, traffic):
+    """Traffic late in the second, then just past its end: the names are
+    in the live window AND in the completed second (module docstring)."""
+    s.clock.advance_ms(600)
+    for name, n, rtype in traffic:
+        for _ in range(n):
+            s.entry(name, resource_type=rtype).exit()
+    s.clock.advance_ms(450)
+
+
+def _spans(s, name):
+    return [x for x in s.obs.spans.snapshot() if x["name"] == name]
+
+
+def test_landing_resolves_the_topk_rows_and_never_walks_the_registry(
+        registry_kind, monkeypatch):
+    monkeypatch.setenv(TELEMETRY_K_ENV, "4")
+    s = _make()
+    _check_kind(s, registry_kind)
+    s.intern_resources([f"idle-{i}" for i in range(30)])
+    _drive_second(s, [("a", 5, 0), ("b", 3, 0), ("c", 1, 0)])
+    walked, asked = [], []
+    items, name_of = s.resources.items, s.resources.name_of
+    monkeypatch.setattr(s.resources, "items",
+                        lambda: walked.append(1) or items())
+    monkeypatch.setattr(s.resources, "name_of",
+                        lambda row: asked.append(row) or name_of(row))
+    assert s.telemetry.poll() == 1
+    assert not walked
+    hot = s.telemetry.hot_entries()
+    assert [h["resource"] for h in hot] == ["a", "b", "c"]
+    assert asked == [h["row"] for h in hot]
+    (land,) = _spans(s, "telemetry.land")
+    assert land["n"] == len(asked) == 3 < s.telemetry.k < len(s.resources)
+    s.close()
+
+
+def test_landing_equals_the_resident_view_entry_for_entry(registry_kind):
+    """The same readback landed through ``name_of`` and through the old
+    row → name dict: hot set, snapshot, timeline and metric nodes agree."""
+    s = _make()
+    _check_kind(s, registry_kind)
+    s.telemetry.writer = _Written()
+    traffic = [("web", 7, 1), ("rpc", 5, 2), ("db", 5, 3), ("plain", 2, 0)]
+    _drive_second(s, traffic)
+    assert s.telemetry.tick()
+    readback = s.telemetry._pending.pop()
+    real = s.resources
+    landed = []
+    for registry in (real, _ResidentView(real)):
+        s.resources = registry
+        s.telemetry._pending.append(readback)     # the same one, twice
+        assert s.telemetry.drain() == 1
+        snap = s.telemetry.snapshot()
+        landed.append((s.telemetry.hot_entries(), snap["hot"],
+                       snap["timeline"][-1], s.telemetry.flight_hot(),
+                       s.telemetry.writer.calls.pop()))
+    s.resources = real
+    by_name_of, by_view = landed
+    assert by_name_of == by_view
+    hot, snap_hot, _timeline, _flight, (ts, nodes) = by_name_of
+    assert hot == snap_hot
+    # equal loads keep row order (rpc interned before db)
+    assert [h["resource"] for h in hot] == ["web", "rpc", "db", "plain"]
+    assert ts == (T0 // 1000) * 1000
+    assert [(n.resource, n.pass_qps, n.classification) for n in nodes] \
+        == sorted((name, n, rtype) for name, n, rtype in traffic)
+    assert dict(s.resource_types) == {"web": 1, "rpc": 2, "db": 3}
+    s.close()
+
+
+def test_row_evicted_between_tick_and_drain_is_skipped(monkeypatch):
+    """Only the Python registry frees a row without handing it on
+    (``evict_name``, the tiering ticker's demotion)."""
+    monkeypatch.setenv("SENTINEL_TPU_NATIVE", "0")
+    s = _make()
+    _drive_second(s, [("stays", 4, 0), ("goes", 6, 0)])
+    assert s.telemetry.tick()
+    assert s.resources.evict_name("goes")
+    assert s.telemetry.drain() == 1
+    assert [h["resource"] for h in s.telemetry.hot_entries()] == ["stays"]
+    loads, _rows = s.telemetry.last_topk      # the raw readback keeps it
+    assert list(loads[:2]) == [6, 4]
+    assert _spans(s, "telemetry.land")[-1]["n"] == 2   # asked for both
+    s.close()
+
+
+def test_row_reinterned_between_tick_and_drain_lands_under_the_new_name(
+        registry_kind):
+    """The name is looked up at land time, as the snapshot was: a row the
+    LRU handed to another name since the tick reads that name."""
+    s = _make()
+    _check_kind(s, registry_kind)
+    _drive_second(s, [("victim", 6, 0)])
+    row = s.resources.lookup("victim")
+    assert s.telemetry.tick()
+    heir = None
+    for i in range(2 * s.resources.capacity):
+        if s.resources.name_of(row) != "victim":
+            break
+        heir = f"filler-{i}"
+        s.resources.get_or_create(heir)
+    assert s.resources.name_of(row) == heir
+    assert s.telemetry.drain() == 1
+    top = s.telemetry.hot_entries()[0]
+    assert (top["resource"], top["row"], top["load"]) == (heir, row, 6)
+    s.close()
+
+
+def test_name_of_agrees_with_the_items_view_on_every_row(registry_kind):
+    """Both registries answer ``name_of`` as their ``items()`` snapshot
+    does, for live, recycled, never-used and out-of-range rows."""
+    from sentinel_tpu.core.registry import make_resource_registry
+    sparse, recycled = make_resource_registry(16), make_resource_registry(16)
+    for name in ("x", "y"):
+        sparse.get_or_create(name)
+    for i in range(40):                       # overflows: rows are recycled
+        recycled.get_or_create(f"n-{i}")
+    assert sparse.name_of(15) is None and sparse.name_of(-1) is None
+    assert len(recycled) == 16
+    for reg in (sparse, recycled):
+        view = _ResidentView(reg)
+        assert [reg.name_of(r) for r in range(-2, 18)] \
+            == [view.name_of(r) for r in range(-2, 18)]
