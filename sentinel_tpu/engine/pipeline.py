@@ -65,6 +65,10 @@ class EngineSpec:
     # 0 = table disabled: state.rt_hist is None and every consumer
     # compiles the feature away (round-20 bit-parity switch)
     hist_buckets: int = 0
+    # the row axis of the window tensors is split over a device mesh
+    # (parallel/local_shard.py): single-row updates take the form the SPMD
+    # partitioner keeps on the owning shard (stats/window.add_one_row)
+    rows_sharded: bool = False
 
 
 class SentinelState(NamedTuple):
@@ -265,7 +269,16 @@ def _init_state_np(spec: EngineSpec, nf: int, nd: int) -> SentinelState:
 _TRANSFER_STATE_LIMIT_BYTES = 48 * 1024 * 1024
 
 
-def init_state(spec: EngineSpec, nf: int, nd: int) -> SentinelState:
+def init_state_shapes(spec: EngineSpec, nf: int, nd: int) -> SentinelState:
+    """The state's structure and shapes with nothing allocated: what a
+    meshed engine derives its sharding pytree from before a byte of the
+    state exists (parallel/local_shard.state_shardings)."""
+    return jax.eval_shape(
+        functools.partial(_init_state_traced, spec, nf, nd))
+
+
+def init_state(spec: EngineSpec, nf: int, nd: int,
+               shardings: Optional[SentinelState] = None) -> SentinelState:
     """Initial device state — WITHOUT paying per-process program loads
     where possible.
 
@@ -274,20 +287,28 @@ def init_state(spec: EngineSpec, nf: int, nd: int) -> SentinelState:
     cold-start story in docs/OPERATIONS.md). Serving-sized states
     (≤ ~48 MB) are instead built host-side and device_put as ONE
     transfer (no XLA program at all); bigger states (the 1M-row scale)
-    fall back to one fused fill program, jit-cached per geometry."""
+    fall back to one fused fill program, jit-cached per geometry.
+
+    ``shardings`` (a meshed engine's ``state_shardings`` pytree) creates
+    the state already laid out: the transfer places each leaf with its
+    sharding, the fill program runs with them as ``out_shardings`` so
+    every device fills its own rows and no leaf ever exists whole on one
+    device — at 4M rows the state is 12.9 GB, most of a 16 GB chip."""
     import math
     import os
     mode = os.environ.get("SENTINEL_INIT_MODE", "")
     # size from shapes alone — don't allocate ~90 MB of numpy zeros just
     # to discard them on the program path
-    shapes = jax.eval_shape(
-        functools.partial(_init_state_traced, spec, nf, nd))
     nbytes = sum(math.prod(leaf.shape) * leaf.dtype.itemsize
-                 for leaf in jax.tree.leaves(shapes))
+                 for leaf in jax.tree.leaves(init_state_shapes(spec, nf, nd)))
     if mode != "program" and (mode == "transfer"
                               or nbytes <= _TRANSFER_STATE_LIMIT_BYTES):
-        return jax.device_put(_init_state_np(spec, nf, nd))
-    return _init_state_jit(spec, nf, nd)()
+        return jax.device_put(_init_state_np(spec, nf, nd), shardings)
+    if shardings is None:
+        return _init_state_jit(spec, nf, nd)()
+    # not cached: the pytree holds the mesh, and an engine initialises once
+    return jax.jit(functools.partial(_init_state_traced, spec, nf, nd),
+                   out_shardings=shardings)()
 
 
 def _stat_targets(spec: EngineSpec, rows, origin_rows, chain_rows, valid,
@@ -636,7 +657,7 @@ def decide_entries(
         ev_ids1, rec_amt1, now_idx_s)
     second = _scoped(
         "decide.record.second", add_one_row, spec.second, second,
-        ENTRY_NODE_ROW, entry_vec, now_idx_s)
+        ENTRY_NODE_ROW, entry_vec, now_idx_s, sharded=spec.rows_sharded)
 
     # alt rows (origin + chain hashes): no OCCUPIED lane on alt (as before)
     if record_alt:
@@ -680,7 +701,7 @@ def decide_entries(
             main_rec1, ev_ids1, rec_amt1, now_idx_m)
         minute = _scoped(
             "decide.record.minute", add_one_row, spec.minute, minute,
-            ENTRY_NODE_ROW, entry_vec, now_idx_m)
+            ENTRY_NODE_ROW, entry_vec, now_idx_m, sharded=spec.rows_sharded)
 
     if skip_threads:
         # nothing loaded reads the gauges: the scatters (+ the alt half)
@@ -786,7 +807,8 @@ def record_exits(
         payload, now_idx_s, rt_ms=rt1, rt_valid=batch.valid)
     second = _scoped(
         "exit.record.second", add_one_row, spec.second, second, ENTRY_NODE_ROW,
-        entry_vec, now_idx_s, rt_add=entry_rt_add, rt_min=entry_rt_min)
+        entry_vec, now_idx_s, rt_add=entry_rt_add, rt_min=entry_rt_min,
+        sharded=spec.rows_sharded)
     if record_alt:
         if spec.second.buckets >= 2:
             alt_second = _scoped(
@@ -815,7 +837,7 @@ def record_exits(
         minute = _scoped(
             "exit.record.minute", add_one_row, spec.minute, minute,
             ENTRY_NODE_ROW, entry_vec, now_idx_m, rt_add=entry_rt_add,
-            rt_min=entry_rt_min)
+            rt_min=entry_rt_min, sharded=spec.rows_sharded)
 
     if skip_threads:
         threads = state.threads

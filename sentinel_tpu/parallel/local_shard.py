@@ -111,8 +111,10 @@ def shard_of_rows(n_rows: int, mesh: Optional[Mesh],
 def state_shardings(spec: EngineSpec, mesh: Mesh,
                     state: SentinelState) -> SentinelState:
     """A ``SentinelState``-shaped pytree of :class:`NamedSharding` per the
-    field map above. ``state`` supplies the structure of the variable-shape
-    parts (custom slot states, rt-tracking window leaves)."""
+    field map above. ``state`` supplies only the STRUCTURE of the
+    variable-shape parts (custom slot states, rt-tracking window leaves):
+    a live state or its shapes (``pipeline.init_state_shapes``) give the
+    same pytree, so an engine has its shardings before its state exists."""
     row = NamedSharding(mesh, P(MESH_AXIS))
     rep = NamedSharding(mesh, P())
 

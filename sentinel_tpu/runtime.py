@@ -57,7 +57,7 @@ from sentinel_tpu.core.registry import (
 )
 from sentinel_tpu.engine.pipeline import (
     EngineSpec, EntryBatch, ExitBatch, RuleSet, SentinelState, Verdicts,
-    decide_and_record_exits, decide_entries, init_state,
+    decide_and_record_exits, decide_entries, init_state, init_state_shapes,
     invalidate_resource_rows, record_blocks, record_exits,
 )
 from sentinel_tpu.engine import fastpath as fp_mod
@@ -684,6 +684,7 @@ class Sentinel:
             # trace-time knob: the value is baked into the state pytree
             # and every jitted step program's cache key)
             hist_buckets=engine_hist_buckets(),
+            rows_sharded=mesh is not None,
         )
         self.param_key_registry = pf_mod.make_param_key_registry(cfg.param_table_slots)
         self._user_param_rules: List[pf_mod.ParamFlowRule] = []
@@ -724,11 +725,32 @@ class Sentinel:
         # main row → alt rows it ever hashed to; consulted on row eviction so
         # the recycled row's origin/context stats are cleared too
         self._alt_rows_by_row: dict = {}
+        # self-telemetry bundle (obs/): spans + decision counters +
+        # latency histograms + sampled block-event log. Every hot-path
+        # instrumentation site below guards on the single `obs.enabled`
+        # flag (SENTINEL_OBS_DISABLE); sampling via SENTINEL_TRACE_SAMPLE.
+        # Built before the state so that the state's initialisation is a
+        # phase like any other.
+        self.obs = RuntimeObs(clock=self.clock)
+        # Meshed: the sharding pytree comes from the state's SHAPES, so
+        # the state is created already laid out — each device fills its
+        # own rows and no leaf ever exists whole on the default device
+        # (at 4M rows the state is most of a chip's memory).
+        state_sh, n_devices = None, 1
+        if mesh is not None:
+            from sentinel_tpu.parallel.local_shard import shardings_for
+            self._mesh_shardings = shardings_for(
+                self.spec, mesh, init_state_shapes(
+                    self.spec, cfg.max_flow_rules, cfg.max_degrade_rules))
+            state_sh, n_devices = self._mesh_shardings[0], mesh.devices.size
         # init_state picks transfer-based init (one device_put, no XLA
         # program) for serving-sized geometries and one fused fill
         # program at the 1M-row scale — see OPERATIONS.md "Cold start".
-        self._state = init_state(self.spec, cfg.max_flow_rules,
-                                 cfg.max_degrade_rules)
+        with self.obs.phase("state.init", n=self.spec.rows,
+                            note=f"devices={n_devices}"):
+            self._state = init_state(
+                self.spec, cfg.max_flow_rules, cfg.max_degrade_rules,
+                shardings=state_sh)
         # Multi-process "rows" mesh (multihost/): replicated leaves
         # (rules, verdicts) stay host-readable everywhere; row-sharded
         # leaves are only partially addressable per host.
@@ -739,10 +761,6 @@ class Sentinel:
         # process meshes only: a multihost batch column is per-process
         # host data and stays with the SPMD replication contract
         self._place_batches = mesh is not None and not self.is_multihost
-        if mesh is not None:
-            from sentinel_tpu.parallel.local_shard import validate_mesh
-            validate_mesh(self.spec, mesh)
-            self._refresh_shardings_locked()
         self._compile_empty_rules()
 
         self.flow_property: SentinelProperty = SentinelProperty()
@@ -771,11 +789,6 @@ class Sentinel:
         self.resource_types: dict = {}
         # per-second rolled-up block log (LogSlot → EagleEyeLogUtil analog)
         self.block_log = BlockStatLogger(self.clock)
-        # self-telemetry bundle (obs/): spans + decision counters +
-        # latency histograms + sampled block-event log. Every hot-path
-        # instrumentation site below guards on the single `obs.enabled`
-        # flag (SENTINEL_OBS_DISABLE); sampling via SENTINEL_TRACE_SAMPLE.
-        self.obs = RuntimeObs(clock=self.clock)
         # surface the startup tune events (artifact load / fingerprint
         # fallback / rejected env knobs) now that telemetry exists:
         # RecordLog line + one counter tick each (key None = log-only)
@@ -3022,15 +3035,18 @@ class Sentinel:
             record_block=(_pad_to(record_block, b, False, np.bool_)
                           if record_block is not None else None),
         )
-        return self._place_batch(batch)
+        return self._place_batch(batch, n)
 
-    def _place_batch(self, batch):
+    def _place_batch(self, batch, n: int):
         """Meshed-mode batch-axis placement (no-op otherwise); shared by
-        the entry, split, fused, and exit dispatch tiers."""
+        the entry, split, fused, and exit dispatch tiers. ``n`` events
+        are placed under the phase ``batch.place`` — the host work only
+        the mesh path does, apart from the dispatch phase around it."""
         if not self._place_batches:
             return batch
         from sentinel_tpu.parallel.local_shard import place_batch
-        return place_batch(batch, self.mesh)
+        with self.obs.phase("batch.place", n=n):
+            return place_batch(batch, self.mesh)
 
     def _decide_split_nowait(self, rows, origin_ids, origin_rows,
                              context_ids, chain_rows, acquire, is_in,
@@ -3320,7 +3336,7 @@ class Sentinel:
                    else _pad_to(np.ones(n_x, np.bool_), b_x, False,
                                 np.bool_)),
         )
-        xbatch = self._place_batch(xbatch)
+        xbatch = self._place_batch(xbatch, n_x)
         times = self._time_scalars(now)
         load1, cpu = self._cpu.sample()
         sys_scalars = jnp.asarray(np.array([load1, cpu], np.float32))
@@ -3506,7 +3522,7 @@ class Sentinel:
                 count_thread=(_pad_to(count_thread, b, False, np.bool_)
                               if count_thread is not None else None),
             )
-            batch = self._place_batch(batch)
+            batch = self._place_batch(batch, n)
             now = self.clock.now_ms() if at_ms is None else at_ms
             times = self._time_scalars(now)
             lock_wait = obs.phase("engine.lock_wait", n=n, trace=tr).start()

@@ -245,17 +245,40 @@ def add_rows_vec(spec: WindowSpec, state: WindowState, rows: jnp.ndarray,
 def add_one_row(spec: WindowSpec, state: WindowState, row: int,
                 vec: jnp.ndarray, now_idx: jnp.ndarray,
                 rt_add: Optional[jnp.ndarray] = None,
-                rt_min: Optional[jnp.ndarray] = None) -> WindowState:
+                rt_min: Optional[jnp.ndarray] = None,
+                sharded: bool = False) -> WindowState:
     """Add a pre-reduced event vector to ONE row's current bucket.
 
     The global ENTRY row receives a contribution from every inbound event;
     as a scatter that doubles the index count of each recording pass — as a
     host-side reduction + this single dynamic-slice update it is one cheap
-    elementwise op. Caller must have refreshed the row at ``now_idx``."""
+    elementwise op. Caller must have refreshed the row at ``now_idx``.
+
+    ``sharded`` (STATIC): the row axis is split over a device mesh. The
+    TPU compiler turns a ONE-index update into a dynamic slice of the row
+    axis, and the SPMD partitioner answers a dynamic slice of a sharded
+    axis by gathering the WHOLE table onto every device — 8 GB for the
+    minute ring at 4M rows, in every decide and exit step. With a second,
+    out-of-range index (dropped, as padding rows are) the update stays a
+    scatter, which the partitioner keeps on the shard that owns the row
+    like the per-event scatters beside it."""
     k = _bucket_of(spec, now_idx)
-    counters = state.counters.at[row, k, :].add(vec)
+    track = spec.track_rt and rt_add is not None
     rt_sum, min_rt = state.rt_sum, state.min_rt
-    if spec.track_rt and rt_add is not None:
+    if sharded:
+        rows = jnp.array([row, state.counters.shape[0]], jnp.int32)
+        counters = state.counters.at[rows, k, :].add(
+            jnp.stack([vec, jnp.zeros_like(vec)]), mode="drop")
+        if track:
+            rt_sum = rt_sum.at[rows, k].add(
+                jnp.stack([rt_add.astype(jnp.float32), jnp.float32(0)]),
+                mode="drop")
+            if rt_min is not None:
+                min_rt = min_rt.at[rows, k].min(
+                    jnp.stack([rt_min, INT32_MAX]), mode="drop")
+        return WindowState(counters, state.stamps, rt_sum, min_rt)
+    counters = state.counters.at[row, k, :].add(vec)
+    if track:
         rt_sum = rt_sum.at[row, k].add(rt_add.astype(jnp.float32))
         if rt_min is not None:
             min_rt = min_rt.at[row, k].min(rt_min)
