@@ -129,15 +129,9 @@ def main() -> None:
                  now_idx_s, load1, cpu, max_rt):
         return jnp.ones(valid.shape, jnp.bool_)
 
-    def stub_refresh_all(wspec, state, now_idx):
-        return state
-
-    def stub_add_rows_multi(wspec, state, rows, event_ids, amounts,
-                            now_idx):
-        return state
-
-    def stub_add_one_row(wspec, state, row, vec, now_idx, **kw):
-        return state
+    def stub_record_window(step, name, wspec, wstate, now_idx, touched,
+                           adds):
+        return wstate
 
     # ---- flow-internal stubs (FLOW_DETAIL=1) ----
     from sentinel_tpu.ops import segments as seg_mod
@@ -177,9 +171,7 @@ def main() -> None:
                         stub_degrade_entry),
             "auth": (pl.auth_mod, "authority_check", stub_auth),
             "system": (pl.sys_mod, "system_check", stub_sys),
-            "refresh": (pl, "refresh_all", stub_refresh_all),
-            "scatter": (pl, "add_rows_multi", stub_add_rows_multi),
-            "entryrow": (pl, "add_one_row", stub_add_one_row),
+            "recording": (pl, "_record_window", stub_record_window),
             "sort": (seg_mod, "sort_by_keys", stub_sort_by_keys),
             "unsort": (seg_mod, "unsort", stub_unsort),
             "ranks": (seg_mod, "ranks_by_key", stub_ranks),
@@ -248,9 +240,8 @@ def main() -> None:
         run("-ranks", "ranks")
         run("-flowscalar", "flowscalar")
         run("-degscalar", "degscalar")
-        run("-recording", "refresh", "scatter", "entryrow")
-        run("-all (floor)", "flowscalar", "degscalar", "refresh",
-            "scatter", "entryrow")
+        run("-recording", "recording")
+        run("-all (floor)", "flowscalar", "degscalar", "recording")
     elif os.environ.get("FLOW_DETAIL"):
         run("FULL")
         run("-sorts", "sort")
@@ -265,9 +256,9 @@ def main() -> None:
         run("-flow", "flow")
         run("-degrade", "degrade")
         run("-auth-system", "auth", "system")
-        run("-recording", "refresh", "scatter", "entryrow")
-        run("-all (floor)", "flow", "degrade", "auth", "system", "refresh",
-            "scatter", "entryrow")
+        run("-recording", "recording")
+        run("-all (floor)", "flow", "degrade", "auth", "system",
+            "recording")
     full = results["FULL"]
     print("marginal costs:")
     for k, v in results.items():

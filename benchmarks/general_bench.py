@@ -202,14 +202,8 @@ def ablate(jax, spec, ruleset, state0, batches, t0_ms, STEPS,
                            **kw):
         return st, jnp.ones(rows.shape, jnp.bool_)
 
-    def stub_refresh_all(wspec, wstate, now_idx):
-        return wstate
-
-    def stub_add_rows_multi(wspec, wstate, rows, event_ids, amounts,
-                            now_idx):
-        return wstate
-
-    def stub_add_one_row(wspec, wstate, row, vec, now_idx, **kw):
+    def stub_record_window(step, name, wspec, wstate, now_idx, touched,
+                           adds):
         return wstate
 
     def stub_ranks(key):
@@ -242,9 +236,7 @@ def ablate(jax, spec, ruleset, state0, batches, t0_ms, STEPS,
         "prefix": (seg_mod, "segment_prefix_sum", stub_prefix),
         "admit": (seg_mod, "greedy_admit", stub_admit),
         "degrade": (pl.deg_mod, "degrade_entry_check", stub_degrade_entry),
-        "refresh": (pl, "refresh_all", stub_refresh_all),
-        "scatter": (pl, "add_rows_multi", stub_add_rows_multi),
-        "entryrow": (pl, "add_one_row", stub_add_one_row),
+        "recording": (pl, "_record_window", stub_record_window),
         # fast-path targets (mode="fast")
         "ranks": (seg_mod, "ranks_by_key", stub_ranks),
         "joint": (seg_mod, "padded_table_gather", stub_joint_gather),
@@ -304,8 +296,7 @@ def ablate(jax, spec, ruleset, state0, batches, t0_ms, STEPS,
         print(f"  {name:<40s} {dt:9.2f} ms", flush=True)
 
     if mode == "fast":
-        floor_stubs = ("flowfast", "degscalar", "joint", "refresh",
-                       "scatter", "entryrow")
+        floor_stubs = ("flowfast", "degscalar", "joint", "recording")
         run("FULL")
         run("-joint-gather", "joint")
         run("-ranksort", "ranks")
@@ -313,7 +304,7 @@ def ablate(jax, spec, ruleset, state0, batches, t0_ms, STEPS,
         run("-warmup", "warmup")
         run("-flow(whole)", "flowfast")
         run("-degrade", "degscalar")
-        run("-recording", "refresh", "scatter", "entryrow")
+        run("-recording", "recording")
         run("-all (floor)", *floor_stubs)
     else:
         run("FULL")
@@ -324,9 +315,9 @@ def ablate(jax, spec, ruleset, state0, batches, t0_ms, STEPS,
         run("-prefixsums", "prefix")
         run("-admit", "admit")
         run("-degrade", "degrade")
-        run("-recording", "refresh", "scatter", "entryrow")
+        run("-recording", "recording")
         run("-all (floor)", "sort", "unsort", "winsum", "warmup", "prefix",
-            "admit", "degrade", "refresh", "scatter", "entryrow")
+            "admit", "degrade", "recording")
     full = results["FULL"]
     print("marginal costs:")
     for k, v in results.items():

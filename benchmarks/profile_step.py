@@ -45,7 +45,8 @@ def main() -> None:
     from sentinel_tpu.rules import param_flow as pf_mod
     from sentinel_tpu.rules import system as sys_mod
     from sentinel_tpu.stats.window import (
-        WindowSpec, add_one_row, add_rows_multi, refresh_all, window_sum_rows,
+        WindowSpec, bucket_add_events, bucket_add_row, close_bucket,
+        open_bucket, window_sum_rows,
     )
     from sentinel_tpu.stats import events as ev_mod
 
@@ -224,10 +225,10 @@ def main() -> None:
         n_ev = second.counters.shape[2]
         entry_vec = jnp.zeros((n_ev,), jnp.int32).at[ev_mod.PASS].set(
             jnp.sum(amt))
-        sec = refresh_all(spec.second, second, times_arr[0])
-        sec = add_rows_multi(spec.second, sec, tgt, ev_ids, amt,
-                             times_arr[0])
-        sec = add_one_row(spec.second, sec, 0, entry_vec, times_arr[0])
+        bucket = open_bucket(spec.second, second, times_arr[0])
+        bucket = bucket_add_events(bucket, tgt, ev_ids, amt)
+        bucket = bucket_add_row(bucket, 0, entry_vec)
+        sec = close_bucket(spec.second, second, bucket, times_arr[0])
         thr = threads.at[tgt].add(jnp.where(batch.valid, 1, 0),
                                   mode="drop")
         return sec, thr

@@ -43,9 +43,10 @@ from sentinel_tpu.rules import param_flow as pf_mod
 from sentinel_tpu.rules import system as sys_mod
 from sentinel_tpu.stats import events as ev
 from sentinel_tpu.stats.window import (
-    WindowSpec, WindowState, add_one_row, add_rows, add_rows_hist,
-    add_rows_multi, add_rows_vec, extract_rows, hist_add_fits, init_window,
-    invalidate_rows, refresh_all, refresh_rows, restore_rows,
+    WindowSpec, WindowState, bucket_add_events, bucket_add_hist,
+    bucket_add_row, bucket_add_vecs, close_bucket, extract_rows,
+    hist_add_fits, init_window, invalidate_rows, open_bucket, refresh_rows,
+    restore_rows,
 )
 
 
@@ -67,7 +68,7 @@ class EngineSpec:
     hist_buckets: int = 0
     # the row axis of the window tensors is split over a device mesh
     # (parallel/local_shard.py): single-row updates take the form the SPMD
-    # partitioner keeps on the owning shard (stats/window.add_one_row)
+    # partitioner keeps on the owning shard (stats/window.bucket_add_row)
     rows_sharded: bool = False
 
 
@@ -333,6 +334,25 @@ def _scoped(name: str, fn, *args, **kwargs):
     stage owns an operation."""
     with jax.named_scope(name):
         return fn(*args, **kwargs)
+
+
+def _record_window(step: str, name: str, wspec: WindowSpec,
+                   wstate: WindowState, now_idx, touched, adds) -> WindowState:
+    """One window's record stage: slice the current bucket's plane out of
+    the ring with every row lazily reset (scope ``<step>.refresh.<name>``),
+    apply ``adds`` (Bucket → Bucket) to the plane and write it back once
+    (``<step>.record.<name>``) — no scatter ever has the ring as its
+    operand (stats.window.open_bucket says what that costs on the chip).
+    ``touched`` are the rows ``adds`` lands on, read only when B == 1."""
+    if wspec.buckets >= 2:
+        bucket = _scoped(f"{step}.refresh.{name}", open_bucket, wspec, wstate,
+                         now_idx)
+    else:   # B=1: full restamp would erase untouched rows' prev window
+        wstate = _scoped(f"{step}.refresh.{name}", refresh_rows, wspec,
+                         wstate, touched, now_idx)
+        bucket = open_bucket(wspec, wstate, now_idx, reset=False)
+    with jax.named_scope(f"{step}.record.{name}"):
+        return close_bucket(wspec, wstate, adds(bucket), now_idx)
 
 
 def decide_entries(
@@ -612,12 +632,13 @@ def decide_entries(
     occ1 = occupied if enable_occupy else jnp.zeros_like(pass_now)
 
     # Recording strategy (this block was ~70% of the step's device time as
-    # per-event add_rows passes): (1) full-table lazy reset (refresh_all:
-    # dynamic-slice, no index arrays); (2) each event lands in exactly ONE
-    # lane (pass_now / occupied / blocked are mutually exclusive), so the
-    # per-row record is one fused scatter of B indices (add_rows_multi);
-    # (3) the global ENTRY row — formerly a second B-index scatter half —
-    # is a reduction + one single-row update (add_one_row).
+    # per-event add_rows passes): (1) the current bucket's plane of every
+    # row, lazily reset, not the ring (_record_window); (2) each event
+    # lands in exactly ONE lane (pass_now / occupied / blocked are mutually
+    # exclusive), so the per-row record is one fused scatter of B indices
+    # (bucket_add_events); (3) the global ENTRY row — formerly a second
+    # B-index scatter half — is a reduction + one single-row update
+    # (bucket_add_row).
     rec1 = pass_now | occ1 | blocked_rec            # all already ∧ valid
     ev_ids1 = jnp.where(pass_now, jnp.int32(ev.PASS),
                         jnp.where(occ1, jnp.int32(ev.OCCUPIED_PASS),
@@ -637,27 +658,22 @@ def decide_entries(
     entry_vec = entry_vec.at[ev.BLOCK].set(
         jnp.sum(jnp.where(blocked_rec & ein, acq, 0)))
 
-    if spec.second.buckets >= 2:
-        second = _scoped(
-            "decide.refresh.second", refresh_all, spec.second, state.second,
-            now_idx_s)
-    else:   # B=1: full restamp would erase untouched rows' prev window
-        # ENTRY joins the refresh list only when this batch actually lands
-        # something on it — an idle/all-outbound batch restamping ENTRY
-        # would erase its previous-window bucket (previousPassQps for
-        # warm-up rules reading the entry node). add_one_row with an
-        # all-zero vector on the unrefreshed bucket is a no-op.
-        entry_refresh = jnp.where(jnp.any(entry_vec != 0),
-                                  jnp.int32(ENTRY_NODE_ROW), pad_r)
-        second = _scoped(
-            "decide.refresh.second", refresh_rows, spec.second, state.second,
-            jnp.concatenate([main_rec1, entry_refresh[None]]), now_idx_s)
-    second = _scoped(
-        "decide.record.second", add_rows_multi, spec.second, second, main_rec1,
-        ev_ids1, rec_amt1, now_idx_s)
-    second = _scoped(
-        "decide.record.second", add_one_row, spec.second, second,
-        ENTRY_NODE_ROW, entry_vec, now_idx_s, sharded=spec.rows_sharded)
+    def record_main(bucket):
+        bucket = bucket_add_events(bucket, main_rec1, ev_ids1, rec_amt1)
+        return bucket_add_row(bucket, ENTRY_NODE_ROW, entry_vec,
+                              sharded=spec.rows_sharded)
+
+    # B=1: ENTRY joins the refresh list only when this batch actually lands
+    # something on it — an idle/all-outbound batch restamping ENTRY would
+    # erase its previous-window bucket (previousPassQps for warm-up rules
+    # reading the entry node). bucket_add_row with an all-zero vector on
+    # the unrefreshed bucket is a no-op.
+    entry_refresh = jnp.where(jnp.any(entry_vec != 0),
+                              jnp.int32(ENTRY_NODE_ROW), pad_r)
+    touched = jnp.concatenate([main_rec1, entry_refresh[None]])
+    second = _record_window(
+        "decide", "second", spec.second, state.second, now_idx_s, touched,
+        record_main)
 
     # alt rows (origin + chain hashes): no OCCUPIED lane on alt (as before)
     if record_alt:
@@ -665,43 +681,32 @@ def decide_entries(
         alt_mask2 = jnp.concatenate([alt_mask1, alt_mask1])
         ev_ids2 = jnp.concatenate([ev_ids1, ev_ids1])
         alt_rec = jnp.where(alt_mask2, alt_targets, pad_a)
-        if spec.second.buckets >= 2:
-            alt_second = _scoped(
-                "decide.refresh.alt_second", refresh_all, spec.second,
-                state.alt_second, now_idx_s)
-        else:
-            alt_second = _scoped(
-                "decide.refresh.alt_second", refresh_rows, spec.second,
-                state.alt_second, alt_targets, now_idx_s)
         if fast_flow and RA <= 4096 and hist_add_fits(2 * batch.rows.shape[0]):
             # the [2B]-index scatter collides massively on the small alt
             # table; the histogram matmul is ~3x cheaper on the MXU, and
             # fast_flow's host-verified uniform acquire makes its int32
-            # post-scaling bit-exact (see stats.window.add_rows_hist)
+            # post-scaling bit-exact (see stats.window.bucket_add_hist)
             a_uni = jnp.max(jnp.where(batch.valid, acq, 0))
-            alt_second = _scoped(
-                "decide.record.alt_second", add_rows_hist, spec.second,
-                alt_second, alt_rec, ev_ids2, a_uni, now_idx_s)
+
+            def record_alt_rows(bucket):
+                return bucket_add_hist(bucket, alt_rec, ev_ids2, a_uni)
         else:
             acq2 = jnp.concatenate([acq, acq])
             alt_amt = jnp.where(alt_mask2, acq2, 0)
-            alt_second = _scoped(
-                "decide.record.alt_second", add_rows_multi, spec.second,
-                alt_second, alt_rec, ev_ids2, alt_amt, now_idx_s)
+
+            def record_alt_rows(bucket):
+                return bucket_add_events(bucket, alt_rec, ev_ids2, alt_amt)
+        alt_second = _record_window(
+            "decide", "alt_second", spec.second, state.alt_second, now_idx_s,
+            alt_targets, record_alt_rows)
     else:
         alt_second = state.alt_second
 
     minute = state.minute
     if spec.minute:
-        minute = _scoped(
-            "decide.refresh.minute", refresh_all, spec.minute, state.minute,
-            now_idx_m)
-        minute = _scoped(
-            "decide.record.minute", add_rows_multi, spec.minute, minute,
-            main_rec1, ev_ids1, rec_amt1, now_idx_m)
-        minute = _scoped(
-            "decide.record.minute", add_one_row, spec.minute, minute,
-            ENTRY_NODE_ROW, entry_vec, now_idx_m, sharded=spec.rows_sharded)
+        minute = _record_window(
+            "decide", "minute", spec.minute, state.minute, now_idx_m,
+            touched, record_main)
 
     if skip_threads:
         # nothing loaded reads the gauges: the scatters (+ the alt half)
@@ -790,54 +795,36 @@ def record_exits(
     entry_rt_add = jnp.sum(jnp.where(ein, rt1, 0).astype(jnp.float32))
     entry_rt_min = jnp.min(jnp.where(ein, rt1, jnp.iinfo(jnp.int32).max))
 
-    if spec.second.buckets >= 2:
-        second = _scoped(
-            "exit.refresh.second", refresh_all, spec.second, state.second,
-            now_idx_s)
-    else:
-        # B=1: same ENTRY gating as decide_entries — only refresh the
-        # entry row when an IN event actually lands on it this batch
-        entry_refresh = jnp.where(jnp.any(ein),
-                                  jnp.int32(ENTRY_NODE_ROW), pad_r)
-        second = _scoped(
-            "exit.refresh.second", refresh_rows, spec.second, state.second,
-            jnp.concatenate([main_rows, entry_refresh[None]]), now_idx_s)
-    second = _scoped(
-        "exit.record.second", add_rows_vec, spec.second, second, main_rows,
-        payload, now_idx_s, rt_ms=rt1, rt_valid=batch.valid)
-    second = _scoped(
-        "exit.record.second", add_one_row, spec.second, second, ENTRY_NODE_ROW,
-        entry_vec, now_idx_s, rt_add=entry_rt_add, rt_min=entry_rt_min,
-        sharded=spec.rows_sharded)
+    def record_main(bucket):
+        bucket = bucket_add_vecs(bucket, main_rows, payload, rt_ms=rt1,
+                                 rt_valid=batch.valid)
+        return bucket_add_row(bucket, ENTRY_NODE_ROW, entry_vec,
+                              rt_add=entry_rt_add, rt_min=entry_rt_min,
+                              sharded=spec.rows_sharded)
+
+    # B=1: same ENTRY gating as decide_entries — only refresh the entry
+    # row when an IN event actually lands on it this batch
+    entry_refresh = jnp.where(jnp.any(ein), jnp.int32(ENTRY_NODE_ROW), pad_r)
+    touched = jnp.concatenate([main_rows, entry_refresh[None]])
+    second = _record_window(
+        "exit", "second", spec.second, state.second, now_idx_s, touched,
+        record_main)
     if record_alt:
-        if spec.second.buckets >= 2:
-            alt_second = _scoped(
-                "exit.refresh.alt_second", refresh_all, spec.second,
-                state.alt_second, now_idx_s)
-        else:
-            alt_second = _scoped(
-                "exit.refresh.alt_second", refresh_rows, spec.second,
-                state.alt_second, alt_targets, now_idx_s)
         rt2 = jnp.concatenate([rt1, rt1])
         valid2 = jnp.concatenate([batch.valid, batch.valid])
-        alt_second = _scoped(
-            "exit.record.alt_second", add_rows_vec, spec.second, alt_second,
-            alt_targets, payload2, now_idx_s, rt_ms=rt2, rt_valid=valid2)
+        alt_second = _record_window(
+            "exit", "alt_second", spec.second, state.alt_second, now_idx_s,
+            alt_targets,
+            lambda bucket: bucket_add_vecs(bucket, alt_targets, payload2,
+                                           rt_ms=rt2, rt_valid=valid2))
     else:
         alt_second = state.alt_second
 
     minute = state.minute
     if spec.minute:
-        minute = _scoped(
-            "exit.refresh.minute", refresh_all, spec.minute, state.minute,
-            now_idx_m)
-        minute = _scoped(
-            "exit.record.minute", add_rows_vec, spec.minute, minute, main_rows,
-            payload, now_idx_m, rt_ms=rt1, rt_valid=batch.valid)
-        minute = _scoped(
-            "exit.record.minute", add_one_row, spec.minute, minute,
-            ENTRY_NODE_ROW, entry_vec, now_idx_m, rt_add=entry_rt_add,
-            rt_min=entry_rt_min, sharded=spec.rows_sharded)
+        minute = _record_window(
+            "exit", "minute", spec.minute, state.minute, now_idx_m, touched,
+            record_main)
 
     if skip_threads:
         threads = state.threads
@@ -950,34 +937,21 @@ def record_blocks(
         spec, rows, origin_rows, chain_rows, valid, is_in)
     amt = jnp.where(valid, acquire, 0)
     amt2 = jnp.concatenate([amt, amt])
-    if spec.second.buckets >= 2:
-        second = _scoped(
-            "blocks.refresh.second", refresh_all, spec.second, state.second,
-            now_idx_s)
-        alt_second = _scoped(
-            "blocks.refresh.alt_second", refresh_all, spec.second,
-            state.alt_second, now_idx_s)
-    else:
-        second = _scoped(
-            "blocks.refresh.second", refresh_rows, spec.second, state.second,
-            main_targets, now_idx_s)
-        alt_second = _scoped(
-            "blocks.refresh.alt_second", refresh_rows, spec.second,
-            state.alt_second, alt_targets, now_idx_s)
-    second = _scoped(
-        "blocks.record.second", add_rows, spec.second, second, main_targets,
-        ev.BLOCK, amt2, now_idx_s)
-    alt_second = _scoped(
-        "blocks.record.alt_second", add_rows, spec.second, alt_second,
-        alt_targets, ev.BLOCK, amt2, now_idx_s)
+    def record_main(bucket):
+        return bucket_add_events(bucket, main_targets, ev.BLOCK, amt2)
+
+    second = _record_window(
+        "blocks", "second", spec.second, state.second, now_idx_s,
+        main_targets, record_main)
+    alt_second = _record_window(
+        "blocks", "alt_second", spec.second, state.alt_second, now_idx_s,
+        alt_targets,
+        lambda bucket: bucket_add_events(bucket, alt_targets, ev.BLOCK, amt2))
     minute = state.minute
     if spec.minute:
-        minute = _scoped(
-            "blocks.refresh.minute", refresh_all, spec.minute, state.minute,
-            now_idx_m)
-        minute = _scoped(
-            "blocks.record.minute", add_rows, spec.minute, minute,
-            main_targets, ev.BLOCK, amt2, now_idx_m)
+        minute = _record_window(
+            "blocks", "minute", spec.minute, state.minute, now_idx_m,
+            main_targets, record_main)
     return state._replace(second=second, alt_second=alt_second, minute=minute)
 
 

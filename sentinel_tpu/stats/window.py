@@ -187,102 +187,146 @@ def refresh_rows(spec: WindowSpec, state: WindowState, rows: jnp.ndarray,
     return WindowState(counters, stamps, rt_sum, min_rt)
 
 
-def refresh_all(spec: WindowSpec, state: WindowState,
-                now_idx: jnp.ndarray) -> WindowState:
-    """Lazy-reset the current bucket of EVERY row — the hot-path form of
-    :func:`refresh_rows`.
+class Bucket(NamedTuple):
+    """The current bucket of EVERY row of a window, sliced out of the ring
+    (:func:`open_bucket`): one plane per tensor, ``k = now_idx % B`` fixed."""
 
-    A full-table pass is a dynamic-slice update (vectorized elementwise, no
-    index arrays), so at 1M rows it costs one linear sweep of
-    ``counters[:, k, :]`` instead of a million-index scatter — on the TPU
-    profile this replaced ~100 ms of scatter with sub-ms work per step.
+    counters: jnp.ndarray          # int32[R, E]
+    stamps: jnp.ndarray            # int32[R]
+    rt_sum: Optional[jnp.ndarray]  # float32[R] (None when rt is untracked)
+    min_rt: Optional[jnp.ndarray]  # int32[R]   (None when rt is untracked)
 
-    Semantics equal ``LeapArray.currentWindow(now)`` applied to all rows: at
-    bucket position ``k = now_idx % B`` the only LIVE stamp is ``now_idx``
-    itself (any other stamp at that position differs by a multiple of B and
-    reads as dead), so zero+restamp changes no window read. Requires
+
+def open_bucket(spec: WindowSpec, state: WindowState, now_idx: jnp.ndarray,
+                reset: bool = True) -> Bucket:
+    """Slice the current bucket's plane out of the ring and lazy-reset it —
+    the hot-path form of :func:`refresh_rows`, for every row at once.
+
+    A step records into the plane (:func:`bucket_add_events` and its
+    siblings) and :func:`close_bucket` writes it back with one in-place
+    dynamic-update-slice, so every scatter of the record stage has a
+    ``[R, E]`` operand and never the ring. That matters from 1,024 indices
+    up: there the TPU compiler lowers a scatter by flattening its WHOLE
+    operand to a row-major 1-D array and rebuilding the tiled array
+    afterwards — four to nine passes over the 2 GB minute ring a step when
+    the operand is the ring, whatever the indices touch.
+
+    The reset equals ``LeapArray.currentWindow(now)`` applied to all rows:
+    at bucket position ``k`` the only LIVE stamp is ``now_idx`` itself (any
+    other stamp at that position differs by a multiple of B and reads as
+    dead), so zero+restamp changes no window read. That needs
     ``buckets >= 2``: with B == 1 the previous window shares the current
     bucket position, and restamping untouched rows would erase their
-    ``prev_window_sum`` (warm-up's previousPassQps) — callers fall back to
-    :func:`refresh_rows` there.
+    ``prev_window_sum`` (warm-up's previousPassQps) — there callers run
+    :func:`refresh_rows` on the rows they touch and open with
+    ``reset=False`` (STATIC), which slices and nothing else.
     """
-    assert spec.buckets >= 2, "refresh_all needs B >= 2 (see docstring)"
+    assert spec.buckets >= 2 or not reset, \
+        "a full reset needs B >= 2 (see docstring)"
     k = _bucket_of(spec, now_idx)
-    keep = (state.stamps[:, k] == now_idx)                  # [R]
-    counters = state.counters.at[:, k, :].multiply(
-        keep[:, None].astype(jnp.int32))
-    stamps = state.stamps.at[:, k].set(now_idx)
+
+    def plane(ring):
+        return lax.dynamic_index_in_dim(ring, k, axis=1, keepdims=False)
+
+    counters, stamps = plane(state.counters), plane(state.stamps)
+    rt_sum = min_rt = None
+    if spec.track_rt:
+        rt_sum, min_rt = plane(state.rt_sum), plane(state.min_rt)
+    if reset:
+        keep = stamps == now_idx                                # [R]
+        counters = counters * keep[:, None].astype(jnp.int32)
+        stamps = jnp.full_like(stamps, now_idx)
+        if spec.track_rt:
+            rt_sum = rt_sum * keep.astype(jnp.float32)
+            min_rt = jnp.where(keep, min_rt, INT32_MAX)
+    return Bucket(counters, stamps, rt_sum, min_rt)
+
+
+def close_bucket(spec: WindowSpec, state: WindowState, bucket: Bucket,
+                 now_idx: jnp.ndarray) -> WindowState:
+    """Write an :func:`open_bucket` plane back to its place in the ring."""
+    k = _bucket_of(spec, now_idx)
+
+    def put(ring, plane):
+        return lax.dynamic_update_index_in_dim(ring, plane, k, axis=1)
+
     rt_sum, min_rt = state.rt_sum, state.min_rt
     if spec.track_rt:
-        rt_sum = rt_sum.at[:, k].multiply(keep.astype(jnp.float32))
-        min_rt = min_rt.at[:, k].set(
-            jnp.where(keep, state.min_rt[:, k], INT32_MAX))
-    return WindowState(counters, stamps, rt_sum, min_rt)
+        rt_sum = put(rt_sum, bucket.rt_sum)
+        min_rt = put(min_rt, bucket.min_rt)
+    return WindowState(put(state.counters, bucket.counters),
+                       put(state.stamps, bucket.stamps), rt_sum, min_rt)
 
 
-def add_rows_vec(spec: WindowSpec, state: WindowState, rows: jnp.ndarray,
-                 payload: jnp.ndarray, now_idx: jnp.ndarray,
-                 rt_ms: Optional[jnp.ndarray] = None,
-                 rt_valid: Optional[jnp.ndarray] = None) -> WindowState:
+def bucket_add_events(bucket: Bucket, rows: jnp.ndarray, event_ids,
+                      amounts: jnp.ndarray) -> Bucket:
+    """Scatter-add ``amounts`` into lane ``event_ids`` of ``rows`` —
+    ``event_ids`` is one event for the whole batch (a Python int) or one
+    per element (int32[N], the fused multi-event record). Padding rows
+    must use row id >= R (dropped by ``mode='drop'``); negative ids wrap
+    in JAX and must not be used as padding."""
+    return bucket._replace(counters=bucket.counters.at[rows, event_ids].add(
+        amounts, mode="drop"))
+
+
+def bucket_add_vecs(bucket: Bucket, rows: jnp.ndarray, payload: jnp.ndarray,
+                    rt_ms: Optional[jnp.ndarray] = None,
+                    rt_valid: Optional[jnp.ndarray] = None) -> Bucket:
     """Scatter-add a full event-lane vector per row: ``payload[N, E]`` lands
-    in the current bucket of ``rows`` — one scatter pass where per-event
-    ``add_rows`` calls would pay one pass each (an element contributing to
-    several lanes, e.g. SUCCESS+EXCEPTION at exit, still costs one pass).
-    Same refresh discipline and padding rules as :func:`add_rows`."""
-    k = _bucket_of(spec, now_idx)
-    counters = state.counters.at[rows, k, :].add(payload, mode="drop")
-    rt_sum, min_rt = state.rt_sum, state.min_rt
-    if spec.track_rt and rt_ms is not None:
+    on ``rows`` in one scatter pass where per-event adds would pay one pass
+    each (an element contributing to several lanes, e.g. SUCCESS+EXCEPTION
+    at exit, still costs one pass). ``rt_ms`` (where the bucket tracks rt)
+    rides along, counted where ``rt_valid``. Padding as
+    :func:`bucket_add_events`."""
+    counters = bucket.counters.at[rows, :].add(payload, mode="drop")
+    rt_sum, min_rt = bucket.rt_sum, bucket.min_rt
+    if rt_sum is not None and rt_ms is not None:
         amt = (rt_ms if rt_valid is None
                else jnp.where(rt_valid, rt_ms, 0)).astype(jnp.float32)
-        rt_sum = rt_sum.at[rows, k].add(amt, mode="drop")
+        rt_sum = rt_sum.at[rows].add(amt, mode="drop")
         mn = (rt_ms if rt_valid is None
               else jnp.where(rt_valid, rt_ms, INT32_MAX))
-        min_rt = min_rt.at[rows, k].min(mn, mode="drop")
-    return WindowState(counters, state.stamps, rt_sum, min_rt)
+        min_rt = min_rt.at[rows].min(mn, mode="drop")
+    return bucket._replace(counters=counters, rt_sum=rt_sum, min_rt=min_rt)
 
 
-def add_one_row(spec: WindowSpec, state: WindowState, row: int,
-                vec: jnp.ndarray, now_idx: jnp.ndarray,
-                rt_add: Optional[jnp.ndarray] = None,
-                rt_min: Optional[jnp.ndarray] = None,
-                sharded: bool = False) -> WindowState:
-    """Add a pre-reduced event vector to ONE row's current bucket.
+def bucket_add_row(bucket: Bucket, row: int, vec: jnp.ndarray,
+                   rt_add: Optional[jnp.ndarray] = None,
+                   rt_min: Optional[jnp.ndarray] = None,
+                   sharded: bool = False) -> Bucket:
+    """Add a pre-reduced event vector to ONE row.
 
     The global ENTRY row receives a contribution from every inbound event;
     as a scatter that doubles the index count of each recording pass — as a
-    host-side reduction + this single dynamic-slice update it is one cheap
-    elementwise op. Caller must have refreshed the row at ``now_idx``.
+    reduction + this single-row update it is one cheap elementwise op.
 
     ``sharded`` (STATIC): the row axis is split over a device mesh. The
     TPU compiler turns a ONE-index update into a dynamic slice of the row
     axis, and the SPMD partitioner answers a dynamic slice of a sharded
-    axis by gathering the WHOLE table onto every device — 8 GB for the
-    minute ring at 4M rows, in every decide and exit step. With a second,
+    axis by gathering the WHOLE operand onto every device. With a second,
     out-of-range index (dropped, as padding rows are) the update stays a
     scatter, which the partitioner keeps on the shard that owns the row
     like the per-event scatters beside it."""
-    k = _bucket_of(spec, now_idx)
-    track = spec.track_rt and rt_add is not None
-    rt_sum, min_rt = state.rt_sum, state.min_rt
+    counters, rt_sum, min_rt = bucket.counters, bucket.rt_sum, bucket.min_rt
+    track = rt_sum is not None and rt_add is not None
     if sharded:
-        rows = jnp.array([row, state.counters.shape[0]], jnp.int32)
-        counters = state.counters.at[rows, k, :].add(
+        rows = jnp.array([row, counters.shape[0]], jnp.int32)
+        counters = counters.at[rows, :].add(
             jnp.stack([vec, jnp.zeros_like(vec)]), mode="drop")
         if track:
-            rt_sum = rt_sum.at[rows, k].add(
+            rt_sum = rt_sum.at[rows].add(
                 jnp.stack([rt_add.astype(jnp.float32), jnp.float32(0)]),
                 mode="drop")
             if rt_min is not None:
-                min_rt = min_rt.at[rows, k].min(
+                min_rt = min_rt.at[rows].min(
                     jnp.stack([rt_min, INT32_MAX]), mode="drop")
-        return WindowState(counters, state.stamps, rt_sum, min_rt)
-    counters = state.counters.at[row, k, :].add(vec)
-    if track:
-        rt_sum = rt_sum.at[row, k].add(rt_add.astype(jnp.float32))
-        if rt_min is not None:
-            min_rt = min_rt.at[row, k].min(rt_min)
-    return WindowState(counters, state.stamps, rt_sum, min_rt)
+    else:
+        counters = counters.at[row, :].add(vec)
+        if track:
+            rt_sum = rt_sum.at[row].add(rt_add.astype(jnp.float32))
+            if rt_min is not None:
+                min_rt = min_rt.at[row].min(rt_min)
+    return bucket._replace(counters=counters, rt_sum=rt_sum, min_rt=min_rt)
 
 
 def _bucket_of(spec: WindowSpec, now_idx: jnp.ndarray) -> jnp.ndarray:
@@ -311,17 +355,8 @@ def add_rows(spec: WindowSpec, state: WindowState, rows: jnp.ndarray,
     return WindowState(counters, state.stamps, rt_sum, min_rt)
 
 
-def add_rows_multi(spec: WindowSpec, state: WindowState, rows: jnp.ndarray,
-                   event_ids: jnp.ndarray, amounts: jnp.ndarray,
-                   now_idx: jnp.ndarray) -> WindowState:
-    """Scatter-add with per-element event ids (fused multi-event record)."""
-    k = _bucket_of(spec, now_idx)
-    counters = state.counters.at[rows, k, event_ids].add(amounts, mode="drop")
-    return state._replace(counters=counters)
-
-
 def hist_add_fits(n: int, chunk: int = 1 << 15) -> bool:
-    """True when an ``n``-element :func:`add_rows_hist` stays inside the
+    """True when an ``n``-element :func:`bucket_add_hist` stays inside the
     f32-exactness bound EVEN AFTER chunk padding (the padding adds up to
     ``chunk - 1`` drop-class rows, so callers guarding on the raw ``n``
     alone can still trip the assert below). The one predicate both the
@@ -329,12 +364,12 @@ def hist_add_fits(n: int, chunk: int = 1 << 15) -> bool:
     return n + chunk <= (1 << 24)
 
 
-def add_rows_hist(spec: WindowSpec, state: WindowState, rows: jnp.ndarray,
-                  event_ids: jnp.ndarray, amount: jnp.ndarray,
-                  now_idx: jnp.ndarray, chunk: int = 1 << 15) -> WindowState:
-    """:func:`add_rows_multi` for SMALL row tables with heavy index
+def bucket_add_hist(bucket: Bucket, rows: jnp.ndarray,
+                    event_ids: jnp.ndarray, amount: jnp.ndarray,
+                    chunk: int = 1 << 15) -> Bucket:
+    """:func:`bucket_add_events` for SMALL row tables with heavy index
     collisions (the alt origin/chain table): per-(row, lane) counts via a
-    chunked one-hot matmul on the MXU, then ONE dense bucket-slice add —
+    chunked one-hot matmul on the MXU, then ONE dense add to the plane —
     measured 10.1 → 3.3 ms against the colliding [2B]-index scatter at
     1M updates into 1024 rows on the v5 chip (BASELINE round-5
     continuation A/B).
@@ -345,8 +380,7 @@ def add_rows_hist(spec: WindowSpec, state: WindowState, rows: jnp.ndarray,
     scaling happens in int32 afterwards, so the result is bit-identical
     to the scatter for any uniform-acquire batch. Padding rows == R drop
     via the extra one-hot class."""
-    R = state.counters.shape[0]
-    n_ev = state.counters.shape[2]
+    R, n_ev = bucket.counters.shape
     n = rows.shape[0]
     ch = min(chunk, n)
     pad = (-n) % ch          # fill the last chunk with drop-class rows —
@@ -371,9 +405,7 @@ def add_rows_hist(spec: WindowSpec, state: WindowState, rows: jnp.ndarray,
         _chunk, jnp.zeros((R + 1, n_ev), jnp.float32),
         (rows.reshape(n // ch, ch), event_ids.reshape(n // ch, ch)))
     counts = delta.astype(jnp.int32)[:R] * amount
-    k = _bucket_of(spec, now_idx)
-    counters = state.counters.at[:, k, :].add(counts)
-    return state._replace(counters=counters)
+    return bucket._replace(counters=bucket.counters + counts)
 
 
 def uncount_rows(spec: WindowSpec, state: WindowState, rows: jnp.ndarray,

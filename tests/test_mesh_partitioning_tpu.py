@@ -8,6 +8,11 @@ by all-gathering the whole second window and the whole minute ring onto
 every chip in every step — 8 GB at 4,194,304 rows. The CPU's compiler
 does not make that rewrite, so only a compile for the chip shows it.
 
+Since PR 30 the steps record into the current bucket's plane and not into
+the ring (``stats.window.open_bucket``); compiled at the cell's own size
+the plane form has to stay shard-local too, with no ring-sized temporary
+on any chip (the ring form: 2,014 MB a chip for decide's minute part).
+
 The topology is described inside a fixture, never at import: one process
 at a time may load the TPU's library (on-chip-measurement guide, §2).
 """
@@ -30,6 +35,7 @@ from sentinel_tpu.parallel.local_shard import (
 )
 
 ROWS, BATCH = 16_384, 1_024
+CELL_ROWS, CELL_BATCH = 4 << 20, 65_536     # `mesh-4m.batch-scalar`
 COLLECTIVE = re.compile(
     r"= \(?(\w+)\[([\d,]*)\][^=]*? (all-gather|all-reduce|all-to-all|"
     r"collective-permute|reduce-scatter)(?:-start)?\(")
@@ -49,22 +55,23 @@ def topo():
 
 @pytest.fixture(scope="module")
 def compiled(topo):
-    """``compiled(step, rows_sharded)``: one of the cell's two step
-    programs — the scalar decide and the exit without alt rows, as
+    """``compiled(step, rows_sharded, rows, batch)``: one of the cell's two
+    step programs — the scalar decide and the exit without alt rows, as
     ``mesh-4m.batch-scalar`` dispatches them — compiled for a state of
-    ``ROWS`` rows split over the four described chips, batch columns on
-    their batch-axis shardings."""
+    ``rows`` rows split over the four described chips, ``batch`` columns
+    on their batch-axis shardings."""
     cfg = dict(max_resources=1024, max_flow_rules=64, max_degrade_rules=16)
     small = stpu.Sentinel(stpu.load_config(**cfg))
     mesh = Mesh(np.array(topo.devices), (MESH_AXIS,))
     rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P(MESH_AXIS))
     i32, b = jnp.int32, jnp.bool_
 
-    def col(dtype):
-        return jax.ShapeDtypeStruct((BATCH,), dtype, sharding=row)
+    def build(step: str, rows_sharded: bool, rows: int = ROWS,
+              batch: int = BATCH):
+        def col(dtype):
+            return jax.ShapeDtypeStruct((batch,), dtype, sharding=row)
 
-    def build(step: str, rows_sharded: bool):
-        spec = dataclasses.replace(small.spec, rows=ROWS, alt_rows=2 * ROWS,
+        spec = dataclasses.replace(small.spec, rows=rows, alt_rows=2 * rows,
                                    rows_sharded=rows_sharded)
         shapes = pipeline.init_state_shapes(spec, 64, 16)
         st_sh = state_shardings(spec, mesh, shapes)
@@ -73,7 +80,7 @@ def compiled(topo):
             shapes, st_sh)
         rules = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(
-                tuple(ROWS if d == 1024 else d for d in x.shape), x.dtype,
+                tuple(rows if d == 1024 else d for d in x.shape), x.dtype,
                 sharding=rep), small._ruleset)
         times = jax.ShapeDtypeStruct((4,), i32, sharding=rep)
         steps = runtime._build_steps(
@@ -115,8 +122,44 @@ def test_no_collective_moves_a_window_tensor(compiled, step):
         <= 16 * BATCH < ROWS * 2 * 8
 
 
-def test_the_one_row_form_gathers_the_whole_ring(compiled):
-    """The control: the exit step with the ENTRY row updated as a one-row
-    dynamic slice (``rows_sharded=False``, the unmeshed form) gathers the
-    minute ring whole — ``ROWS × 60 × 8`` elements on every chip."""
-    assert _largest_collective(compiled("exit", False)) >= ROWS * 60 * 8
+@pytest.mark.parametrize("step", ["decide", "exit"])
+def test_at_the_cells_size_no_chip_holds_a_ring_sized_temp(compiled, step):
+    """4,194,304 rows, 65,536 lanes: under 256 MB of temp a chip (a shard
+    of the minute ring is 2,013 MB), collectives that stay batch-sized
+    (the widest is the entry batch's ``s32[65536, 8]`` all-gather) and no
+    ``while`` that carries a shard's ring."""
+    program = compiled(step, True, CELL_ROWS, CELL_BATCH)
+    assert program.memory_analysis().temp_size_in_bytes < 256 << 20
+    assert _largest_collective(program) <= 16 * CELL_BATCH
+    carried = [int(np.prod([int(d) for d in dims.split(",")]))
+               for line in program.as_text().splitlines() if " while(" in line
+               for dims in re.findall(r"\[([\d,]+)\]",
+                                      line.split(" while(")[0])]
+    assert max(carried, default=0) < CELL_ROWS // 4 * 60 * 8   # a shard's ring
+
+
+def test_a_one_row_update_of_the_sharded_ring_gathers_it_whole(topo):
+    """The control, and why ``EngineSpec.rows_sharded`` exists: the ENTRY
+    row's update as a ONE-index update of the row-sharded minute ring (the
+    unmeshed form before PR 29) becomes a dynamic slice of the sharded
+    axis, and the partitioner gathers the ring whole — ``ROWS × 60 × 8``
+    elements on every chip. Sliced out to the plane and written back with
+    nothing between, the compiler folds the three into that same update."""
+    mesh = Mesh(np.array(topo.devices), (MESH_AXIS,))
+    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P(MESH_AXIS))
+    ring = jax.ShapeDtypeStruct((ROWS, 60, 8), jnp.int32, sharding=row)
+    k = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
+    vec = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=rep)
+
+    def on_the_ring(ring, k, vec):
+        return ring.at[0, k, :].add(vec)
+
+    def on_the_plane(ring, k, vec):
+        plane = jax.lax.dynamic_index_in_dim(ring, k, 1, keepdims=False)
+        return jax.lax.dynamic_update_index_in_dim(
+            ring, plane.at[0, :].add(vec), k, 1)
+
+    for form in (on_the_ring, on_the_plane):
+        program = jax.jit(form, out_shardings=row).lower(
+            ring, k, vec).compile()
+        assert _largest_collective(program) >= ROWS * 60 * 8, form

@@ -201,7 +201,7 @@ def test_knob_envs(monkeypatch):
 # ---------------------------------------------------------------------------
 
 def test_hist_add_fits_accounts_for_chunk_padding():
-    """The guard must bound n PLUS the up-to-chunk padding add_rows_hist
+    """The guard must bound n PLUS the up-to-chunk padding bucket_add_hist
     appends (2**24 is where f32 scatter-add loses integer exactness) —
     the raw ``2*B <= 2**24`` form was off by the padding."""
     chunk = 1 << 15

@@ -211,7 +211,7 @@ def test_entry_rt_sum_no_int32_overflow_in_large_batch():
 
 
 def test_late_dispatch_within_ring_preserves_newer_buckets():
-    """refresh_all (full-table lazy reset) must not clobber newer-stamped
+    """open_bucket (full-table lazy reset) must not clobber newer-stamped
     buckets when a LATE batch (historical at_ms within one window ring —
     the fast-path flush case) dispatches after live traffic: the safe-late
     guard keeps dispatch indices within one ring of the max, under which a
@@ -252,11 +252,13 @@ def test_late_dispatch_within_ring_preserves_newer_buckets():
     assert sph.node_totals_by_row(5)["pass"] == 3
 
 
-def test_add_rows_hist_matches_scatter_bitwise():
-    """The MXU histogram add (add_rows_hist) must be bit-identical to the
-    index scatter (add_rows_multi) for uniform amounts — including
+def test_bucket_add_hist_matches_scatter_bitwise():
+    """The MXU histogram add (bucket_add_hist) must be bit-identical to the
+    index scatter (bucket_add_events) for uniform amounts — including
     padding rows (dropped), collision pileups, and every event lane."""
-    from sentinel_tpu.stats.window import add_rows_hist, add_rows_multi
+    from sentinel_tpu.stats.window import (
+        bucket_add_events, bucket_add_hist, close_bucket, open_bucket,
+    )
 
     rng = np.random.default_rng(5)
     spec = SECOND_SPEC
@@ -264,39 +266,45 @@ def test_add_rows_hist_matches_scatter_bitwise():
     n = 1 << 12
     st = init_window(spec, rows=R)
     idx = spec.index_of(1_700_000_000_250)
-    st = refresh_rows(spec, st, jnp.arange(R, dtype=jnp.int32), idx)
     rows_np = rng.integers(0, R + 1, n).astype(np.int32)   # R = padding
     rows_np[: n // 2] = 3          # heavy collision pileup on one row
     rows = jnp.asarray(rows_np)
     evs = jnp.asarray(rng.integers(0, 3, n).astype(np.int32))
+
+    def both(m, amount, **kw):
+        bucket = open_bucket(spec, st, idx)
+        got = bucket_add_hist(bucket, rows[:m], evs[:m], jnp.int32(amount),
+                              **kw)
+        want = bucket_add_events(bucket, rows[:m], evs[:m],
+                                 jnp.full(m, amount, jnp.int32))
+        return (close_bucket(spec, st, got, idx),
+                close_bucket(spec, st, want, idx))
+
     for amount in (1, 7):
-        a = jnp.int32(amount)
-        got = add_rows_hist(spec, st, rows, evs, a, idx)
-        want = add_rows_multi(spec, st, rows, evs,
-                              jnp.full(n, amount, jnp.int32), idx)
+        got, want = both(n, amount)
         assert np.array_equal(np.asarray(got.counters),
                               np.asarray(want.counters)), amount
         assert np.array_equal(np.asarray(got.stamps),
                               np.asarray(want.stamps))
+        assert int(np.asarray(got.counters).sum()) == \
+            amount * int((rows_np < R).sum())
     # non-power-of-2 n exercises the drop-class padding of the last chunk
-    m = 3000
-    got = add_rows_hist(spec, st, rows[:m], evs[:m], jnp.int32(2), idx,
-                        chunk=1024)
-    want = add_rows_multi(spec, st, rows[:m], evs[:m],
-                          jnp.full(m, 2, jnp.int32), idx)
+    got, want = both(3000, 2, chunk=1024)
     assert np.array_equal(np.asarray(got.counters),
                           np.asarray(want.counters))
 
 
 def test_hist_add_fits_accounts_for_chunk_padding():
     """Regression for the fast-flow dispatch guard (engine/pipeline.py):
-    add_rows_hist pads the batch to a full chunk with drop-class rows, so
+    bucket_add_hist pads the batch to a full chunk with drop-class rows, so
     a caller gating on raw ``n < 2**24`` can still trip the f32-exactness
     assert. hist_add_fits is the shared predicate that budgets for the
     padding — pin both sides of its boundary against the real kernel."""
     import jax
 
-    from sentinel_tpu.stats.window import add_rows_hist, hist_add_fits
+    from sentinel_tpu.stats.window import (
+        bucket_add_hist, hist_add_fits, open_bucket,
+    )
 
     CH = 1 << 15
     LIM = 1 << 24
@@ -313,11 +321,192 @@ def test_hist_add_fits_accounts_for_chunk_padding():
     def trace(n):
         # eval_shape: the assert fires at trace time, nothing allocates
         jax.eval_shape(
-            lambda r, e: add_rows_hist(spec, st, r, e, jnp.int32(1),
-                                       jnp.int32(0)),
+            lambda r, e: bucket_add_hist(
+                open_bucket(spec, st, jnp.int32(0)), r, e, jnp.int32(1)),
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((n,), jnp.int32))
 
     trace(LIM - CH)                          # boundary size traces clean
     with pytest.raises(AssertionError, match="hist_add_fits"):
         trace(LIM - CH + 1)                  # raw-n guards admit this one
+
+
+# ---------------------------------------------------------------------------
+# The plane form of the record stage (open_bucket → bucket_add_* →
+# close_bucket, driven through the pipeline's one call site) against a plain
+# NumPy replay, bit-exact for all four tensors after every step.
+# ---------------------------------------------------------------------------
+
+PLANE_R, PLANE_E = 8, ev.NUM_EVENTS
+I32_MAX = np.iinfo(np.int32).max
+
+
+class _NpWindow:
+    """A window as plain arrays; one event at a time, in batch order."""
+
+    def __init__(self, spec, state):
+        self.spec = spec
+        self.counters, self.stamps, self.rt_sum, self.min_rt = (
+            np.array(x) for x in state)
+
+    def _reset(self, r, k, now_idx):
+        if self.stamps[r, k] != now_idx:
+            self.counters[r, k, :] = 0
+            self.stamps[r, k] = now_idx
+            if self.spec.track_rt:
+                self.rt_sum[r, k] = 0.0
+                self.min_rt[r, k] = I32_MAX
+
+    def replay(self, now_idx, touched, adds, entry):
+        """``adds``: (row, lane vector[E], rt or None) per element;
+        ``entry``: (lane vector[E], rt sum or None, rt min or None)."""
+        B = self.spec.buckets
+        k = now_idx % B
+        for r in (range(PLANE_R) if B >= 2 else touched):
+            if r < PLANE_R:
+                self._reset(r, k, now_idx)
+        for r, vec, rt in adds:
+            if r >= PLANE_R:                      # padding: dropped
+                continue
+            self.counters[r, k, :] += vec
+            if rt is not None and self.spec.track_rt:
+                self.rt_sum[r, k] = np.float32(self.rt_sum[r, k]
+                                               + np.float32(rt))
+                self.min_rt[r, k] = min(self.min_rt[r, k], rt)
+        vec, rt_add, rt_min = entry
+        self.counters[0, k, :] += vec
+        if rt_add is not None and self.spec.track_rt:
+            self.rt_sum[0, k] = np.float32(self.rt_sum[0, k]
+                                           + np.float32(rt_add))
+            self.min_rt[0, k] = min(self.min_rt[0, k], rt_min)
+
+    def assert_equals(self, state, at):
+        for name, want, got in zip(state._fields, (
+                self.counters, self.stamps, self.rt_sum, self.min_rt), state):
+            got = np.asarray(got)
+            assert got.dtype == want.dtype and np.array_equal(got, want), \
+                (name, at)
+
+
+def _plane_steps(buckets):
+    """(window index, rows) of each step: the same bucket twice, the bucket
+    rolling between two steps, then a lap and more later — row 5 is touched
+    in the first step only, so its buckets sit untouched for more than B
+    windows and the last steps reuse their positions. Every batch holds
+    duplicate rows and two padding rows (id >= R)."""
+    i0 = SECOND_SPEC.index_of(1_785_324_450_225)
+    pad = [PLANE_R, PLANE_R + 3]
+    return [(i0, [1, 5, 1, 1, 3] + pad),
+            (i0, [1, 2, 2, 7] + pad),
+            (i0 + 1, [1, 1, 4, 7] + pad),
+            (i0 + buckets + 3, [2, 2, 6] + pad),
+            (i0 + 2 * buckets + 3, [1, 6, 6, 6] + pad)]
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["entry-one-index", "entry-rows-sharded"])
+@pytest.mark.parametrize("track_rt", [True, False], ids=["rt", "no-rt"])
+@pytest.mark.parametrize("buckets", [2, 60, 1], ids=["B2", "B60", "B1"])
+@pytest.mark.parametrize("form", ["events", "event", "vecs", "hist"])
+def test_plane_record_matches_numpy_replay(form, buckets, track_rt, sharded):
+    """decide's fused per-element record (``events``), record_blocks' one
+    event (``event``), exit's lane vectors with rt (``vecs``) and the alt
+    table's histogram add (``hist``), each followed by the ENTRY row's
+    pre-reduced vector in both of its forms — B == 1 on refresh_rows."""
+    import jax
+
+    from sentinel_tpu.engine.pipeline import _record_window
+    from sentinel_tpu.stats.window import (
+        bucket_add_events, bucket_add_hist, bucket_add_row, bucket_add_vecs,
+    )
+
+    spec = WindowSpec(buckets=buckets, win_ms=500, track_rt=track_rt)
+    rng = np.random.default_rng(buckets * 8 + track_rt * 2 + sharded)
+    state = init_window(spec, rows=PLANE_R)
+    model = _NpWindow(spec, state)
+
+    @jax.jit
+    def record(state, now_idx, rows, lanes, amounts, payload, rt, entry_vec,
+               entry_rt_add, entry_rt_min):
+        def adds(bucket):
+            if form == "events":
+                bucket = bucket_add_events(bucket, rows, lanes, amounts)
+            elif form == "event":
+                bucket = bucket_add_events(bucket, rows, ev.BLOCK, amounts)
+            elif form == "hist":
+                bucket = bucket_add_hist(bucket, rows, lanes, amounts[0],
+                                         chunk=4)
+            else:
+                bucket = bucket_add_vecs(bucket, rows, payload, rt_ms=rt,
+                                         rt_valid=rows < PLANE_R)
+            return bucket_add_row(
+                bucket, 0, entry_vec, sharded=sharded,
+                **(dict(rt_add=entry_rt_add, rt_min=entry_rt_min)
+                   if form == "vecs" else {}))
+        return _record_window("t", "w", spec, state, now_idx, rows, adds)
+
+    for at, (now_idx, rows) in enumerate(_plane_steps(buckets)):
+        n = len(rows)
+        lanes = rng.integers(0, 3, n).astype(np.int32)
+        amounts = (np.full(n, 3, np.int32) if form == "hist"
+                   else rng.integers(1, 5, n).astype(np.int32))
+        payload = rng.integers(0, 4, (n, PLANE_E)).astype(np.int32)
+        rt = rng.integers(1, 900, n).astype(np.int32)
+        entry_vec = rng.integers(0, 9, PLANE_E).astype(np.int32)
+        entry_rt = rng.integers(1, 900, 2).astype(np.int32)
+
+        def lane_vec(i):
+            if form == "vecs":
+                return payload[i]
+            vec = np.zeros(PLANE_E, np.int32)
+            vec[ev.BLOCK if form == "event" else lanes[i]] = amounts[i]
+            return vec
+
+        with_rt = form == "vecs"
+        model.replay(
+            now_idx, rows,
+            [(r, lane_vec(i), int(rt[i]) if with_rt else None)
+             for i, r in enumerate(rows)],
+            (entry_vec, float(entry_rt[0]) if with_rt else None,
+             int(entry_rt[1])))
+        state = record(state, jnp.int32(now_idx),
+                       jnp.asarray(rows, jnp.int32), jnp.asarray(lanes),
+                       jnp.asarray(amounts), jnp.asarray(payload),
+                       jnp.asarray(rt), jnp.asarray(entry_vec),
+                       jnp.float32(entry_rt[0]), jnp.int32(entry_rt[1]))
+        model.assert_equals(state, at)
+
+
+def test_plane_reset_keeps_the_other_buckets_and_reads():
+    """open/close touches bucket ``k`` alone: after a roll the older
+    bucket's counts, stamps and rt still read through the window sums."""
+    from sentinel_tpu.stats.window import (
+        bucket_add_vecs, close_bucket, open_bucket,
+    )
+
+    spec = SECOND_SPEC
+    st = init_window(spec, rows=4)
+    rows = jnp.array([2, 2, 9], jnp.int32)
+    payload = jnp.zeros((3, ev.NUM_EVENTS), jnp.int32).at[:, ev.SUCCESS].set(1)
+    for now_ms, rt in ((1000, 40), (1500, 15)):
+        idx = spec.index_of(now_ms)
+        bucket = bucket_add_vecs(open_bucket(spec, st, idx), rows, payload,
+                                 rt_ms=jnp.full(3, rt, jnp.int32),
+                                 rt_valid=rows < 4)
+        st = close_bucket(spec, st, bucket, idx)
+    idx = spec.index_of(1500)
+    assert _sum(spec, st, 2, ev.SUCCESS, 1500) == 4
+    assert float(rt_totals(spec, st, idx)[2]) == 110.0
+    assert int(min_rt_rows(spec, st, jnp.array([2], jnp.int32), idx,
+                           default_rt=5000)[0]) == 15
+    assert _sum(spec, st, 2, ev.SUCCESS, 2000) == 2     # the 1000 bucket died
+
+
+def test_open_bucket_refuses_a_full_reset_of_a_one_bucket_window():
+    from sentinel_tpu.stats.window import open_bucket
+
+    spec = WindowSpec(buckets=1, win_ms=1000)
+    st = init_window(spec, rows=2)
+    with pytest.raises(AssertionError, match="B >= 2"):
+        open_bucket(spec, st, jnp.int32(3))
+    assert open_bucket(spec, st, jnp.int32(3), reset=False).stamps.shape == (2,)
