@@ -44,10 +44,9 @@ times the SAME split-firing workload through two runtimes — obs enabled vs
 instrumented/uninstrumented step-time ratio at ``OBS_OVERHEAD_MAX`` (1.02,
 the ISSUE's ≤2% budget). Machine speed cancels in the ratio.
 
-Gate (e) — the dispatch-pipeline gate (r6, portable): the fused
-decide+exit program must actually save its dispatch (fused/two-call
-step-time ratio ≤ ``FUSED_MAX``), the depth-2 ``DispatchPipeline``
-overlay must cost nothing material over the bare sync loop
+Gate (e) — the dispatch-pipeline gate (r6, portable): the depth-2
+``DispatchPipeline`` overlay must cost nothing material over the bare
+sync loop
 (≤ ``PIPELINE_OVERHEAD_MAX``), and the ``pipeline.depth`` counter must
 prove batches genuinely overlapped in flight. The comment block above
 ``measure_dispatch_pipeline`` explains why the overlay's latency WIN is
@@ -70,8 +69,7 @@ above ``TRACE_REQUIRED_REQUEST_SPAN``.
 Gate (h) — the meshed-serving gate (r9): on an 8-virtual-device CPU
 mesh (a ``--meshed`` subprocess, so XLA_FLAGS lands before jax
 initializes), the row-sharded engine's verdicts through the FULL
-serving path — DispatchPipeline, fused decide+exit, split/prio/occupy
-routing, a rule reload with live occupy bookings, and the
+serving path — DispatchPipeline, split/prio/occupy routing, a rule reload with live occupy bookings, and the
 AdaptiveBatcher fan-out — must be bit-identical to the single-device
 engine, and the weak-scaling curve's normalized per-partition cost must
 stay flat (≤ ``WEAK_SCALING_FLAT_MAX``). ``CI_GATE_MESHED=0`` skips.
@@ -130,16 +128,12 @@ runs with tiering ON on both engines, so the sketch-update dispatch
 cost is already inside that band. ``CI_GATE_TIER=0`` skips. See the
 comment block above ``TIER_ENV_FLAG``.
 
-Gate (m) — the single-dispatch gate (r16): with both cadence carries
-armed, a steady fused serving batch must cost exactly ONE device
-dispatch (``pipeline.dispatches`` rises by one per batch; the sketch
-observe, the telemetry tick and the sketch decay all ride the jitted
-program's ``lax.cond`` epilogue) with each service ticking once per
-due cadence slot; verdicts AND the count-min table must be
-bit-identical between ``SENTINEL_SINGLE_DISPATCH=1`` and ``=0``
-through tiered churn with a mid-run rule reload; and the armed-vs-
-disarmed step-time ratio must stay ≤ ``OBS_OVERHEAD_MAX`` — the
-epilogue may not leak cost into batches where no tick is due.
+Gate (m) — the single-dispatch gate (r16): a decide batch on a
+tiering engine must cost exactly ONE device dispatch
+(``pipeline.dispatches`` rises by one per batch; the sketch observe
+rides the jitted decide program), and verdicts AND the count-min table
+must be bit-identical between ``SENTINEL_SINGLE_DISPATCH=1`` and ``=0``
+through tiered churn with a mid-run rule reload.
 ``CI_GATE_SINGLE_DISPATCH=0`` skips. See the comment block above
 ``SINGLE_DISPATCH_ENV_FLAG``.
 """
@@ -433,13 +427,6 @@ def measure_obs_overhead() -> dict:
 
 # Gate (e) — the dispatch-pipeline gate (r6, portable). Ratios, so machine
 # speed cancels:
-#   fused:    the allow-then-exit serving loop through
-#             decide_and_exit_raw_nowait (ONE dispatch/step) vs the
-#             decide+exit two-call form — pure dispatch-count reduction,
-#             backend-independent (measured ~0.91-0.97 on CPU; the
-#             dispatch floor of a host-attached chip: not measured). Must be
-#             ≤ FUSED_MAX of two-call: this is the gated "pipelined
-#             dispatch beats the synchronous loop" number.
 #   overlay:  DispatchPipeline(depth=2) vs the sync loop through
 #             entry_batch_nowait. On THIS backend the window is ~
 #             breakeven — the CPU PJRT client acquires donated buffers
@@ -454,7 +441,6 @@ def measure_obs_overhead() -> dict:
 #             window — recorded for the artifact trail but NOT gated: the
 #             CPU round trip is ~35 µs, so the window's deque overhead is
 #             the same order as the savings and the ratio is noise there.
-FUSED_MAX = 0.985
 PIPELINE_OVERHEAD_MAX = 1.10
 
 
@@ -503,7 +489,7 @@ def measure_dispatch_pipeline() -> dict:
             dt = fn()
             fbest[key] = min(fbest.get(key, dt), dt)
 
-    # --- runtime fixture shared by the fused and overlay pins ---
+    # --- runtime fixture of the overlay pin ---
     B, STEPS, REPEATS = 8192, 6, 8
     sph = stpu.Sentinel(stpu.load_config(
         max_resources=1024, max_flow_rules=64, max_degrade_rules=16,
@@ -513,34 +499,6 @@ def measure_dispatch_pipeline() -> dict:
     rng = np.random.default_rng(13)
     rows = sph.intern_resources(
         [f"s{int(i)}" for i in rng.integers(0, 512, B)])
-    pad_a = sph.spec.alt_rows
-    orow = np.full(B, pad_a, np.int32)
-    ctx0 = np.zeros(B, np.int32)
-    ones = np.ones(B, np.int32)
-    is_in = np.ones(B, np.bool_)
-    noprio = np.zeros(B, np.bool_)
-    rt = np.full(B, 5, np.int32)
-    err = np.zeros(B, np.bool_)
-
-    def run_two_call() -> float:
-        t0 = _time.perf_counter()
-        for _ in range(STEPS):
-            h = sph.decide_raw_nowait(rows, ctx0, orow, ctx0, orow, ones,
-                                      is_in, noprio)
-            sph.exit_batch(rows=rows, origin_rows=orow, chain_rows=orow,
-                           acquire=ones, rt_ms=rt, error=err, is_in=is_in)
-            h.result()
-        return (_time.perf_counter() - t0) / STEPS
-
-    def run_fused() -> float:
-        t0 = _time.perf_counter()
-        for _ in range(STEPS):
-            sph.decide_and_exit_raw_nowait(
-                rows, ctx0, orow, ctx0, orow, ones, is_in, noprio,
-                exit_rows=rows, exit_origin_rows=orow,
-                exit_chain_rows=orow, exit_acquire=ones, exit_rt_ms=rt,
-                exit_error=err, exit_is_in=is_in).result()
-        return (_time.perf_counter() - t0) / STEPS
 
     def run_sync() -> float:
         t0 = _time.perf_counter()
@@ -561,8 +519,7 @@ def measure_dispatch_pipeline() -> dict:
         return (_time.perf_counter() - t0) / STEPS
 
     best = {}
-    pairs = [("two_call", run_two_call), ("fused", run_fused),
-             ("sync", run_sync), ("pipelined", run_pipelined)]
+    pairs = [("sync", run_sync), ("pipelined", run_pipelined)]
     for _key, fn in pairs:                       # warm compiles + caches
         fn()
     for rep in range(REPEATS):
@@ -577,20 +534,15 @@ def measure_dispatch_pipeline() -> dict:
     # run_pipelined executed once to warm + once per repeat; average
     # in-flight depth > 1 ⟺ depth_sum > enqueues
     enqueues = (REPEATS + 1) * STEPS
-    fused_routes = sph.obs.counters.get(obs_keys.ROUTE_FUSED)
     sph.close()
     return {
         "floor_sync_s": fbest["s"], "floor_pipelined_s": fbest["p"],
         "floor_ratio": fbest["p"] / fbest["s"],
-        "two_call_s_per_step": best["two_call"],
-        "fused_s_per_step": best["fused"],
-        "fused_ratio": best["fused"] / best["two_call"],
         "sync_s_per_step": best["sync"],
         "pipelined_s_per_step": best["pipelined"],
         "pipeline_overhead_ratio": best["pipelined"] / best["sync"],
         "pipelined_depth_reached": depth_sum > enqueues,
         "pipeline_stalls": stalls,
-        "fused_dispatches": fused_routes,
     }
 
 
@@ -719,7 +671,7 @@ def measure_trace_capture() -> dict:
 #             batch above the split threshold with 10% origins and 1%
 #             prioritized, so the split + fast-occupy routes fire), a
 #             mid-stream rule reload with live occupy bookings (the
-#             carry path), the fused decide+exit tier, and the
+#             carry path), and the
 #             AdaptiveBatcher fan-out (meshed verdicts replayed
 #             flush-by-flush on the single-device twin) — and every
 #             verdict must be BIT-IDENTICAL. Placement is layout, not
@@ -810,8 +762,6 @@ def _meshed_parity(jax) -> dict:
     ones = np.ones(n, np.int32)
     is_in = np.ones(n, np.bool_)
     prio = rng.random(n) < 0.01
-    rt = np.full(n, 5, np.int32)
-    err = np.zeros(n, np.bool_)
 
     split_calls = []
     orig_split = meshed._decide_split_nowait
@@ -846,19 +796,6 @@ def _meshed_parity(jax) -> dict:
             stpu.FlowRule(resource="bulk", count=1e6),
         ])
     out["parity"]["post_reload"] = drive_raw(4, 4)
-    # fused decide+exit through the pipeline
-    fused = {}
-    for key, pipe in pipes.items():
-        tickets = [pipe.submit_fused(
-            rows, oids, orow, ctx0, chain, ones, is_in, prio,
-            exit_rows=rows, exit_origin_rows=orow, exit_chain_rows=chain,
-            exit_acquire=ones, exit_rt_ms=rt, exit_error=err,
-            exit_is_in=is_in, at_ms=T0 + (8 + i) * 50)
-            for i in range(3)]
-        fused[key] = [t.result() for t in tickets]
-    out["parity"]["fused"] = all(
-        vequal(a, b) for a, b in zip(fused["ref"], fused["meshed"]))
-
     out["split_fired"] = len(split_calls)
     out["occupy_granted_ref"] = granted["ref"]
     out["occupy_granted_meshed"] = granted["meshed"]
@@ -1546,33 +1483,22 @@ def measure_tiering() -> dict:
     return out
 
 
-# Gate (m) — the single-dispatch gate (r16). Three halves:
-#   mechanism: a ManualClock engine with BOTH cadence carries armed and
-#             steady fused (decide+exit) traffic — pipeline.dispatches
-#             must rise by exactly ONE per batch (the sketch observe,
-#             the telemetry tick and the sketch decay all ride the one
-#             jitted program, no standalone observe/tick dispatches),
-#             split_route.single_dispatch must attribute every batch,
-#             and each service's tick count must equal a host-side
-#             replay of its cadence (once per due slot, never per
-#             batch, no skipped slots).
+# Gate (m) — the single-dispatch gate (r16). One probe, two readings:
+#   mechanism: with the knob on, pipeline.dispatches must rise by
+#             exactly ONE per batch (the sketch observe rides the
+#             decide program, no standalone observe dispatch) and
+#             split_route.single_dispatch must attribute every batch.
 #   parity:   seeded churn traffic (tiered 24-row engine, mid-run rule
 #             reload, ~25% prioritized) with SENTINEL_SINGLE_DISPATCH=1
 #             vs =0 — verdict triples AND the final count-min table
 #             must be bit-identical, the probe must block somewhere
 #             (an all-PASS parity is vacuous), and the route counter
 #             must prove the two runs really took different routes.
-#   overhead: steady fused step time with the carries ARMED at 5 Hz vs
-#             disarmed, interleaved min-of-N, ratio ≤ OBS_OVERHEAD_MAX
-#             — the lax.cond epilogue may not leak cost into batches
-#             where no tick is due.
 # CI_GATE_SINGLE_DISPATCH=0 skips the whole gate.
 SINGLE_DISPATCH_ENV_FLAG = "CI_GATE_SINGLE_DISPATCH"
 
 
 def measure_single_dispatch() -> dict:
-    import time as _time
-
     import numpy as np
 
     sys.path.insert(0, str(HERE.parent))
@@ -1585,78 +1511,6 @@ def measure_single_dispatch() -> dict:
     T0 = 1_785_000_000_000
     out: dict = {}
 
-    def build(clock=None, **env):
-        prev = {k: os.environ.get(k) for k in env}
-        os.environ.update(env)
-        try:
-            return stpu.Sentinel(stpu.load_config(
-                max_resources=64, max_flow_rules=16, max_degrade_rules=16,
-                max_authority_rules=16, minute_enabled=True,
-                host_fast_path=False), clock=clock)
-        finally:
-            for k, v in prev.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
-
-    def fused_cols(s, rows):
-        n = rows.shape[0]
-        pad_a = s.spec.alt_rows
-        return (rows, np.zeros(n, np.int32), np.full(n, pad_a, np.int32),
-                np.zeros(n, np.int32), np.full(n, pad_a, np.int32),
-                np.ones(n, np.int32), np.ones(n, np.bool_),
-                np.zeros(n, np.bool_))
-
-    # ---- mechanism half: armed carries, dispatches/batch == 1 --------
-    clk = ManualClock(start_ms=T0)
-    sph = build(clk, SENTINEL_SINGLE_DISPATCH="1")
-    try:
-        rows_all = sph.intern_resources(["sd-a", "sd-b", "sd-c"])
-        t_arm = int(clk.now_ms())
-        sph.telemetry.arm_carry(400)
-        sph.tiering.arm_carry(150)
-        base = sph.obs.counters.get(obs_keys.PIPE_DISPATCH)
-        route0 = sph.obs.counters.get(obs_keys.ROUTE_SINGLE_DISPATCH)
-        tel0 = sph.telemetry.snapshot()["ticks"]
-        tier0 = sph.tiering.snapshot()["ticks"]
-        rng = np.random.default_rng(1603)
-        times, prev_rows = [], None
-        for _ in range(30):
-            rows = np.asarray(rng.choice(rows_all, size=4), np.int32)
-            times.append(int(clk.now_ms()))
-            sph.decide_and_exit_raw_nowait(
-                *fused_cols(sph, rows),
-                exit_rows=prev_rows if prev_rows is not None else rows,
-                exit_valid=(np.ones(4, np.bool_)
-                            if prev_rows is not None
-                            else np.zeros(4, np.bool_))).result()
-            prev_rows = rows
-            sph.telemetry.drain()       # the CadenceScheduler's job
-            sph.tiering.drain()
-            clk.advance_ms(50)
-
-        def claims(interval):
-            last, n = t_arm, 0
-            for t in times:
-                if t - last >= interval:
-                    last, n = t, n + 1
-            return n
-
-        disp = sph.obs.counters.get(obs_keys.PIPE_DISPATCH) - base
-        out["mech_batches"] = len(times)
-        out["dispatches_per_batch"] = disp / len(times)
-        out["route_single_dispatch"] = (
-            sph.obs.counters.get(obs_keys.ROUTE_SINGLE_DISPATCH) - route0)
-        out["tel_ticks"] = sph.telemetry.snapshot()["ticks"] - tel0
-        out["tel_ticks_expected"] = claims(400)
-        out["tier_ticks"] = sph.tiering.snapshot()["ticks"] - tier0
-        out["tier_ticks_expected"] = claims(150)
-        out["tel_drops"] = sph.telemetry.snapshot()["drops"]
-    finally:
-        sph.close()
-
-    # ---- parity half: fused observe+epilogue vs legacy, bitwise ------
     RULED = [f"sd{i}" for i in range(8)]
     SKEYS = [f"sd{i}" for i in range(48)]
 
@@ -1701,12 +1555,19 @@ def measure_single_dispatch() -> dict:
                 cclk.advance_ms(25)
             sketch = np.asarray(s.tiering._sketch).copy()
             route = s.obs.counters.get(obs_keys.ROUTE_SINGLE_DISPATCH)
-            return verdicts, sketch, route
+            disp = s.obs.counters.get(obs_keys.PIPE_DISPATCH)
+            return verdicts, sketch, route, disp
         finally:
             s.close()
 
-    on_v, on_sk, on_route = churn("1")
-    off_v, off_sk, off_route = churn("0")
+    on_v, on_sk, on_route, on_disp = churn("1")
+    off_v, off_sk, off_route, _off_disp = churn("0")
+    # mechanism: every churn batch is one whole-batch decide, and with
+    # the observe fused that is one dispatch (cold-path programs —
+    # invalidation drains, promotions, reloads — are not counted)
+    out["mech_batches"] = len(on_v)
+    out["dispatches_per_batch"] = on_disp / len(on_v)
+    out["route_single_dispatch"] = on_route
     out["parity"] = all(
         np.array_equal(a[0], b[0]) and np.array_equal(a[1], b[1])
         and np.array_equal(a[2], b[2])
@@ -1716,56 +1577,6 @@ def measure_single_dispatch() -> dict:
         int((~a).sum()) for a, _r, _w in on_v))
     out["parity_route_on"] = on_route
     out["parity_route_off"] = off_route
-
-    # ---- overhead half: armed epilogue vs disarmed, no-tick-due ------
-    # Both engines run on ManualClocks that NEVER advance inside a
-    # timed region, so no timed batch has a tick due — the gated
-    # property is precisely that the lax.cond epilogue costs nothing
-    # on those batches. Between regions the armed clock jumps past the
-    # cadence and one UNTIMED dispatch fires the real epilogue program,
-    # so the armed engine keeps the production steady state (carry
-    # bookkeeping warm, epilogue executable resident) rather than an
-    # idealized never-armed one.
-    B, STEPS, REPEATS = 4096, 6, 8
-    pair = []
-    for key, armed in (("on", True), ("off", False)):
-        sclk = ManualClock(start_ms=T0)
-        s = build(sclk, SENTINEL_SINGLE_DISPATCH="1")
-        s.load_flow_rules([stpu.FlowRule(resource="sd-api", count=1e9)])
-        rows_all = s.intern_resources([f"sd-r{i}" for i in range(8)])
-        rng = np.random.default_rng(1605)
-        cols = fused_cols(
-            s, np.asarray(rng.choice(rows_all, size=B), np.int32))
-        kw = dict(exit_rows=cols[0], exit_valid=np.zeros(B, np.bool_))
-        if armed:
-            s.telemetry.arm_carry(200)
-            s.tiering.arm_carry(200)
-        for _ in range(2):                  # warm the plain fused program
-            s.decide_and_exit_raw_nowait(*cols, **kw).result()
-        if armed:                           # warm the epilogue variant too
-            sclk.advance_ms(250)
-            s.decide_and_exit_raw_nowait(*cols, **kw).result()
-            s.telemetry.drain()
-            s.tiering.drain()
-        pair.append((key, s, sclk, cols, kw))
-    best: dict = {}
-    for rep in range(REPEATS):
-        for key, s, sclk, cols, kw in (pair if rep % 2 == 0
-                                       else pair[::-1]):
-            t0 = _time.perf_counter()
-            for _ in range(STEPS):
-                s.decide_and_exit_raw_nowait(*cols, **kw).result()
-            dt = (_time.perf_counter() - t0) / STEPS
-            best[key] = min(best.get(key, dt), dt)
-            sclk.advance_ms(250)            # untimed: epilogue fires here
-            s.decide_and_exit_raw_nowait(*cols, **kw).result()
-            s.telemetry.drain()
-            s.tiering.drain()
-    for _key, s, _clk, _cols, _kw in pair:
-        s.close()
-    out["sd_epilogue_on_s_per_step"] = best["on"]
-    out["sd_epilogue_off_s_per_step"] = best["off"]
-    out["sd_overhead_ratio"] = best["on"] / best["off"]
     return out
 
 
@@ -1845,11 +1656,11 @@ def measure_control() -> dict:
         return (m.get("by_prefix") or {}).get("steady") or {}
 
     # Warmup: a long, LIGHT episode at both batch geometries so every
-    # padded dispatch width AND the 1 Hz cadence-carry program variants
-    # compile before anything is timed — a first-occurrence XLA compile
-    # mid-replay stalls serving for hundreds of ms and would be charged
-    # to whichever config drew it. The 1.6 s duration is what lets the
-    # telemetry/tiering carries actually fire during warmup.
+    # padded dispatch width AND the tick programs compile before
+    # anything is timed — a first-occurrence XLA compile mid-replay
+    # stalls serving for hundreds of ms and would be charged to
+    # whichever config drew it. The 1.6 s duration is what lets the
+    # telemetry/tiering ticks actually fire during warmup.
     for bm in (16, 32):
         serving_bench.run_workload(
             "overload_episode", seed=3, duration_ms=1600.0,
@@ -2454,40 +2265,26 @@ def main() -> int:
             rc = 1
     if single is not None:
         if single["dispatches_per_batch"] != 1.0:
-            print(f"SINGLE-DISPATCH REGRESSION: steady-state fused "
-                  f"serving cost {single['dispatches_per_batch']} device "
-                  f"dispatches per batch with both tickers armed "
+            print(f"SINGLE-DISPATCH REGRESSION: a decide batch cost "
+                  f"{single['dispatches_per_batch']} device dispatches "
                   f"(batches={single['mech_batches']}) — the sketch "
-                  f"observe or the tick epilogue fell back to a "
-                  f"standalone program", file=sys.stderr)
+                  f"observe fell back to a standalone program",
+                  file=sys.stderr)
             rc = 1
         if single["route_single_dispatch"] < single["mech_batches"]:
             print(f"SINGLE-DISPATCH MECHANISM REGRESSION: only "
                   f"{single['route_single_dispatch']} of "
-                  f"{single['mech_batches']} fused batches earned "
+                  f"{single['mech_batches']} batches earned "
                   f"split_route.single_dispatch — the scrape can no "
-                  f"longer tell the fused route from the legacy "
+                  f"longer tell the sketch-fused decide from the legacy "
                   f"composition", file=sys.stderr)
-            rc = 1
-        if (single["tel_ticks"] != single["tel_ticks_expected"]
-                or single["tier_ticks"] != single["tier_ticks_expected"]
-                or single["tel_ticks_expected"] == 0
-                or single["tier_ticks_expected"] == 0
-                or single["tel_drops"] != 0):
-            print(f"SINGLE-DISPATCH CADENCE REGRESSION: carried ticks "
-                  f"drifted from the host cadence replay — telemetry "
-                  f"{single['tel_ticks']}/{single['tel_ticks_expected']} "
-                  f"(drops {single['tel_drops']}), tiering "
-                  f"{single['tier_ticks']}/{single['tier_ticks_expected']}"
-                  f" — the epilogue is firing per batch, skipping due "
-                  f"slots, or the probe degenerated", file=sys.stderr)
             rc = 1
         if not single["parity"] or not single["sketch_parity"]:
             print(f"SINGLE-DISPATCH PARITY REGRESSION: verdict parity="
                   f"{single['parity']}, sketch parity="
                   f"{single['sketch_parity']} between "
                   f"SENTINEL_SINGLE_DISPATCH=1 and =0 — the fused "
-                  f"observe or the lax.cond epilogue changed an answer; "
+                  f"observe changed an answer; "
                   f"SENTINEL_SINGLE_DISPATCH=0 is the operator escape "
                   f"hatch while this is debugged", file=sys.stderr)
             rc = 1
@@ -2505,14 +2302,6 @@ def main() -> int:
                   f"{single['parity_route_off']}) says the two parity "
                   f"runs did not actually take different routes",
                   file=sys.stderr)
-            rc = 1
-        if single["sd_overhead_ratio"] > OBS_OVERHEAD_MAX:
-            print(f"SINGLE-DISPATCH OVERHEAD REGRESSION: armed-epilogue "
-                  f"step time ratio "
-                  f"{round(single['sd_overhead_ratio'], 4)} > "
-                  f"{OBS_OVERHEAD_MAX} vs carries disarmed (5 Hz probe "
-                  f"cadence) — the lax.cond epilogue is leaking cost "
-                  f"into batches where no tick is due", file=sys.stderr)
             rc = 1
     if control is not None:
         c_lo, c_hi = STEADY_P99_BAND_MS
@@ -2657,13 +2446,6 @@ def main() -> int:
               "batch_max-full batch (flush_reason.full == 0) — the flash "
               "probe is not stressing the coalescing path",
               file=sys.stderr)
-        rc = 1
-    fu = disp["fused_ratio"]
-    if fu > FUSED_MAX:
-        print(f"FUSED-DISPATCH REGRESSION: fused/two-call step-time ratio "
-              f"{fu:.4f} > {FUSED_MAX} — decide_and_exit_raw_nowait no "
-              f"longer saves its dispatch (it must cost ONE dispatch, "
-              f"not two)", file=sys.stderr)
         rc = 1
     po = disp["pipeline_overhead_ratio"]
     if po > PIPELINE_OVERHEAD_MAX:

@@ -292,7 +292,6 @@ def bench_breakers():
         rt_ms=jnp.asarray(rng.integers(1, 200, B).astype(np.int32)),
         error=jnp.asarray(rng.random(B) < 0.3),
         is_in=jnp.ones(B, jnp.bool_), valid=jnp.ones(B, jnp.bool_))
-    from sentinel_tpu.engine.pipeline import decide_and_record_exits
     # same static variants the runtime selects for alt-free traffic
     # (thread gauges elided: degrade-only ruleset has no gauge readers)
     kw = dict(enable_occupy=False, record_alt=False, scalar_flow=True,
@@ -302,7 +301,6 @@ def bench_breakers():
     exit_step = jax.jit(functools.partial(record_exits, spec,
                                           record_alt=False,
                                           skip_threads=True))
-    fused = jax.jit(functools.partial(decide_and_record_exits, spec, **kw))
     sysv = jnp.asarray(np.array([0.5, 0.1], np.float32))
 
     def times(i):
@@ -310,51 +308,35 @@ def bench_breakers():
         return jnp.asarray(np.array(
             [spec.second.index_of(now), 0, now, now % 500], np.int32))
 
-    # ---- two-dispatch form (the round-1/2 shape: decide, then exit) ----
+    # decide, then exit: the two dispatches a serving step makes
     state, v0 = step(ruleset, state, ebatch, times(0), sysv)
     state = exit_step(ruleset, state, xbatch, times(0))
     np.asarray(v0.allow[:1])     # honest-mode gate (see bench.py)
     jax.block_until_ready(state)
     tick = 1
-    rates2 = []
+    rates, disp_ms, dev_ms = [], [], []
     for _ in range(REPEATS):
         t0 = time.perf_counter()
+        t_disp = 0.0
         for i in range(STEPS):
+            td = time.perf_counter()
             state, v = step(ruleset, state, ebatch, times(tick), sysv)
             state = exit_step(ruleset, state, xbatch, times(tick))
             tick += 1
-        jax.block_until_ready(state)
-        rates2.append(B * STEPS / (time.perf_counter() - t0))
-
-    # ---- fused single-dispatch form (decide_and_record_exits) ----
-    state, _ = fused(ruleset, state, ebatch, xbatch, times(tick), sysv)
-    jax.block_until_ready(state)
-    rates1, dispf_ms, devf_ms = [], [], []
-    for _ in range(REPEATS):
-        t0 = time.perf_counter()
-        t_disp_f = 0.0
-        for i in range(STEPS):
-            td = time.perf_counter()
-            state, v = fused(ruleset, state, ebatch, xbatch,
-                             times(tick), sysv)
-            tick += 1
-            t_disp_f += time.perf_counter() - td
+            t_disp += time.perf_counter() - td
         jax.block_until_ready((state, v))
-        dt1 = time.perf_counter() - t0
-        rates1.append(B * STEPS / dt1)
-        dispf_ms.append(t_disp_f / STEPS * 1000)
-        devf_ms.append((dt1 - t_disp_f) / STEPS * 1000)
-    med1, lo1, hi1, n = _band(rates1)
-    med2, lo2, hi2, _ = _band(rates2)
+        dt = time.perf_counter() - t0
+        rates.append(B * STEPS / dt)
+        disp_ms.append(t_disp / STEPS * 1000)
+        dev_ms.append((dt - t_disp) / STEPS * 1000)
+    med, lo, hi, n = _band(rates)
     return {"config": "3-circuit-breakers-entry+exit",
-            "entry_exit_pairs_per_sec": med1,
-            "band_min": lo1, "band_max": hi1, "runs": n,
-            "two_dispatch_pairs_per_sec": med2,
-            "two_dispatch_band": [lo2, hi2],
-            "host_dispatch_ms_per_step_fused": round(
-                sorted(dispf_ms)[n // 2], 3),
-            "device_bound_ms_per_step_fused": round(
-                sorted(devf_ms)[n // 2], 3)}
+            "entry_exit_pairs_per_sec": med,
+            "band_min": lo, "band_max": hi, "runs": n,
+            "host_dispatch_ms_per_step": round(
+                sorted(disp_ms)[n // 2], 3),
+            "device_bound_ms_per_step": round(
+                sorted(dev_ms)[n // 2], 3)}
 
 
 def bench_hot_param_zipf(B_override=None):

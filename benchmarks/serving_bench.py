@@ -160,13 +160,10 @@ def run_workload(name: str, *, seed: int = DEFAULT_SEED,
     _warm(sph, batch_max, reqs[0].resource if reqs else "warm/0")
     sph.obs.counters.clear()
     sph.obs.hist_request.clear()
-    # round 16 — ONE CadenceScheduler replaces the two ticker threads
-    # (rounds 12 + 15): it arms the telemetry (1 Hz) and tiering
-    # (SENTINEL_TIER_TICK_MS) epilogue carries so fused serving traffic
-    # runs the ticks inside its own dispatch, and only self-dispatches
-    # standalone ticks over idle gaps. Health + hot view land in the
-    # artifact below; the overhead ratios are gated by ci_gate gates
-    # (k) and (m).
+    # ONE CadenceScheduler is the clock of the telemetry (1 Hz) and
+    # tiering (SENTINEL_TIER_TICK_MS) ticks. Health + hot view land in
+    # the artifact below; the overhead ratio is gated by ci_gate gate
+    # (k).
     telem = getattr(sph, "telemetry", None)
     from sentinel_tpu.serving import CadenceScheduler
     ctl = None
@@ -263,10 +260,9 @@ def run_workload(name: str, *, seed: int = DEFAULT_SEED,
         "settled_obs": sph.obs.hist_request.count,
         "pipe_stall": c.get(obs_keys.PIPE_STALL),
         "pipe_depth_sum": c.get(obs_keys.PIPE_DEPTH),
-        # round 16 — device dispatches per flushed batch (ticker
-        # self-dispatches included, so steady ≈1 only when the sketch
-        # observe rides the decide program; the exact ==1 invariant on
-        # the fused path is gated by ci_gate gate (m))
+        # device dispatches per flushed batch (exits and ticks included;
+        # a decide is 1 only when the sketch observe rides the decide
+        # program — that invariant is gated by ci_gate gate (m))
         "dispatches": c.get(obs_keys.PIPE_DISPATCH),
         "route_single_dispatch": c.get(obs_keys.ROUTE_SINGLE_DISPATCH),
         "dispatches_per_batch": (

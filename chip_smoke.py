@@ -5,7 +5,7 @@ The quickest proof that the system still starts on a TPU. Through the
 entry points a user calls — ``Sentinel(load_config(...))`` →
 ``sph.frontend()`` (``AdaptiveBatcher``) → ``DispatchPipeline`` → the
 jitted tick → verdict fan-out, behind ``frontend.server.start_server``
-answering real HTTP, with the ``CadenceScheduler`` armed — it drives one
+answering real HTTP, with the ``CadenceScheduler`` running — it drives one
 deployment of 1M resident rows (``PRODUCT`` below) through every program
 family the engine serves with, then the other front door
 (``ClusterTokenServer`` over a ``ClusterEngine``, real TCP frames), and on
@@ -233,12 +233,12 @@ class Deployment:
               have=len(sph.resources), rows=geom.rows)
         self.fe = sph.frontend(batch_max=geom.tick)
         self.pipe = stpu.DispatchPipeline(sph)
-        # armed as serving_bench/start_transport arm it
+        # started as serving_bench/start_transport start it
         self.sched = stpu.CadenceScheduler(sph, telemetry_interval_sec=1.0)
         self.sched.start()
 
     # ruled rows, one disjoint group per phase so no phase reads another's
-    # window: r0/r1 fused (they also carry degrade rules), then the burst
+    # window: r0/r1 exits (they also carry degrade rules), then the burst
     # rows, the mixed tick's origin rows, the origin phase's two rows
     def ruled(self, start: int, n: int) -> List[str]:
         check(start + n <= self.geom.flow_rules, "ruled rows exhausted")
@@ -465,21 +465,20 @@ async def phase_mixed_prio(d: Deployment, http) -> dict:
     return out
 
 
-def phase_fused(d: Deployment) -> dict:
-    """Entries paired with exits through ``DispatchPipeline.submit_fused``:
-    one program decides, records RT + errors (``rt_hist``) and — a second
-    of virtual time after the last telemetry tick — runs the due cadence
-    epilogue. Every exit of r0 fails, so its exception-ratio breaker
-    opens and the next tick's entries on it are ``DegradeException``; r1
-    fails 2 of 20 and stays closed."""
+def phase_exits(d: Deployment) -> dict:
+    """Entries through ``DispatchPipeline.submit_raw``, then their exits
+    through ``exit_batch``: the exit program records RT + errors
+    (``rt_hist``) and a telemetry tick reads them back. Every exit of r0
+    fails, so its exception-ratio breaker opens and the next tick's
+    entries on it are ``DegradeException``; r1 fails 2 of 20 and stays
+    closed."""
     import numpy as np
     from sentinel_tpu.core.errors import exception_name_for
     sph, clock = d.sph, d.clock
     clock.advance_ms(2000)
-    sph.telemetry.poll()        # a tick now, so the next is due in 1000 ms
+    sph.telemetry.poll()        # a tick now: the daemon's next is 1500 ms off
     ticks0 = sph.telemetry.snapshot()["ticks"]
-    clock.advance_ms(1000)      # due for the carry, not yet stale for the daemon
-    t_carry = clock.now_ms()
+    clock.advance_ms(1000)      # a fresh window, not yet due for the daemon
     rx, ry = sph.intern_resources(["r0", "r1"])
     free = sph.intern_resources(d.fill[:16])
     pad = sph.spec.alt_rows
@@ -502,25 +501,27 @@ def phase_fused(d: Deployment) -> dict:
     rt = d.rng.integers(1, 200, len(xrows)).astype(np.int32)
     err = np.zeros(len(xrows), np.bool_)
     err[:x_exits + 2] = True            # all of r0's, two of r1's
-    v1 = d.pipe.submit_fused(*cols(rows), exit_rows=xrows, exit_rt_ms=rt,
-                             exit_error=err).result()
-    check(bool(np.all(v1.allow)), "fused tick 1 must admit everything",
+    v1 = d.pipe.submit_raw(*cols(rows)).result()
+    check(bool(np.all(v1.allow)), "tick 1 must admit everything",
           blocked=int(np.sum(~v1.allow)))
+    alt = np.full(len(xrows), pad, np.int32)
+    sph.exit_batch(rows=xrows, origin_rows=alt, chain_rows=alt,
+                   acquire=np.ones(len(xrows), np.int32), rt_ms=rt,
+                   error=err, is_in=np.ones(len(xrows), np.bool_))
+    t_tick = clock.now_ms()
+    check(sph.telemetry.tick(), "telemetry tick refused")
     clock.advance_ms(10)
     rows2 = [rx] * n_x + [ry] * n_x
-    v2 = d.pipe.submit_fused(*cols(rows2),
-                             exit_rows=np.asarray([ry] * n_x, np.int32),
-                             exit_rt_ms=np.full(n_x, 5, np.int32)).result()
+    v2 = d.pipe.submit_raw(*cols(rows2)).result()
     check(not v2.allow[:n_x].any() and bool(v2.allow[n_x:].all()),
           "breaker: r0 open, r1 closed", allow=v2.allow.tolist())
     names = {exception_name_for(int(r)) for r in v2.reason[:n_x]}
     check(names == {"DegradeException"}, "breaker block reason", got=names)
 
-    # the epilogue's telemetry tick rode the first fused dispatch
-    wait_for(lambda: sph.telemetry.snapshot()["ts_ms"] == t_carry,
-             "carried telemetry tick to land", d.sched.poll)
+    wait_for(lambda: sph.telemetry.snapshot()["ts_ms"] == t_tick,
+             "telemetry tick to land", d.sched.poll)
     check(sph.telemetry.snapshot()["ticks"] == ticks0 + 1,
-          "telemetry tick must ride the fused dispatch",
+          "one telemetry tick since the poll",
           ticks=sph.telemetry.snapshot()["ticks"], before=ticks0)
     loads, top_rows = sph.telemetry.last_topk
     check(int(top_rows[0]) == int(rx) and int(loads[0]) == n_x,
@@ -624,7 +625,7 @@ SERVED_PHASES = (
     ("scalar", phase_scalar, True),
     ("origin", phase_origin, True),
     ("mixed_prio", phase_mixed_prio, True),
-    ("fused", phase_fused, False),
+    ("exits", phase_exits, False),
     ("hot_param", phase_hot_param, False),
     ("per_call", phase_per_call, False),
     ("tier_migration", phase_tier_migration, True),

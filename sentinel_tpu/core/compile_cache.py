@@ -96,11 +96,10 @@ def program_key(kind: str, step_id: int, geometry, statics: Mapping,
     bookkeeping (``Sentinel._fetched_programs`` / ``compile_cache.hit`` /
     ``.miss`` counters).
 
-    ``kind`` names the program family (``"decide"``, ``"fused"``);
+    ``kind`` names the program family (``"decide"``, ``"decide_sd"``);
     ``step_id`` is ``id()`` of the jitted callable, so rebuilt jits
     (rule reload, geometry change) key fresh; ``geometry`` is the padded
-    batch-shape tuple (one entry for decide, ``(b_entry, b_exit)`` for
-    the fused decide+exit program); ``statics`` the static-arg flags the
+    batch-shape tuple; ``statics`` the static-arg flags the
     variant was specialized on; ``columns`` which of the batch's optional
     columns are present (``None`` or an array is part of the pytree
     structure jit specializes on: two batches that differ only there are

@@ -874,49 +874,6 @@ def record_exits(
         custom=state.custom, rt_hist=rt_hist)
 
 
-def decide_and_record_exits(
-    spec: EngineSpec,
-    rules: RuleSet,
-    state: SentinelState,
-    entry_batch: EntryBatch,
-    exit_batch: ExitBatch,
-    times: jnp.ndarray,          # int32[4]
-    sys_scalars: jnp.ndarray,    # float32[2]
-    enable_occupy: bool = False,
-    custom_slots: Tuple = (),
-    record_alt: bool = True,     # STATIC (see decide_entries)
-    scalar_flow: bool = False,   # STATIC (see decide_entries)
-    fast_flow: bool = False,     # STATIC (see decide_entries)
-    skip_auth: bool = False,     # STATIC
-    skip_sys: bool = False,      # STATIC
-    scalar_has_rl: bool = True,  # STATIC
-    skip_threads: bool = False,  # STATIC (see decide_entries)
-    sortfree: bool = False,      # STATIC (see decide_entries)
-) -> Tuple[SentinelState, Verdicts]:
-    """Fused entry+exit step: one dispatch where serving loops would pay two.
-
-    A steady-state workload completes a batch of calls per step
-    (``DegradeSlot.entry`` feeding breakers on the way in,
-    ``StatisticSlot.exit`` + ``DegradeSlot.exit`` on the way out —
-    ``StatisticSlot.java:133-178``); the exit batch is known at dispatch time
-    (it is the *previous* step's completions), so both halves fuse into one
-    jitted call. Ordering matches the two-dispatch form: exits land AFTER
-    this step's decisions, exactly like the separate ``record_exits``
-    dispatch that immediately follows ``decide_entries`` — XLA fuses the
-    window scatters of both halves into one pass over the tables, and
-    the step pays one dispatch instead of two."""
-    state, verdicts = decide_entries(
-        spec, rules, state, entry_batch, times, sys_scalars,
-        enable_occupy=enable_occupy, custom_slots=custom_slots,
-        record_alt=record_alt, scalar_flow=scalar_flow,
-        fast_flow=fast_flow, skip_auth=skip_auth, skip_sys=skip_sys,
-        scalar_has_rl=scalar_has_rl, skip_threads=skip_threads,
-        sortfree=sortfree)
-    state = record_exits(spec, rules, state, exit_batch, times,
-                         record_alt=record_alt, skip_threads=skip_threads)
-    return state, verdicts
-
-
 def record_blocks(
     spec: EngineSpec,
     state: SentinelState,

@@ -14,9 +14,11 @@ dispatch stays within the 2% observability budget (benchmarks/ci_gate.py
   meshed_total/route_total attributes how much traffic the mesh path
   carries), ``sortfree`` when the dispatch's flow programs grouped
   segments sort-free (alongside its route counter, same pattern), and
-  ``single_dispatch`` when a whole-batch decide/fused program carried
-  the tiering sketch observe inside itself (round 16 — the batch cost
-  ONE device dispatch instead of decide + observe).
+  ``single_dispatch`` when a whole-batch decide program carried the
+  tiering sketch observe inside itself (the batch cost ONE device
+  dispatch instead of decide + observe). ``fused_exit`` is a catalog
+  key nothing increments (its program family went in PR 31; the
+  catalog is append-only).
 * ``sortfree.bucket_overflow`` — claim-cascade overflow total: elements
   whose step fell back to the sorted branch (ops/sortfree.py); sustained
   growth means the bucket table is undersized for the key distribution.
@@ -183,20 +185,18 @@ TIER_PROMOTED = "tier.promoted"
 TIER_DEMOTED = "tier.demoted"
 TIER_SKETCH_OVERFLOW = "tier.sketch_overflow"
 
-# PR 16 — single-dispatch serving tick: ``pipeline.dispatches`` counts
-# DEVICE DISPATCHES issued by the serving hot path and its tickers
-# (decide = 1, split = 2, fused decide+exit = 1, exit = 1, a standalone
-# sketch observe = 1, a self-dispatched telemetry or tiering tick = 1;
+# ``pipeline.dispatches`` counts DEVICE DISPATCHES issued by the
+# serving hot path and its tickers (decide = 1, split = 2, exit = 1, a
+# standalone sketch observe = 1, a telemetry or tiering tick = 1;
 # cold-path programs — invalidation drains, promotions/restores, rule
 # reloads — are deliberately NOT counted: the key exists so
 # dispatches-per-batch is measurable from obs plumbing alone, and the
 # cold path is not per-batch). ``split_route.single_dispatch`` ticks
 # once per whole-batch dispatch that carried the tiering sketch update
-# inside the decide/fused program itself (the round-16 fused observe —
-# alongside its route counter, like ROUTE_MESHED/ROUTE_SORTFREE); the
-# per-sub-batch split pipeline fuses the sketch too but keeps its two
-# dispatches, so it never ticks this key. Gate (m) in
-# benchmarks/ci_gate.py holds steady-state dispatches/batch == 1.
+# inside the decide program itself (alongside its route counter, like
+# ROUTE_MESHED/ROUTE_SORTFREE); the per-sub-batch split pipeline fuses
+# the sketch too but keeps its two dispatches, so it never ticks this
+# key.
 PIPE_DISPATCH = "pipeline.dispatches"
 ROUTE_SINGLE_DISPATCH = "split_route.single_dispatch"
 

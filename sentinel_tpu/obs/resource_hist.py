@@ -134,21 +134,26 @@ def quantiles_from_counts(counts, quantiles: Sequence[float] = QUANTILES):
     (total == 0) yield 0.0 — "no signal", distinct from any recorded
     latency only together with the count, which callers carry.
     """
-    c = jnp.asarray(counts).astype(jnp.float32)
+    # counts accumulate in int32 — exact, and the same in any summation
+    # order — and become float32 only for the interpolation: a float32
+    # sum is inexact past 2**24 and its order differs between backends
+    c = jnp.asarray(counts).astype(jnp.int32)
     hb = c.shape[-1]
-    total = jnp.sum(c, axis=-1)                              # [...]
+    total = jnp.sum(c, axis=-1).astype(jnp.float32)          # [...]
     cum = jnp.cumsum(c, axis=-1)                             # [..., hb]
+    cum_f = cum.astype(jnp.float32)
     edges = bucket_edges_ms(hb)
     lo = jnp.asarray(edges[:-1])
     hi = jnp.asarray(edges[1:])
     outs = []
     for p in quantiles:
         rank = jnp.maximum(1.0, np.float32(p) * total)       # [...]
-        idx = jnp.sum((cum < rank[..., None]).astype(jnp.int32), axis=-1)
+        idx = jnp.sum((cum_f < rank[..., None]).astype(jnp.int32), axis=-1)
         idx = jnp.minimum(idx, hb - 1)
         cb = jnp.take_along_axis(cum, idx[..., None], axis=-1)[..., 0]
         ci = jnp.take_along_axis(c, idx[..., None], axis=-1)[..., 0]
-        frac = (rank - (cb - ci)) / jnp.maximum(ci, 1.0)
+        frac = ((rank - (cb - ci).astype(jnp.float32))
+                / jnp.maximum(ci, 1).astype(jnp.float32))
         v = lo[idx] + (hi[idx] - lo[idx]) * frac
         outs.append(jnp.where(total > 0, v, 0.0))
     return jnp.stack(outs, axis=-1).astype(jnp.float32)
@@ -156,24 +161,28 @@ def quantiles_from_counts(counts, quantiles: Sequence[float] = QUANTILES):
 
 def np_quantiles(counts, quantiles: Sequence[float] = QUANTILES
                  ) -> np.ndarray:
-    """NumPy mirror of :func:`quantiles_from_counts`, same float32
-    arithmetic order — the bit-exact reference for the merge/extract
+    """NumPy mirror of :func:`quantiles_from_counts`, same integer
+    accumulation (int64 here: equal to the device's int32 wherever that
+    has not wrapped, and exact for a multihost sum past it) and float32
+    arithmetic — the bit-exact reference for the merge/extract
     tests and the host-side fallback (multihost aggregation, the
     controller's interval deltas)."""
-    c = np.asarray(counts).astype(np.float32)
+    c = np.asarray(counts).astype(np.int64)
     hb = c.shape[-1]
-    total = np.sum(c, axis=-1)
+    total = np.sum(c, axis=-1).astype(np.float32)
     cum = np.cumsum(c, axis=-1)
+    cum_f = cum.astype(np.float32)
     edges = bucket_edges_ms(hb)
     lo, hi = edges[:-1], edges[1:]
     outs = []
     for p in quantiles:
         rank = np.maximum(np.float32(1.0), np.float32(p) * total)
-        idx = np.sum((cum < rank[..., None]).astype(np.int32), axis=-1)
+        idx = np.sum((cum_f < rank[..., None]).astype(np.int32), axis=-1)
         idx = np.minimum(idx, hb - 1)
         cb = np.take_along_axis(cum, idx[..., None], axis=-1)[..., 0]
         ci = np.take_along_axis(c, idx[..., None], axis=-1)[..., 0]
-        frac = (rank - (cb - ci)) / np.maximum(ci, np.float32(1.0))
+        frac = ((rank - (cb - ci).astype(np.float32))
+                / np.maximum(ci, 1).astype(np.float32))
         v = lo[idx] + (hi[idx] - lo[idx]) * frac
         outs.append(np.where(total > 0, v, np.float32(0.0)))
     return np.stack(outs, axis=-1).astype(np.float32)

@@ -247,10 +247,6 @@ class HotTelemetry:
         # the first completed second is the one the clock is currently in
         # minus one; earlier seconds pre-date this service
         self._last_sec = sentinel.clock.now_ms() // 1000 - 1
-        # round 16 — epilogue carry cadence: when armed (CadenceScheduler,
-        # serving.py), serving traffic runs the telemetry tick inside the
-        # fused dispatch and the ticker only self-dispatches on idle gaps
-        self._carry_ms: Optional[int] = None
         self._last_tick_ms = int(sentinel.clock.now_ms())
         self.writer = None
         self.base_name: Optional[str] = None
@@ -331,87 +327,16 @@ class HotTelemetry:
             self._obs.counters.add(obs_keys.PIPE_DISPATCH)
         return True
 
-    # ---- round 16: single-dispatch epilogue surface ------------------
-
-    def arm_carry(self, interval_ms: int) -> None:
-        """Let serving traffic carry the telemetry tick inside the fused
-        dispatch at this cadence (CadenceScheduler, serving.py)."""
-        with self._lock:
-            self._carry_ms = max(1, int(interval_ms))
-            self._last_tick_ms = int(self._sentinel.clock.now_ms())
-
-    def disarm_carry(self) -> None:
-        with self._lock:
-            self._carry_ms = None
+    # ---- the scheduler's clock (CadenceScheduler, serving.py) --------
 
     def last_tick_ms(self) -> int:
         with self._lock:
             return self._last_tick_ms
 
-    def carry_due_locked(self, now_ms: int):
-        """Engine lock held: claim one epilogue-carried tick if the
-        cadence is armed and due; → the host scalars the runtime feeds
-        the fused program's ``lax.cond`` epilogue
-        (``(now_ms, sec, append, now_idx_s, sec_idx_m)``) or None.
-
-        Exactly :meth:`tick`'s host prep — same drop-and-count bound,
-        same completed-second bookkeeping — minus the dispatch, which
-        the caller's fused serving program performs in the same engine
-        lock hold. The claim updates ``_last_tick_ms``/``_last_sec``
-        immediately so a concurrent self-dispatch fallback won't
-        double-tick."""
-        if not self.enabled or self._closed:
-            return None
+    def stamp_last_tick(self) -> None:
+        """Count the tick interval from now (``CadenceScheduler.start``)."""
         with self._lock:
-            if (self._carry_ms is None
-                    or now_ms - self._last_tick_ms < self._carry_ms):
-                return None
-            # claim the due slot even on drop: re-attempting every batch
-            # until the drain catches up would spam readback_drop far
-            # beyond the armed cadence
-            self._last_tick_ms = int(now_ms)
-            if len(self._pending) >= PENDING_MAX:
-                self._drops += 1
-                drop = True
-            else:
-                drop = False
-        if drop:
-            self._obs.counters.add(obs_keys.TELEMETRY_DROP)
-            return None
-        sec = now_ms // 1000 - 1               # last COMPLETED second
-        append = 1 if sec > self._last_sec else 0
-        spec = self._sentinel.spec
-        idx_s = int(spec.second.index_of(now_ms))
-        sec_idx_m = int(spec.minute.index_of(sec * 1000)
-                        if spec.minute is not None else 0)
-        if append:
-            self._last_sec = sec
-        return (int(now_ms), int(sec), append, idx_s, sec_idx_m)
-
-    def ring_for_fuse_locked(self) -> TelemetryRing:
-        """Engine lock held: the timeline ring operand for a fused
-        epilogue dispatch (lazily built, like :meth:`tick`'s)."""
-        if self._ring is None:
-            self._ring = init_ring(self.ring_slots)
-        return self._ring
-
-    def set_ring_locked(self, ring: TelemetryRing) -> None:
-        """Engine lock held: store the donated-output ring returned by a
-        fused epilogue dispatch whose telemetry branch was SKIPPED (the
-        ring operand is donated either way)."""
-        self._ring = ring
-
-    def queue_carry(self, prep, outs, ring: TelemetryRing) -> None:
-        """Engine lock held: queue the readback of an epilogue-carried
-        tick (``prep`` is :meth:`carry_due_locked`'s claim; the host
-        copy was started by the runtime). :meth:`drain` lands it exactly
-        like a self-dispatched one."""
-        now_ms, sec, append, _idx_s, _sec_idx_m = prep
-        self._ring = ring
-        with self._lock:
-            self._pending.append((now_ms, sec, append, outs))
-            self._ticks += 1
-        self._obs.counters.add(obs_keys.TELEMETRY_TICK)
+            self._last_tick_ms = int(self._sentinel.clock.now_ms())
 
     # ---- host side ---------------------------------------------------
 

@@ -57,7 +57,7 @@ from sentinel_tpu.core.registry import (
 )
 from sentinel_tpu.engine.pipeline import (
     EngineSpec, EntryBatch, ExitBatch, RuleSet, SentinelState, Verdicts,
-    decide_and_record_exits, decide_entries, init_state, init_state_shapes,
+    decide_entries, init_state, init_state_shapes,
     invalidate_resource_rows, record_blocks, record_exits,
 )
 from sentinel_tpu.engine import fastpath as fp_mod
@@ -125,19 +125,16 @@ def sortfree_enabled() -> bool:
 
 
 def single_dispatch_enabled() -> bool:
-    """Single-dispatch serving tick (round 16): fold the tiering
-    sketch's conservative-update scatter into the jitted decide programs
-    (the sketch table becomes another donated operand) and, on the fused
-    decide+exit path, a ``lax.cond``-gated epilogue that runs the
-    telemetry tick + sketch decay when the host says one is due — so a
-    steady-state serving batch costs exactly ONE device dispatch.
+    """Sketch-fused decide: fold the tiering sketch's conservative-update
+    scatter into the jitted decide programs (the sketch table becomes
+    another donated operand), so a decide batch on a tiering engine is
+    one device dispatch, not a decide plus a standalone observe.
     Bit-exact with the two-dispatch composition by construction (the
-    fused programs trace the same ``sketch.update_sketch`` /
-    ``sketch.tick_read`` / ``telemetry_tick`` math in the same order).
+    fused program traces the same ``sketch.update_sketch``).
     ``SENTINEL_SINGLE_DISPATCH=0`` is the operator escape hatch — it
     restores the pre-round-16 dispatch sequence AND its program cache
-    keys byte-for-byte (see docs/OPERATIONS.md "Single-dispatch
-    serving")."""
+    keys byte-for-byte (see docs/OPERATIONS.md "Sketch-fused decide and
+    the tick schedule")."""
     return _env_on("SENTINEL_SINGLE_DISPATCH")
 
 
@@ -149,6 +146,12 @@ def pipeline_depth(default: int = 2) -> int:
     except ValueError:
         return default
     return max(1, min(d, 64))
+
+
+#: Static flag names shared by every decide program (must match the
+#: ``decide_entries`` keyword surface).
+_STEP_STATICS = ("scalar_flow", "fast_flow", "skip_auth", "skip_sys",
+                 "scalar_has_rl", "skip_threads", "sortfree")
 
 
 def _build_steps(spec: EngineSpec, custom_slots: tuple, shardings=None,
@@ -174,20 +177,7 @@ def _build_steps(spec: EngineSpec, custom_slots: tuple, shardings=None,
         return jax.jit(functools.partial(
             decide_entries, spec, enable_occupy=occ,
             custom_slots=custom_slots, record_alt=alt),
-            static_argnames=("scalar_flow", "fast_flow", "skip_auth",
-                             "skip_sys", "scalar_has_rl",
-                             "skip_threads", "sortfree"), **kw_sv, **kw_d1)
-
-    def fused(occ, alt):
-        # decide+exit in ONE program (engine/pipeline.py
-        # decide_and_record_exits): the allow-then-exit serving pattern
-        # pays one dispatch where the two-call form pays two
-        return jax.jit(functools.partial(
-            decide_and_record_exits, spec, enable_occupy=occ,
-            custom_slots=custom_slots, record_alt=alt),
-            static_argnames=("scalar_flow", "fast_flow", "skip_auth",
-                             "skip_sys", "scalar_has_rl",
-                             "skip_threads", "sortfree"), **kw_sv, **kw_d1)
+            static_argnames=_STEP_STATICS, **kw_sv, **kw_d1)
 
     # jit objects are lazy (tracing happens on first call), so building all
     # variants is free; the *_noalt ones compile away the origin/chain
@@ -203,9 +193,7 @@ def _build_steps(spec: EngineSpec, custom_slots: tuple, shardings=None,
             jax.jit(functools.partial(invalidate_resource_rows, spec),
                     **kw_s, **kw_d0),
             jax.jit(functools.partial(record_blocks, spec),
-                    **kw_s, **kw_d0),
-            (fused(False, True), fused(True, True),
-             fused(False, False), fused(True, False)))
+                    **kw_s, **kw_d0))
 
 
 @functools.lru_cache(maxsize=None)
@@ -227,67 +215,33 @@ def _jitted_steps(spec: EngineSpec, custom_slots: tuple = (), shardings=None,
         return _build_steps(spec, custom_slots, shardings, donate)
     return _jitted_steps_cached(spec, donate)
 
-#: Static flag names shared by every decide-shaped program (must match
-#: the ``decide_entries`` keyword surface — _build_steps uses the same
-#: tuple inline).
-_STEP_STATICS = ("scalar_flow", "fast_flow", "skip_auth", "skip_sys",
-                 "scalar_has_rl", "skip_threads", "sortfree")
-
-#: Epilogue due-flag bits (host-computed, packed into the int32[4]
-#: ``epi`` operand as [flags, now_idx_s, sec_idx_m, append]).
-_EPI_TELEMETRY = 1       # run the telemetry tick branch
-_EPI_TIER = 2            # run the sketch decay + estimate branch
-
-
 def _build_sd_steps(spec: EngineSpec, custom_slots: tuple, shardings=None,
-                    donate: bool = True, mesh=None, tel_k: int = 1,
-                    tel_rows_per_shard: int = 0):
-    """Round-16 sketch-fused serving programs (``SENTINEL_SINGLE_DISPATCH``).
+                    donate: bool = True, mesh=None):
+    """Sketch-fused decide programs (``SENTINEL_SINGLE_DISPATCH``):
+    ``decide_entries`` + :func:`sketch.update_sketch` over the batch's
+    rows in one program, ``(rules, state, sketch, batch, times,
+    sys_scalars) → (state, verdicts, sketch)``. Four variants in
+    :func:`_build_steps`'s layout (index ``(2 if no_alt else 0) +
+    (1 if use_occ else 0)``).
 
-    Three families, mirroring :func:`_build_steps`'s variant layout
-    (index ``(2 if no_alt else 0) + (1 if use_occ else 0)``):
+    Bit-parity with the legacy composition (decide, then the standalone
+    observe) is by construction: the sketch update reads only
+    ``batch.rows``/``valid`` (never the decide outputs) and the decide
+    never reads the sketch.
 
-    * ``decide`` — ``decide_entries`` + :func:`sketch.update_sketch`
-      over the batch's rows, one program: ``(rules, state, sketch,
-      batch, times, sys_scalars) → (state, verdicts, sketch)``.
-    * ``fused`` — same fusion over ``decide_and_record_exits``.
-    * ``fused_epi`` — the fused program plus a ``lax.cond``-gated
-      epilogue: bit ``_EPI_TELEMETRY`` of ``epi[0]`` runs
-      :func:`~sentinel_tpu.obs.telemetry.telemetry_tick` over the
-      post-decide window state + timeline ring, bit ``_EPI_TIER`` runs
-      :func:`sketch.tick_read` (decay then full-table estimate).
-      Signature ``(rules, state, sketch, ring, epi, batch, xbatch,
-      times, sys_scalars) → (state, verdicts, sketch, ring, tel_outs,
-      est)``; the skipped branches return zero-shaped outputs and the
-      operands unchanged.
-
-    Bit-parity with the legacy two-dispatch composition is by
-    construction: the sketch update reads only ``batch.rows``/``valid``
-    (never the decide outputs), the decide never reads the sketch, and
-    the epilogue branches trace the exact helpers the standalone ticks
-    jit — same math, same order (observe, then decay+estimate over the
-    updated table), different program boundaries.
-
-    Sketch/ring/epilogue outputs are replicated on meshed engines
-    (``NamedSharding(mesh, P())`` — the tables are a few KB; only the
+    The sketch output is replicated on meshed engines
+    (``NamedSharding(mesh, P())`` — the table is a few KB; only the
     row-sharded state carries a layout)."""
-    from sentinel_tpu.obs.telemetry import TelemetryRing, telemetry_tick
     from sentinel_tpu.tiering import sketch as sk_mod
 
     if shardings is None or mesh is None:
         kw3: dict = {}
-        kw6: dict = {}
     else:
         from jax.sharding import NamedSharding, PartitionSpec
         st_out, vd_out = shardings
         rep = NamedSharding(mesh, PartitionSpec())
-        ring_rep = TelemetryRing(seconds=rep, lanes=rep, rt=rep,
-                                 cursor=rep)
         kw3 = {"out_shardings": (st_out, vd_out, rep)}
-        kw6 = {"out_shardings": (st_out, vd_out, rep, ring_rep, rep, rep)}
     kw_d12 = {"donate_argnums": (1, 2)} if donate else {}
-    kw_d123 = {"donate_argnums": (1, 2, 3)} if donate else {}
-    n_ev = ev.NUM_EVENTS
 
     def dec_sd(occ, alt):
         base = functools.partial(decide_entries, spec, enable_occupy=occ,
@@ -313,99 +267,17 @@ def _build_sd_steps(spec: EngineSpec, custom_slots: tuple, shardings=None,
         return jax.jit(step, static_argnames=_STEP_STATICS,
                        **kw3, **kw_d12)
 
-    def fused_sd(occ, alt, epilogue):
-        base = functools.partial(decide_and_record_exits, spec,
-                                 enable_occupy=occ,
-                                 custom_slots=custom_slots, record_alt=alt)
-
-        def step(rules, state, sketch, batch, xbatch, times, sys_scalars,
-                 scalar_flow=False, fast_flow=False, skip_auth=False,
-                 skip_sys=False, scalar_has_rl=True, skip_threads=False,
-                 sortfree=False):
-            state, verdicts = base(
-                rules, state, batch, xbatch, times, sys_scalars,
-                scalar_flow=scalar_flow, fast_flow=fast_flow,
-                skip_auth=skip_auth, skip_sys=skip_sys,
-                scalar_has_rl=scalar_has_rl, skip_threads=skip_threads,
-                sortfree=sortfree)
-            sketch, _overflow = sk_mod.update_sketch(
-                sketch, batch.rows, batch.valid)
-            return state, verdicts, sketch
-
-        if not epilogue:
-            return jax.jit(step, static_argnames=_STEP_STATICS,
-                           **kw3, **kw_d12)
-
-        def step_epi(rules, state, sketch, ring, epi, batch, xbatch,
-                     times, sys_scalars, scalar_flow=False,
-                     fast_flow=False, skip_auth=False, skip_sys=False,
-                     scalar_has_rl=True, skip_threads=False,
-                     sortfree=False):
-            state, verdicts, sketch = step(
-                rules, state, sketch, batch, xbatch, times, sys_scalars,
-                scalar_flow=scalar_flow, fast_flow=fast_flow,
-                skip_auth=skip_auth, skip_sys=skip_sys,
-                scalar_has_rl=scalar_has_rl, skip_threads=skip_threads,
-                sortfree=sortfree)
-
-            def tel_run(op):
-                second, minute, rt_hist, rg = op
-                return telemetry_tick(
-                    spec.second, spec.minute, tel_k, mesh,
-                    tel_rows_per_shard, second, minute, rt_hist, rg,
-                    epi[1], epi[2], epi[3])
-
-            def tel_skip(op):
-                _second, _minute, _rt_hist, rg = op
-                hb = spec.hist_buckets       # 0 → zero-width hist outputs
-                zk = jnp.zeros((tel_k,), jnp.int32)
-                zl = jnp.zeros((tel_k, n_ev), jnp.int32)
-                return (zk, zk, zl, zl, jnp.zeros((tel_k,), jnp.float32),
-                        jnp.zeros((n_ev,), jnp.int32),
-                        jnp.zeros((), jnp.float32),
-                        jnp.zeros((tel_k, hb), jnp.int32),
-                        jnp.zeros((tel_k, 3 if hb else 0),
-                                  jnp.float32)), rg
-
-            tel_outs, ring2 = jax.lax.cond(
-                (epi[0] & _EPI_TELEMETRY) > 0, tel_run, tel_skip,
-                (state.second, state.minute, state.rt_hist, ring))
-
-            def tier_run(sc):
-                return sk_mod.tick_read(sc, spec.rows)
-
-            def tier_skip(sc):
-                return sc, jnp.zeros((spec.rows,), jnp.int32)
-
-            sketch, est = jax.lax.cond(
-                (epi[0] & _EPI_TIER) > 0, tier_run, tier_skip, sketch)
-            return state, verdicts, sketch, ring2, tel_outs, est
-
-        return jax.jit(step_epi, static_argnames=_STEP_STATICS,
-                       **kw6, **kw_d123)
-
-    return {
-        "decide": (dec_sd(False, True), dec_sd(True, True),
-                   dec_sd(False, False), dec_sd(True, False)),
-        "fused": (fused_sd(False, True, False), fused_sd(True, True, False),
-                  fused_sd(False, False, False),
-                  fused_sd(True, False, False)),
-        "fused_epi": (fused_sd(False, True, True),
-                      fused_sd(True, True, True),
-                      fused_sd(False, False, True),
-                      fused_sd(True, False, True)),
-    }
+    return (dec_sd(False, True), dec_sd(True, True),
+            dec_sd(False, False), dec_sd(True, False))
 
 
 @functools.lru_cache(maxsize=None)
-def _sd_steps_cached(spec: EngineSpec, donate: bool, tel_k: int,
-                     tel_rows_per_shard: int):
+def _sd_steps_cached(spec: EngineSpec, donate: bool):
     """Sketch-fused programs shared across Sentinel instances with the same
-    geometry + telemetry layout — same caching policy as
-    :func:`_jitted_steps_cached` (variants with custom DeviceSlots or mesh
-    shardings stay per-instance so their compilations are collectable)."""
-    return _build_sd_steps(spec, (), donate=donate, tel_k=tel_k,
-                           tel_rows_per_shard=tel_rows_per_shard)
+    geometry — same caching policy as :func:`_jitted_steps_cached`
+    (variants with custom DeviceSlots or mesh shardings stay per-instance
+    so their compilations are collectable)."""
+    return _build_sd_steps(spec, (), donate=donate)
 
 
 # jitted once at import; shapes are padded to powers of two so the trace
@@ -867,15 +739,13 @@ class Sentinel:
         (self._jit_decide, self._jit_decide_prio,
          self._jit_decide_noalt, self._jit_decide_prio_noalt,
          self._jit_exit, self._jit_exit_noalt,
-         self._jit_invalidate, self._jit_record_blocks,
-         self._jit_fused_steps) = \
+         self._jit_invalidate, self._jit_record_blocks) = \
             _jitted_steps(self.spec, shardings=self._mesh_shardings,
                           donate=self._donate)
-        # round 16 — single-dispatch serving tick: sketch-fused decide
-        # programs built lazily (_sd_steps_locked; reset wherever the
-        # legacy 9-tuple above is reassigned). The knob off leaves every
-        # legacy path — and its program cache keys — byte-identical to
-        # pre-r16.
+        # sketch-fused decide programs, built lazily (_sd_steps_locked;
+        # reset wherever the legacy tuple above is reassigned). The knob
+        # off leaves every legacy path — and its program cache keys —
+        # byte-identical to pre-r16.
         self._single_dispatch = bool(self._tuned.get(
             "SENTINEL_SINGLE_DISPATCH", single_dispatch_enabled()))
         self._sd_steps = None
@@ -1304,30 +1174,25 @@ class Sentinel:
         (self._jit_decide, self._jit_decide_prio,
          self._jit_decide_noalt, self._jit_decide_prio_noalt,
          self._jit_exit, self._jit_exit_noalt,
-         self._jit_invalidate, self._jit_record_blocks,
-         self._jit_fused_steps) = \
+         self._jit_invalidate, self._jit_record_blocks) = \
             _jitted_steps(self.spec, self._device_slots,
                           self._mesh_shardings, donate=self._donate)
-        self._sd_steps = None       # sketch-fused variants track the 9-tuple
+        self._sd_steps = None       # sketch-fused variants track the tuple
 
     def _sd_steps_locked(self):
-        """Round-16 sketch-fused serving programs, built lazily (engine
-        lock held — the builder reads live geometry / shardings /
-        telemetry layout; plain-geometry engines share the process-wide
-        :func:`_sd_steps_cached` compilations). Never consulted with
-        ``SENTINEL_SINGLE_DISPATCH`` off."""
+        """Sketch-fused decide programs, built lazily (engine lock held —
+        the builder reads live geometry / shardings; plain-geometry
+        engines share the process-wide :func:`_sd_steps_cached`
+        compilations). Never consulted with ``SENTINEL_SINGLE_DISPATCH``
+        off."""
         if self._sd_steps is None:
             if self._device_slots or self._mesh_shardings is not None \
                     or self.mesh is not None:
                 self._sd_steps = _build_sd_steps(
                     self.spec, self._device_slots, self._mesh_shardings,
-                    donate=self._donate, mesh=self.mesh,
-                    tel_k=self.telemetry.k,
-                    tel_rows_per_shard=self.telemetry._rows_per_shard)
+                    donate=self._donate, mesh=self.mesh)
             else:
-                self._sd_steps = _sd_steps_cached(
-                    self.spec, self._donate, self.telemetry.k,
-                    self.telemetry._rows_per_shard)
+                self._sd_steps = _sd_steps_cached(self.spec, self._donate)
         return self._sd_steps
 
     def _slot_code(self, kind: str, index: int) -> int:
@@ -1519,11 +1384,10 @@ class Sentinel:
             (self._jit_decide, self._jit_decide_prio,
              self._jit_decide_noalt, self._jit_decide_prio_noalt,
              self._jit_exit, self._jit_exit_noalt,
-             self._jit_invalidate, self._jit_record_blocks,
-             self._jit_fused_steps) = \
+             self._jit_invalidate, self._jit_record_blocks) = \
                 _jitted_steps(self.spec, self._device_slots,
                               self._mesh_shardings, donate=self._donate)
-            self._sd_steps = None   # sketch-fused variants track the 9-tuple
+            self._sd_steps = None   # sketch-fused variants track the tuple
             self._occupy_live_until_ms = -1
             self._seen_idx = -(2 ** 62)
             self._fast.win_ms = max(1, new_second.win_ms)
@@ -2665,6 +2529,153 @@ class Sentinel:
         col = self._state.breakers.state
         return _jit_copy_column(col) if self._donate else col
 
+    def _breaker_ticket_locked(self):
+        """Breaker observers ride a step's existing readback: →
+        ``(seq, degrade rules, state column)`` for the deferred diff, or
+        None without observers. The seq is taken under the dispatch lock
+        so diffs land in dispatch order."""
+        if not self._breaker_observers:
+            return None
+        self._breaker_seq += 1
+        return (self._breaker_seq, self._deg.rules,
+                self._breaker_snapshot_locked())
+
+    @staticmethod
+    def _lane_eligibility(n, origin_ids, acquire, prioritized, valid,
+                          flow_slots, pad_a):
+        """Host-side route eligibility of one raw batch (numpy, before
+        any padding) → ``(vfull, oid_np, prio_np, acq_uniform,
+        no_origin_ids, key_fits, any_prio)``.
+
+        Only lanes the caller marked valid count: arbitrary values on
+        invalid lanes are masked device-side and must not disqualify a
+        fast path. A shorter ``valid`` is legal (pad_to fills False).
+        ``key_fits``: the fast general path's composite rank key
+        (``flow_slots`` × (``pad_a`` + 1)) must fit int32. ``prio_np`` is
+        the one host copy of the prioritized column, reused by the
+        any-prio check, the split mask and the occupy-granted count."""
+        vfull = np.ones(n, np.bool_)
+        if valid is not None:
+            vsrc = np.asarray(valid, bool)
+            m = min(n, vsrc.shape[0])
+            vfull[:] = False
+            vfull[:m] = vsrc[:m]
+        acq_np = np.asarray(acquire)
+        oid_np = np.asarray(origin_ids)
+        acq_v = acq_np if valid is None else acq_np[vfull]
+        acq_uniform = (acq_v.size > 0
+                       and int(acq_v.min()) == int(acq_v.max()) >= 1)
+        oid_v = oid_np if valid is None else oid_np[vfull]
+        no_origin_ids = int(np.max(oid_v, initial=0)) == 0
+        key_fits = flow_slots * (pad_a + 1) < 2 ** 31
+        prio_np = np.asarray(prioritized)
+        return (vfull, oid_np, prio_np, acq_uniform, no_origin_ids,
+                key_fits, bool(prio_np.any()))
+
+    def _step_scalars(self, now):
+        """The ``(times, sys_scalars)`` operands of a decide step stamped
+        at ``now``."""
+        times = self._time_scalars(now)
+        load1, cpu = self._cpu.sample()
+        return times, jnp.asarray(np.array([load1, cpu], np.float32))
+
+    def _base_flags(self) -> dict:
+        """The static flags every decide program takes; the route adds
+        its own (``scalar_flow`` / ``fast_flow`` + ``scalar_has_rl``)."""
+        flags = {"skip_auth": self._skip_auth,
+                 "skip_sys": self._skip_sys,
+                 "skip_threads": self._skip_threads}
+        if self._sortfree:
+            # conditional key presence: with sortfree disabled the
+            # flags dict — hence every cached program key — is
+            # byte-identical to pre-round-10 builds
+            flags["sortfree"] = True
+        return flags
+
+    def _observe_locked(self, *batches):
+        """Hot-set sketch observe (tiering) for the batches about to be
+        decided → ``(sd_sketch, standalone observes dispatched)``.
+        Single-dispatch engines fuse the scatter-max INTO the decide
+        program (the sketch rides as a donated operand, returned here);
+        otherwise ``sd_sketch`` is None and the legacy standalone observe
+        is dispatched per batch. Padding lanes are valid=False no-ops
+        either way."""
+        sd_sketch = (self.tiering.sketch_for_fuse_locked()
+                     if self._single_dispatch else None)
+        observed = 0
+        if sd_sketch is None:
+            for b in batches:
+                observed += int(self.tiering.observe_locked(b.rows, b.valid))
+        return sd_sketch, observed
+
+    def _occupy_stamp_locked(self, any_prio: bool, now: int) -> bool:
+        """Static occupy variant: the occupy-aware pipeline runs only when
+        this batch is prioritized OR a previous booking can still be live
+        (bookings last ≤ B+1 windows — a concurrent prioritized batch
+        since the caller's optimistic host check keeps occupy live);
+        everything else compiles to a pipeline with zero occupy code."""
+        if any_prio:
+            self._occupy_live_until_ms = now + (
+                (self.spec.second.buckets + 1) * self.spec.second.win_ms)
+        return any_prio or now < self._occupy_live_until_ms
+
+    def _run_decide_locked(self, batch, flags, no_alt, use_occ, sd_sketch,
+                           state, times, sys_scalars):
+        """THE place a decide program is dispatched (engine lock held) →
+        ``(state, verdicts, sketch)``. ``no_alt`` / ``use_occ`` pick the
+        variant — the *_noalt ones compile the alt-table scatters away
+        (origin ids without rows are fine for the elision: the fast path
+        matches them by ID) — and ``sd_sketch`` the family: the
+        same-indexed sketch-fused program when not None, else the legacy
+        one (``sketch`` is then None)."""
+        if sd_sketch is not None:
+            step = self._sd_steps_locked()[
+                (2 if no_alt else 0) + (1 if use_occ else 0)]
+            self._note_program_locked("decide_sd", step, batch, flags)
+            return step(self._ruleset, state, sd_sketch, batch, times,
+                        sys_scalars, **flags)
+        if no_alt:
+            step = (self._jit_decide_prio_noalt if use_occ
+                    else self._jit_decide_noalt)
+        else:
+            step = self._jit_decide_prio if use_occ else self._jit_decide
+        self._note_program_locked("decide", step, batch, flags)
+        state, verdicts = step(self._ruleset, state, batch, times,
+                               sys_scalars, **flags)
+        return state, verdicts, None
+
+    def _settle_decide(self, staged, parts, granted, brk, obs_on, tr,
+                       span, t_disp, n) -> None:
+        """Shared tail of a decide handle's deferred read, run once every
+        verdict array of ``parts`` is on the host. That proves the device
+        consumed the staged host operands: only now may the slots be
+        reused (a read that raised instead just leaks its slots — safe).
+        ``granted`` = ``(allow, wait_ms, prioritized)`` host columns of
+        the lanes that may have booked, or None."""
+        while staged:
+            ring, slot = staged.pop()
+            ring.release(slot)
+        if obs_on:
+            obs = self.obs
+            t_end = obs.spans.now_ns()
+            obs.hist_dispatch.record(t_end - t_disp)
+            if tr:
+                obs.spans.record(tr, span, t_disp, t_end, n=n)
+            ovf = 0
+            for v in parts:
+                if v.sf_overflow is not None:
+                    ovf += int(np.asarray(v.sf_overflow))
+            if ovf:
+                obs.counters.add(obs_keys.SORTFREE_OVERFLOW, ovf)
+            if granted is not None:
+                allow, wait_ms, prio = granted
+                booked = int(np.count_nonzero(allow & (wait_ms > 0) & prio))
+                if booked:
+                    obs.counters.add(obs_keys.OCCUPY_GRANTED, booked)
+        if brk is not None:
+            self._diff_and_fire_breakers(
+                brk[0], brk[1], np.asarray(brk[2][:-1]).tolist())
+
     def decide_raw_nowait(self, rows, origin_ids, origin_rows, context_ids,
                           chain_rows, acquire, is_in, prioritized, *,
                           param_rules=None, param_keys=None,
@@ -2701,31 +2712,12 @@ class Sentinel:
                                         if obs_on else 0)
         t_d0 = obs.spans.now_ns() if obs_on else 0
         pad_a = self.spec.alt_rows
-        # ---- host-side eligibility (numpy, before any padding) ----
-        # Only lanes the caller marked valid count: arbitrary values on
-        # invalid lanes are masked device-side and must not disqualify a
-        # fast path. A shorter `valid` is legal (pad_to fills False).
-        vfull = np.ones(n, np.bool_)
-        if valid is not None:
-            vsrc = np.asarray(valid, bool)
-            m = min(n, vsrc.shape[0])
-            vfull[:] = False
-            vfull[:m] = vsrc[:m]
-        acq_np = np.asarray(acquire)
-        oid_np = np.asarray(origin_ids)
-        acq_v = acq_np if valid is None else acq_np[vfull]
-        acq_uniform = (acq_v.size > 0
-                       and int(acq_v.min()) == int(acq_v.max()) >= 1)
-        oid_v = oid_np if valid is None else oid_np[vfull]
-        no_origin_ids = int(np.max(oid_v, initial=0)) == 0
+        (vfull, oid_np, prio_np, acq_uniform, no_origin_ids, key_fits,
+         any_prio) = self._lane_eligibility(
+            n, origin_ids, acquire, prioritized, valid,
+            self._ruleset.flow_table.active.shape[0],  # graftlint: disable=LOCK002 -- single atomic reference read; rule swaps publish a complete RuleSet under the lock
+            pad_a)
         no_alt_rows = self._batch_has_no_alt(origin_rows, chain_rows)
-        # the fast general path's composite rank key must fit int32
-        key_fits = (self._ruleset.flow_table.active.shape[0]  # graftlint: disable=LOCK002 -- single atomic reference read; rule swaps publish a complete RuleSet under the lock
-                    * (pad_a + 1)) < 2 ** 31
-        # one host copy of the prioritized column, reused by the any-prio
-        # check, the split mask, and the occupy-granted counting below
-        prio_np = np.asarray(prioritized)
-        any_prio = bool(prio_np.any())
         now = self.clock.now_ms() if at_ms is None else at_ms
 
         # ---- per-event split (occupy state re-verified under the lock
@@ -2781,13 +2773,7 @@ class Sentinel:
                 rows, origin_ids, origin_rows, context_ids, chain_rows,
                 acquire, is_in, prioritized, vfull, param_rules, param_keys,
                 cluster_fallback, count_thread, record_block, staged=staged)
-            # no_alt_rows (computed above) is about ROWS only: batches with no
-            # real origin/chain rows take the *_noalt step variants (the
-            # alt-table scatters compile away; origin ids without rows are
-            # fine for the elision — the fast path matches them by ID)
-            times = self._time_scalars(now)
-            load1, cpu = self._cpu.sample()
-            sys_scalars = jnp.asarray(np.array([load1, cpu], np.float32))
+            times, sys_scalars = self._step_scalars(now)
             lock_wait = obs.phase("engine.lock_wait", n=n, trace=tr).start()
             with self._lock:
                 lock_wait.stop()
@@ -2797,42 +2783,11 @@ class Sentinel:
                     batch = batch._replace(param_rules=None, param_keys=None)
                 now, times = self._restamp_if_stale_locked(at_ms, now, times)
                 self._drain_evictions_locked()
-                # hot-set sketch observe (tiering): single-dispatch engines
-                # fuse the scatter-max INTO the decide program below (round
-                # 16 — the sketch rides as a donated operand); the legacy
-                # standalone dispatch stays as the disabled/fallback path.
-                # Padding lanes are valid=False no-ops either way.
-                sd_sketch = (self.tiering.sketch_for_fuse_locked()
-                             if self._single_dispatch else None)
-                observed = False
-                if sd_sketch is None:
-                    observed = self.tiering.observe_locked(batch.rows,
-                                                           batch.valid)
+                sd_sketch, observed = self._observe_locked(batch)
                 self._seen_idx = max(self._seen_idx,
                                      self.spec.second.index_of(now))
-                # static occupy variant: the occupy-aware pipeline runs only
-                # when this batch is prioritized OR a previous booking can
-                # still be live (bookings last ≤ B+1 windows); everything else
-                # compiles to a pipeline with zero occupy code
-                if any_prio:
-                    self._occupy_live_until_ms = now + (
-                        (self.spec.second.buckets + 1)
-                        * self.spec.second.win_ms)
-                use_occ = any_prio or now < self._occupy_live_until_ms
-                if no_alt_rows:
-                    decide = (self._jit_decide_prio_noalt if use_occ
-                              else self._jit_decide_noalt)
-                else:
-                    decide = (self._jit_decide_prio if use_occ
-                              else self._jit_decide)
-                flags = {"skip_auth": self._skip_auth,
-                         "skip_sys": self._skip_sys,
-                         "skip_threads": self._skip_threads}
-                if self._sortfree:
-                    # conditional key presence: with sortfree disabled the
-                    # flags dict — hence every cached program key — is
-                    # byte-identical to pre-round-10 builds
-                    flags["sortfree"] = True
+                use_occ = self._occupy_stamp_locked(any_prio, now)
+                flags = self._base_flags()
                 if (no_alt_rows and no_origin_ids and not any_prio
                         and cluster_fallback is None and acq_uniform):
                     # scalar admission path (rules/flow.flow_check_scalar);
@@ -2850,29 +2805,14 @@ class Sentinel:
                     # whole-batch demotion to the sorted path
                     flags["fast_flow"] = True
                     flags["scalar_has_rl"] = self._scalar_has_rl
+                with obs.annotate("sentinel_tpu.decide"):
+                    self._state, verdicts, new_sketch = \
+                        self._run_decide_locked(
+                            batch, flags, no_alt_rows, use_occ, sd_sketch,
+                            self._state, times, sys_scalars)
                 if sd_sketch is not None:
-                    dec_sd = self._sd_steps_locked()["decide"][
-                        (2 if no_alt_rows else 0) + (1 if use_occ else 0)]
-                    self._note_program_locked("decide_sd", dec_sd, batch, flags)
-                    with obs.annotate("sentinel_tpu.decide"):
-                        state, verdicts, new_sketch = dec_sd(
-                            self._ruleset, self._state, sd_sketch, batch,
-                            times, sys_scalars, **flags)
                     self.tiering.set_sketch_locked(new_sketch)
-                else:
-                    self._note_program_locked("decide", decide, batch, flags)
-                    with obs.annotate("sentinel_tpu.decide"):
-                        state, verdicts = decide(
-                            self._ruleset, self._state, batch, times,
-                            sys_scalars, **flags)
-                self._state = state
-                # breaker observers: ride the existing readback (seq taken
-                # under the dispatch lock so diffs land in dispatch order)
-                brk = None
-                if self._breaker_observers:
-                    self._breaker_seq += 1
-                    brk = (self._breaker_seq, self._deg.rules,
-                           self._breaker_snapshot_locked())
+                brk = self._breaker_ticket_locked()
             start_host_copy((verdicts.allow, verdicts.reason, verdicts.wait_ms)
                             + ((brk[2],) if brk else ()))
             if obs_on:
@@ -2890,61 +2830,33 @@ class Sentinel:
                     obs.counters.add(obs_keys.ROUTE_SORTFREE)
                 if self.mesh is not None:
                     obs.counters.add(obs_keys.ROUTE_MESHED)
-                obs.counters.add(obs_keys.PIPE_DISPATCH,
-                                 2 if observed else 1)
+                obs.counters.add(obs_keys.PIPE_DISPATCH, 1 + observed)
                 if sd_sketch is not None:
                     obs.counters.add(obs_keys.ROUTE_SINGLE_DISPATCH)
                 dispatch.note = route.split(".", 1)[1]
         t_disp = obs.spans.now_ns() if obs_on else 0
-        prio_np_full = prio_np if any_prio else None
 
         def _read() -> Verdicts:
             out = Verdicts(allow=np.asarray(verdicts.allow)[:n],
                            reason=np.asarray(verdicts.reason)[:n],
                            wait_ms=np.asarray(verdicts.wait_ms)[:n])
-            # verdict materialization proves the device consumed the
-            # staged host operands: only now may the slots be reused (a
-            # read that raised instead just leaks its slots — safe)
-            while staged:
-                ring, slot = staged.pop()
-                ring.release(slot)
-            if obs_on:
-                t_end = obs.spans.now_ns()
-                obs.hist_dispatch.record(t_end - t_disp)
-                if tr:
-                    obs.spans.record(tr, "decide.device", t_disp, t_end,
-                                     n=n)
-                if verdicts.sf_overflow is not None:
-                    ovf = int(np.asarray(verdicts.sf_overflow))
-                    if ovf:
-                        obs.counters.add(obs_keys.SORTFREE_OVERFLOW, ovf)
-                if prio_np_full is not None:
-                    granted = int(np.count_nonzero(
-                        out.allow & (out.wait_ms > 0)
-                        & prio_np_full[:n]))
-                    if granted:
-                        obs.counters.add(obs_keys.OCCUPY_GRANTED, granted)
-            if brk is not None:
-                self._diff_and_fire_breakers(
-                    brk[0], brk[1], np.asarray(brk[2][:-1]).tolist())
+            self._settle_decide(
+                staged, (verdicts,),
+                (out.allow, out.wait_ms, prio_np[:n]) if any_prio else None,
+                brk, obs_on, tr, "decide.device", t_disp, n)
             return out
 
         return self._pending_verdicts(_read)
 
-    def _note_program_locked(self, kind: str, step, batch, flags,
-                             xbatch=None) -> None:
+    def _note_program_locked(self, kind: str, step, batch, flags) -> None:
         """First-vs-repeat dispatch accounting per (program, padded batch
         geometry, statics) combo: ``compile_cache.miss`` on the dispatch
         that traces and compiles the program (or loads it from the
         persistent cache), ``compile_cache.hit`` on every later one.
         ``kind`` separates families that share a jit object's statics
-        but are different executables (``decide`` / ``decide_sd`` /
-        ``fused`` / ``fused_sd`` / ``fused_sd_epi``)."""
+        but are different executables (``decide`` / ``decide_sd``)."""
         geometry = (int(batch.rows.shape[0]),)
         columns = tuple(c is not None for c in batch)
-        if xbatch is not None:
-            geometry += (int(xbatch.rows.shape[0]),)
-            columns += tuple(c is not None for c in xbatch)
         key = program_key(kind, id(step), geometry, flags, columns)
         hit = key in self._fetched_programs
         if not hit:
@@ -2965,7 +2877,7 @@ class Sentinel:
                            count_thread, record_block,
                            staged=None) -> EntryBatch:
         """Pad raw numpy event arrays into a device EntryBatch (shared by
-        the whole-batch, split, and fused dispatch paths).
+        the whole-batch and split dispatch paths).
 
         Serving-sized batches fill a preallocated staging slot
         (``_StagingRing``) in place of ~9 fresh allocations per step;
@@ -3039,7 +2951,7 @@ class Sentinel:
 
     def _place_batch(self, batch, n: int):
         """Meshed-mode batch-axis placement (no-op otherwise); shared by
-        the entry, split, fused, and exit dispatch tiers. ``n`` events
+        the entry, split and exit dispatch tiers. ``n`` events
         are placed under the phase ``batch.place`` — the host work only
         the mesh path does, apart from the dispatch phase around it."""
         if not self._place_batches:
@@ -3104,9 +3016,7 @@ class Sentinel:
             take(cluster_fallback, idx_g), take(count_thread, idx_g),
             take(record_block, idx_g), staged=staged)
         no_alt_g = self._batch_has_no_alt(orow_g, crow_g)
-        times = self._time_scalars(now)
-        load1, cpu = self._cpu.sample()
-        sys_scalars = jnp.asarray(np.array([load1, cpu], np.float32))
+        times, sys_scalars = self._step_scalars(now)
         lock_wait = obs.phase("engine.lock_wait", n=n, trace=tr).start()
         with self._lock:
             lock_wait.stop()
@@ -3114,80 +3024,34 @@ class Sentinel:
                 bs = bs._replace(param_rules=None, param_keys=None)
                 bg = bg._replace(param_rules=None, param_keys=None)
             self._drain_evictions_locked()
-            # hot-set sketch observe (tiering): both split halves carry
-            # real traffic rows; padding lanes are valid=False no-ops.
-            # Single-dispatch mode (round 16) folds the observe into each
-            # sub-step instead — the sketch threads through both halves.
-            sd_sketch = (self.tiering.sketch_for_fuse_locked()
-                         if self._single_dispatch else None)
-            observed = 0
-            if sd_sketch is None:
-                observed += int(self.tiering.observe_locked(bs.rows,
-                                                            bs.valid))
-                observed += int(self.tiering.observe_locked(bg.rows,
-                                                            bg.valid))
+            # both split halves carry real traffic rows; with the observe
+            # fused, the sketch threads through both sub-steps
+            sd_sketch, observed = self._observe_locked(bs, bg)
             self._seen_idx = max(self._seen_idx,
                                  self.spec.second.index_of(now))
-            flags = {"skip_auth": self._skip_auth,
-                     "skip_sys": self._skip_sys,
-                     "skip_threads": self._skip_threads}
-            if self._sortfree:
-                flags["sortfree"] = True   # see decide_raw_nowait
-            # occupy re-verify under the lock: this batch's prioritized
-            # events, or a concurrent prioritized batch since the
-            # optimistic host check, keep occupy live — both sides then
-            # take their occupy-AWARE fast variants (scalar reads live
-            # bookings via occupy_base, general may book via
+            flags = self._base_flags()
+            # occupy re-verified under the lock: both sides take their
+            # occupy-AWARE fast variants when it is live (scalar reads
+            # live bookings via occupy_base, general may book via
             # flow_check_fast_occupy); neither demotes to the sorted path
-            if any_prio:
-                self._occupy_live_until_ms = now + (
-                    (self.spec.second.buckets + 1)
-                    * self.spec.second.win_ms)
-            use_occ = any_prio or now < self._occupy_live_until_ms
+            use_occ = self._occupy_stamp_locked(any_prio, now)
             fl_s = dict(flags, scalar_flow=True,
                         scalar_has_rl=self._scalar_has_rl)
             fl_g = dict(flags, fast_flow=True,
                         scalar_has_rl=self._scalar_has_rl)
-            if use_occ:
-                dec_s = self._jit_decide_prio_noalt
-                dec_g = (self._jit_decide_prio_noalt if no_alt_g
-                         else self._jit_decide_prio)
-            else:
-                dec_s = self._jit_decide_noalt
-                dec_g = (self._jit_decide_noalt if no_alt_g
-                         else self._jit_decide)
+            with obs.annotate("sentinel_tpu.decide_split"):
+                # the scalar half is always the noalt variant (origin-free
+                # by construction), the general half keys off its own
+                # no_alt_g
+                state, v1, sketch = self._run_decide_locked(
+                    bs, fl_s, True, use_occ, sd_sketch, self._state, times,
+                    sys_scalars)
+                self._state, v2, sketch = self._run_decide_locked(
+                    bg, fl_g, no_alt_g, use_occ, sketch, state, times,
+                    sys_scalars)
             if sd_sketch is not None:
-                # sketch-fused sub-steps: the scalar half is always the
-                # noalt variant (origin-free by construction), the
-                # general half keys off its own no_alt_g
-                sd_steps = self._sd_steps_locked()["decide"]
-                dec_s_sd = sd_steps[2 + (1 if use_occ else 0)]
-                dec_g_sd = sd_steps[(2 if no_alt_g else 0)
-                                    + (1 if use_occ else 0)]
-                self._note_program_locked("decide_sd", dec_s_sd, bs, fl_s)
-                self._note_program_locked("decide_sd", dec_g_sd, bg, fl_g)
-                with obs.annotate("sentinel_tpu.decide_split"):
-                    state, v1, sd_sk1 = dec_s_sd(
-                        self._ruleset, self._state, sd_sketch, bs, times,
-                        sys_scalars, **fl_s)
-                    state, v2, sd_sk2 = dec_g_sd(
-                        self._ruleset, state, sd_sk1, bg, times,
-                        sys_scalars, **fl_g)
-                self.tiering.set_sketch_locked(sd_sk2)
-            else:
-                self._note_program_locked("decide", dec_s, bs, fl_s)
-                self._note_program_locked("decide", dec_g, bg, fl_g)
-                with obs.annotate("sentinel_tpu.decide_split"):
-                    state, v1 = dec_s(self._ruleset, self._state, bs,
-                                      times, sys_scalars, **fl_s)
-                    state, v2 = dec_g(self._ruleset, state, bg, times,
-                                      sys_scalars, **fl_g)
-            self._state = state
-            brk = None
-            if self._breaker_observers:
-                self._breaker_seq += 1
-                brk = (self._breaker_seq, self._deg.rules,
-                       self._breaker_snapshot_locked())
+                self.tiering.set_sketch_locked(sketch)
+            brk = self._breaker_ticket_locked()
         start_host_copy((v1.allow, v1.reason, v1.wait_ms,
                          v2.allow, v2.reason, v2.wait_ms)
                         + ((brk[2],) if brk else ()))
@@ -3217,285 +3081,11 @@ class Sentinel:
             allow[idx_g] = np.asarray(v2.allow)[:n_g]
             reason[idx_g] = np.asarray(v2.reason)[:n_g]
             wait[idx_g] = np.asarray(v2.wait_ms)[:n_g]
-            # both halves materialized → staged slots consumed; reuse ok
-            while staged:
-                ring, slot = staged.pop()
-                ring.release(slot)
-            if obs_on:
-                t_end = obs.spans.now_ns()
-                obs.hist_dispatch.record(t_end - t_disp)
-                if tr:
-                    obs.spans.record(tr, "split.device", t_disp, t_end,
-                                     n=n)
-                if any_prio:
-                    granted = int(np.count_nonzero(
-                        allow[idx_g] & (wait[idx_g] > 0) & prio_g))
-                    if granted:
-                        obs.counters.add(obs_keys.OCCUPY_GRANTED, granted)
-                ovf = 0
-                if v1.sf_overflow is not None:
-                    ovf += int(np.asarray(v1.sf_overflow))
-                if v2.sf_overflow is not None:
-                    ovf += int(np.asarray(v2.sf_overflow))
-                if ovf:
-                    obs.counters.add(obs_keys.SORTFREE_OVERFLOW, ovf)
-            if brk is not None:
-                self._diff_and_fire_breakers(
-                    brk[0], brk[1], np.asarray(brk[2][:-1]).tolist())
+            self._settle_decide(
+                staged, (v1, v2),
+                (allow[idx_g], wait[idx_g], prio_g) if any_prio else None,
+                brk, obs_on, tr, "split.device", t_disp, n)
             return Verdicts(allow=allow, reason=reason, wait_ms=wait)
-
-        return self._pending_verdicts(_read)
-
-    def decide_and_exit_raw_nowait(
-            self, rows, origin_ids, origin_rows, context_ids, chain_rows,
-            acquire, is_in, prioritized, *, exit_rows,
-            exit_origin_rows=None, exit_chain_rows=None, exit_acquire=None,
-            exit_rt_ms=None, exit_error=None, exit_is_in=None,
-            exit_valid=None, valid=None, at_ms: Optional[int] = None,
-            trace_id: int = 0) -> "PendingVerdicts":
-        """Fused decide+exit dispatch: ONE device program runs this step's
-        entry decisions and records the previous step's completions
-        (engine/pipeline.py ``decide_and_record_exits`` — exits land
-        after decides, bit-identical to the decide-then-exit call pair).
-        The allow-then-exit serving loop collapses its two dispatches per
-        step into one.
-
-        Scope: the fused program covers the raw decide/exit columns only.
-        Call sites needing param-flow pairs, cluster token delegation,
-        host gates, per-event split routing, or exit-side thread-pair
-        accounting keep the two-call form (``entry_batch_nowait`` +
-        ``exit_batch``) — those tiers do host work between the halves
-        that a single program cannot express. Exit columns default to the
-        trivial padding (no origins, acquire=1, rt=0, no errors) so the
-        common "report last step's completions" call stays short."""
-        n = rows.shape[0]
-        n_x = exit_rows.shape[0]
-        obs = self.obs
-        obs_on = obs.enabled
-        tr = trace_id if trace_id else (obs.spans.maybe_trace()
-                                        if obs_on else 0)
-        t_d0 = obs.spans.now_ns() if obs_on else 0
-        pad_a = self.spec.alt_rows
-        vfull = np.ones(n, np.bool_)
-        if valid is not None:
-            vsrc = np.asarray(valid, bool)
-            m = min(n, vsrc.shape[0])
-            vfull[:] = False
-            vfull[:m] = vsrc[:m]
-        acq_np = np.asarray(acquire)
-        oid_np = np.asarray(origin_ids)
-        acq_v = acq_np if valid is None else acq_np[vfull]
-        acq_uniform = (acq_v.size > 0
-                       and int(acq_v.min()) == int(acq_v.max()) >= 1)
-        oid_v = oid_np if valid is None else oid_np[vfull]
-        no_origin_ids = int(np.max(oid_v, initial=0)) == 0
-        key_fits = (self._ruleset.flow_table.active.shape[0]
-                    * (pad_a + 1)) < 2 ** 31
-        prio_np = np.asarray(prioritized)
-        any_prio = bool(prio_np.any())
-        now = self.clock.now_ms() if at_ms is None else at_ms
-
-        # record_alt is shared by both fused halves: the no-alt scatter
-        # elision is legal only when NEITHER side carries real alt rows
-        # (defaulted exit columns are all padding)
-        empty = np.empty(0, np.int32)
-        no_alt = (self._batch_has_no_alt(origin_rows, chain_rows)
-                  and self._batch_has_no_alt(
-                      exit_origin_rows if exit_origin_rows is not None
-                      else empty,
-                      exit_chain_rows if exit_chain_rows is not None
-                      else empty))
-
-        staged: list = []
-        batch = self._build_entry_batch(
-            rows, origin_ids, origin_rows, context_ids, chain_rows,
-            acquire, is_in, prioritized, vfull, None, None, None, None,
-            None, staged=staged)
-        b_x = self._pad(n_x)
-        xbatch = ExitBatch(
-            rows=_pad_to(exit_rows, b_x, self.spec.rows, np.int32),
-            origin_rows=(_pad_to(exit_origin_rows, b_x, pad_a, np.int32)
-                         if exit_origin_rows is not None
-                         else np.full(b_x, pad_a, np.int32)),
-            chain_rows=(_pad_to(exit_chain_rows, b_x, pad_a, np.int32)
-                        if exit_chain_rows is not None
-                        else np.full(b_x, pad_a, np.int32)),
-            acquire=(_pad_to(exit_acquire, b_x, 0, np.int32)
-                     if exit_acquire is not None
-                     else _pad_to(np.ones(n_x, np.int32), b_x, 0, np.int32)),
-            rt_ms=(_pad_to(exit_rt_ms, b_x, 0, np.int32)
-                   if exit_rt_ms is not None else np.zeros(b_x, np.int32)),
-            error=(_pad_to(exit_error, b_x, False, np.bool_)
-                   if exit_error is not None else np.zeros(b_x, np.bool_)),
-            is_in=(_pad_to(exit_is_in, b_x, False, np.bool_)
-                   if exit_is_in is not None
-                   else _pad_to(np.ones(n_x, np.bool_), b_x, False,
-                                np.bool_)),
-            valid=(_pad_to(exit_valid, b_x, False, np.bool_)
-                   if exit_valid is not None
-                   else _pad_to(np.ones(n_x, np.bool_), b_x, False,
-                                np.bool_)),
-        )
-        xbatch = self._place_batch(xbatch, n_x)
-        times = self._time_scalars(now)
-        load1, cpu = self._cpu.sample()
-        sys_scalars = jnp.asarray(np.array([load1, cpu], np.float32))
-        lock_wait = obs.phase("engine.lock_wait", n=n, trace=tr).start()
-        with self._lock:
-            lock_wait.stop()
-            now, times = self._restamp_if_stale_locked(at_ms, now, times)
-            self._drain_evictions_locked()
-            # hot-set sketch observe (tiering): see decide_raw_nowait.
-            # Single-dispatch mode (round 16) folds the observe — and any
-            # due telemetry/tiering tick epilogue — into the one fused
-            # serving program dispatched below.
-            sd_sketch = (self.tiering.sketch_for_fuse_locked()
-                         if self._single_dispatch else None)
-            observed = False
-            if sd_sketch is None:
-                observed = self.tiering.observe_locked(batch.rows,
-                                                       batch.valid)
-            self._seen_idx = max(self._seen_idx,
-                                 self.spec.second.index_of(now))
-            if any_prio:
-                self._occupy_live_until_ms = now + (
-                    (self.spec.second.buckets + 1)
-                    * self.spec.second.win_ms)
-            use_occ = any_prio or now < self._occupy_live_until_ms
-            # variant order mirrors the decide set: (occ,alt) =
-            # (F,T),(T,T),(F,F),(T,F)
-            vidx = (2 if no_alt else 0) + (1 if use_occ else 0)
-            flags = {"skip_auth": self._skip_auth,
-                     "skip_sys": self._skip_sys,
-                     "skip_threads": self._skip_threads}
-            if self._sortfree:
-                flags["sortfree"] = True   # see decide_raw_nowait
-            if no_alt and no_origin_ids and not any_prio and acq_uniform:
-                flags["scalar_flow"] = True
-                flags["scalar_has_rl"] = self._scalar_has_rl
-            elif acq_uniform and key_fits:
-                flags["fast_flow"] = True
-                flags["scalar_has_rl"] = self._scalar_has_rl
-            tel_prep = None
-            tier_due = False
-            tel_outs = est = None
-            if sd_sketch is not None:
-                # consult both carry cadences under the SAME lock hold
-                # that dispatches — a claim is only made when the
-                # epilogue program below will actually run it
-                tel_prep = self.telemetry.carry_due_locked(now)
-                tier_due = self.tiering.carry_due_locked(now)
-                sd = self._sd_steps_locked()
-                if tel_prep is not None or tier_due:
-                    fused_sd = sd["fused_epi"][vidx]
-                    ring = self.telemetry.ring_for_fuse_locked()
-                    eflags = ((_EPI_TELEMETRY if tel_prep is not None
-                               else 0) | (_EPI_TIER if tier_due else 0))
-                    if tel_prep is not None:
-                        _, _, append, idx_s, sec_idx_m = tel_prep
-                    else:
-                        append = idx_s = sec_idx_m = 0
-                    epi = jnp.asarray(np.array(
-                        [eflags, idx_s, sec_idx_m, append], np.int32))
-                    self._note_program_locked(
-                        "fused_sd_epi", fused_sd, batch, flags, xbatch)
-                    with obs.annotate("sentinel_tpu.fused"):
-                        (state, verdicts, new_sketch, new_ring, tel_outs,
-                         est) = fused_sd(
-                            self._ruleset, self._state, sd_sketch, ring,
-                            epi, batch, xbatch, times, sys_scalars,
-                            **flags)
-                    self.tiering.set_sketch_locked(new_sketch)
-                    if tel_prep is not None:
-                        self.telemetry.queue_carry(tel_prep, tel_outs,
-                                                   new_ring)
-                    else:
-                        self.telemetry.set_ring_locked(new_ring)
-                        tel_outs = None
-                    if tier_due:
-                        self.tiering.queue_estimates(est)
-                    else:
-                        est = None
-                else:
-                    fused_sd = sd["fused"][vidx]
-                    self._note_program_locked(
-                        "fused_sd", fused_sd, batch, flags, xbatch)
-                    with obs.annotate("sentinel_tpu.fused"):
-                        state, verdicts, new_sketch = fused_sd(
-                            self._ruleset, self._state, sd_sketch, batch,
-                            xbatch, times, sys_scalars, **flags)
-                    self.tiering.set_sketch_locked(new_sketch)
-            else:
-                fused = self._jit_fused_steps[vidx]
-                self._note_program_locked("fused", fused, batch, flags,
-                                          xbatch)
-                with obs.annotate("sentinel_tpu.fused"):
-                    state, verdicts = fused(
-                        self._ruleset, self._state, batch, xbatch, times,
-                        sys_scalars, **flags)
-            self._state = state
-            brk = None
-            if self._breaker_observers:
-                self._breaker_seq += 1
-                brk = (self._breaker_seq, self._deg.rules,
-                       self._breaker_snapshot_locked())
-        start_host_copy((verdicts.allow, verdicts.reason, verdicts.wait_ms)
-                        + (tuple(tel_outs) if tel_outs is not None else ())
-                        + ((est,) if est is not None else ())
-                        + ((brk[2],) if brk else ()))
-        t_disp = 0
-        if obs_on:
-            if "scalar_flow" in flags:
-                route = obs_keys.ROUTE_SCALAR
-            elif "fast_flow" in flags:
-                route = (obs_keys.ROUTE_FAST_OCCUPY if use_occ
-                         else obs_keys.ROUTE_FAST)
-            else:
-                route = obs_keys.ROUTE_GENERAL
-            obs.counters.add(obs_keys.ROUTE_FUSED)
-            obs.counters.add(obs_keys.PIPE_DISPATCH,
-                             2 if observed else 1)
-            if sd_sketch is not None:
-                obs.counters.add(obs_keys.ROUTE_SINGLE_DISPATCH)
-            if "sortfree" in flags:
-                obs.counters.add(obs_keys.ROUTE_SORTFREE)
-            if self.mesh is not None:
-                obs.counters.add(obs_keys.ROUTE_MESHED)
-            t_disp = obs.spans.now_ns()
-            if tr:
-                obs.spans.record(tr, "fused.dispatch", t_d0, t_disp, n=n,
-                                 note=f"{route.split('.', 1)[1]} "
-                                      f"exits={n_x}")
-        prio_np_full = prio_np if any_prio else None
-
-        def _read() -> Verdicts:
-            out = Verdicts(allow=np.asarray(verdicts.allow)[:n],
-                           reason=np.asarray(verdicts.reason)[:n],
-                           wait_ms=np.asarray(verdicts.wait_ms)[:n])
-            # settlement proves the staged operands were consumed
-            while staged:
-                ring, slot = staged.pop()
-                ring.release(slot)
-            if obs_on:
-                t_end = obs.spans.now_ns()
-                obs.hist_dispatch.record(t_end - t_disp)
-                if tr:
-                    obs.spans.record(tr, "fused.device", t_disp, t_end,
-                                     n=n)
-                if verdicts.sf_overflow is not None:
-                    ovf = int(np.asarray(verdicts.sf_overflow))
-                    if ovf:
-                        obs.counters.add(obs_keys.SORTFREE_OVERFLOW, ovf)
-                if prio_np_full is not None:
-                    granted = int(np.count_nonzero(
-                        out.allow & (out.wait_ms > 0)
-                        & prio_np_full[:n]))
-                    if granted:
-                        obs.counters.add(obs_keys.OCCUPY_GRANTED, granted)
-            if brk is not None:
-                self._diff_and_fire_breakers(
-                    brk[0], brk[1], np.asarray(brk[2][:-1]).tolist())
-            return out
 
         return self._pending_verdicts(_read)
 
@@ -3557,11 +3147,7 @@ class Sentinel:
                 # exit feeds resolve probes / trip breakers: with observers
                 # registered, this call pays one small state read so the
                 # observer fires within the exit call that caused the arc
-                brk = None
-                if self._breaker_observers:
-                    self._breaker_seq += 1
-                    brk = (self._breaker_seq, self._deg.rules,
-                           self._breaker_snapshot_locked())
+                brk = self._breaker_ticket_locked()
             # unpin only AFTER the device-side decrement is enqueued (entry-side
             # pin discipline: resolve→pin, decide, exit-decrement→unpin)
             if unpin is not None:

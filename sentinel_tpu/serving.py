@@ -142,15 +142,6 @@ class DispatchPipeline:
             lambda: self._s.decide_raw_nowait(*args, **kwargs), n,
             trace_id=kwargs.get("trace_id", 0))
 
-    def submit_fused(self, *args, **kwargs) -> PipelinedVerdicts:
-        """Dispatch through :meth:`Sentinel.decide_and_exit_raw_nowait`:
-        this step's decides and the previous step's completions in ONE
-        device program (see its docstring for the applicability scope)."""
-        n = args[0].shape[0] if args else 0
-        return self._submit(
-            lambda: self._s.decide_and_exit_raw_nowait(*args, **kwargs), n,
-            trace_id=kwargs.get("trace_id", 0))
-
     def _submit(self, dispatch, n: int,
                 trace_id: int = 0) -> PipelinedVerdicts:
         obs = self._s.obs
@@ -225,27 +216,27 @@ class DispatchPipeline:
 
 
 class CadenceScheduler:
-    """One thread for both tick cadences (round 16 single-dispatch).
+    """The one clock of both ticks: one thread in place of the two
+    per-service ticker daemons (``telemetry.start`` + ``tiering.start``).
 
-    Replaces the two per-service ticker daemons (``telemetry.start`` +
-    ``tiering.start``). Arming the services' carry cadences lets steady
-    serving traffic run the telemetry tick and the sketch decay/estimate
-    INSIDE the fused serving dispatch (the runtime's ``lax.cond``
-    epilogue) — so under load the ticks cost zero extra dispatches. The
-    scheduler thread then only (a) drains both services' queued
-    readbacks off the engine lock and (b) self-dispatches a standalone
-    ``tick()`` for a service whose armed cadence has gone stale
-    (:data:`IDLE_FACTOR` × its interval with no batch carrying the
-    epilogue — the zero-traffic fallback), so an idle engine still
-    refreshes its hot set and decays its sketch.
+    Every ``poll()`` ticks a service once ``now − last_tick_ms()`` has
+    reached :data:`IDLE_FACTOR` × its interval, then drains both
+    services' queued readbacks off the engine lock; the thread polls
+    every ``max(0.02, min(intervals) / 2000)`` s. So the EFFECTIVE tick
+    period is 1.5 × the configured interval plus up to one poll: at the
+    defaults ≈ 0.35 s for the tiering tick (1.5 × 200 ms + ≤ 100 ms) and
+    ≈ 1.52 s for the telemetry tick. Choosing another factor is a
+    measurement question — it moves the tick's share of the device (31 %
+    of busy time on one chip, 79 % on the four-chip mesh: ledger PR 30
+    ``breakdown``) — so it is a constant here, not a knob.
 
     ``poll()`` is the thread body and is callable directly in tests;
     start/stop are idempotent and ``stop`` is registered with
     ``Sentinel.register_shutdown``.
     """
 
-    #: a carry slot is considered missed — and the scheduler
-    #: self-dispatches — after this many armed intervals without a tick
+    #: a service is ticked once this many of its intervals have passed
+    #: since its last tick
     IDLE_FACTOR = 1.5
 
     def __init__(self, sentinel: Sentinel,
@@ -257,8 +248,8 @@ class CadenceScheduler:
             tiering_interval_sec = tier_tick_ms() / 1000.0
         self._tel_ms = max(1, int(telemetry_interval_sec * 1000))
         self._tier_ms = max(1, int(tiering_interval_sec * 1000))
-        # drain at twice the fastest cadence so carried readbacks land
-        # with at most half an interval of extra latency
+        # drain at twice the fastest cadence so readbacks land with at
+        # most half an interval of extra latency
         self._poll_s = max(0.02, min(self._tel_ms, self._tier_ms) / 2000.0)
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -270,8 +261,8 @@ class CadenceScheduler:
             reg(self)
 
     def poll(self) -> int:
-        """One scheduler pass: self-dispatch any stale service's tick,
-        then drain both; → entries drained."""
+        """One scheduler pass: tick any service that is due, then drain
+        both; → entries drained."""
         sn = self._s
         n = 0
         tel = sn.telemetry
@@ -287,9 +278,8 @@ class CadenceScheduler:
                     >= self._tier_ms * self.IDLE_FACTOR):
                 tier.tick()
             n += tier.drain()
-        # round 17: the overload controller rides the same daemon. Its
-        # tick is never device-carried (pure host observe+decide), so
-        # the cadence check is exact, not the stale-carry fallback.
+        # round 17: the overload controller rides the same daemon at its
+        # own exact interval (pure host observe+decide, no device tick)
         ctl = getattr(sn, "control", None)
         if ctl is not None and ctl.enabled:
             now = sn.clock.now_ms()
@@ -299,11 +289,12 @@ class CadenceScheduler:
         return n
 
     def start(self) -> None:
-        """Arm both carry cadences and start the daemon (idempotent)."""
+        """Count both tick intervals from now and start the daemon
+        (idempotent)."""
         if self._thread is not None:
             return
-        self._s.telemetry.arm_carry(self._tel_ms)
-        self._s.tiering.arm_carry(self._tier_ms)
+        self._s.telemetry.stamp_last_tick()
+        self._s.tiering.stamp_last_tick()
         self._stop.clear()
 
         def loop():
@@ -319,10 +310,8 @@ class CadenceScheduler:
         self._thread.start()
 
     def stop(self) -> None:
-        """Disarm the carries and join the daemon (idempotent; the
-        services' own registered stops handle their final drains)."""
-        self._s.telemetry.disarm_carry()
-        self._s.tiering.disarm_carry()
+        """Join the daemon (idempotent; the services' own registered
+        stops handle their final drains)."""
         self._stop.set()
         if self._thread is not None:
             self._thread.join(timeout=2)

@@ -180,10 +180,6 @@ class TierManager:
         self._sketch_update = None
         self._ticks = 0
         self._last_est: Optional[np.ndarray] = None
-        # round 16 — epilogue carry cadence: when armed (CadenceScheduler,
-        # serving.py), serving traffic runs the decay+estimate inside the
-        # fused dispatch and the ticker only self-dispatches on idle gaps
-        self._carry_ms: Optional[int] = None
         self._last_tick_ms = int(sentinel.clock.now_ms())
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -298,7 +294,7 @@ class TierManager:
             self._sketch, rows_dev, valid_dev)
         return True
 
-    # ---- round 16: single-dispatch fusion surface ---------------------
+    # ---- sketch-fused decide surface ----------------------------------
 
     def sketch_for_fuse_locked(self):
         """Engine lock held: the sketch table to thread through a
@@ -314,43 +310,14 @@ class TierManager:
         a sketch-fused dispatch."""
         self._sketch = sketch
 
-    def arm_carry(self, interval_ms: int) -> None:
-        """Let serving traffic carry the decay+estimate tick inside the
-        fused dispatch at this cadence (CadenceScheduler, serving.py)."""
-        with self._lock:
-            self._carry_ms = max(1, int(interval_ms))
-            self._last_tick_ms = int(self._sentinel.clock.now_ms())
-
-    def disarm_carry(self) -> None:
-        with self._lock:
-            self._carry_ms = None
-
     def last_tick_ms(self) -> int:
         with self._lock:
             return self._last_tick_ms
 
-    def carry_due_locked(self, now_ms: int) -> bool:
-        """Engine lock held: claim one carried tick if the cadence is
-        armed and due. The claim updates ``_last_tick_ms`` immediately —
-        the caller dispatches the epilogue in the same lock hold, so a
-        concurrent self-dispatch fallback won't double-tick."""
-        if (not self.enabled or self._closed or self._sketch is None):
-            return False
+    def stamp_last_tick(self) -> None:
+        """Count the tick interval from now (``CadenceScheduler.start``)."""
         with self._lock:
-            if (self._carry_ms is None
-                    or now_ms - self._last_tick_ms < self._carry_ms):
-                return False
-            self._last_tick_ms = int(now_ms)
-            return True
-
-    def queue_estimates(self, est) -> None:
-        """Queue an epilogue-carried estimate readback (engine lock
-        held; the host copy was started by the runtime). Counted as a
-        tick — :meth:`drain` lands it exactly like a self-dispatched
-        one."""
-        with self._lock:
-            self._est_q.append(est)
-            self._ticks += 1
+            self._last_tick_ms = int(self._sentinel.clock.now_ms())
 
     def pre_invalidate_locked(self, evicted: List[int], now_ms: int) -> None:
         """Demote snapshot: gather the evicted rows' state BEFORE the
@@ -804,7 +771,7 @@ class TierManager:
             "cold": len(self.cold),
             "cold_dropped": self.cold.dropped,
             "pending_land": pend,
-            "ticks": self._ticks,  # graftlint: disable=LOCK002 -- diagnostic snapshot; a torn counter read is harmless
+            "ticks": self._ticks,
             "hot_hit": c.get(obs_keys.TIER_HOT_HIT),
             "cold_miss": c.get(obs_keys.TIER_COLD_MISS),
             "promoted": c.get(obs_keys.TIER_PROMOTED),

@@ -170,13 +170,8 @@ def jit_update(impl: str = DEFAULT_IMPL):
 
 def tick_read(counts: jnp.ndarray, n_rows: int
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One ticker read as pure math: decay, then estimate every row.
-
-    Shared verbatim by the self-dispatched ticker (:func:`jit_tick_read`)
-    and the round-16 single-dispatch epilogue (the ``lax.cond`` branch
-    runtime._build_sd_steps traces into the fused serving program) — one
-    definition is what makes the carried estimates bit-identical to the
-    standalone tick's."""
+    """One ticker read as pure math: decay, then estimate every row
+    (jitted by :func:`jit_tick_read`)."""
     counts = decay_sketch(counts)
     return counts, estimate_all(counts, n_rows)
 

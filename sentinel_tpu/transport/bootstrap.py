@@ -88,13 +88,11 @@ def start_transport(sentinel, *, host: str = "0.0.0.0", port: int = 8719,
             obs.flight.configure(sentinel.cfg.metric_dir(),
                                  sentinel.cfg.app_name)
         # hot-resource telemetry (obs/telemetry.py): top-K second lines
-        # ride the same rotation as <app>-metric. Since round 16 the
-        # telemetry + tiering cadences share ONE CadenceScheduler thread
-        # (serving.py): it arms both services' epilogue carries so
-        # steady serving traffic runs the ticks inside the fused
-        # dispatch, and only self-dispatches on idle gaps. Its drains
-        # still overlap the dispatch pipeline rather than serializing
-        # behind metric_timer.tick(). Stops via register_shutdown.
+        # ride the same rotation as <app>-metric. The telemetry +
+        # tiering ticks share ONE CadenceScheduler thread (serving.py),
+        # the clock of both. Its drains overlap the dispatch pipeline
+        # rather than serializing behind metric_timer.tick(). Stops via
+        # register_shutdown.
         telemetry = getattr(sentinel, "telemetry", None)
         if telemetry is not None and telemetry.enabled:
             telemetry.configure(sentinel.cfg.metric_dir(),

@@ -127,15 +127,14 @@ KNOBS: Tuple[KnobSpec, ...] = (
     KnobSpec("SENTINEL_SORTFREE", "bool", True, None, None, SCOPE_TRACE,
              (True, False),
              "sort-free claim-cascade general path (vs sorted reference)"),
-    # runtime.single_dispatch_enabled() — round 16: fold the tiering
-    # sketch observe (and the lax.cond telemetry/decay epilogue on the
-    # fused path) into the decide programs so a steady-state batch costs
-    # ONE device dispatch; =0 is the operator escape hatch restoring the
-    # pre-r16 two-dispatch composition byte-for-byte (compile-cache keys
-    # included)
+    # runtime.single_dispatch_enabled() — fold the tiering sketch
+    # observe into the decide programs so a decide batch costs ONE
+    # device dispatch; =0 is the operator escape hatch restoring the
+    # pre-r16 decide + observe composition byte-for-byte (compile-cache
+    # keys included)
     KnobSpec("SENTINEL_SINGLE_DISPATCH", "bool", True, None, None,
              SCOPE_TRACE, (True, False),
-             "fuse sketch observe + tick epilogue into the decide dispatch"),
+             "fuse the tiering sketch observe into the decide dispatch"),
     # ops/sortfree.py table_bits() — auto-sized from the batch when
     # unset (default None); an explicit override clamps to [1, 18] (the
     # sub-6 range exists for the collision-forcing parity tests)

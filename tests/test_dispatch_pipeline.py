@@ -1,7 +1,7 @@
-"""Depth-k dispatch pipelining (sentinel_tpu/serving.py) and the fused
-decide+exit program: bit-parity pins against the sequential two-call
-serving loop, strict in-order settle under out-of-order ``result()``
-calls, the leaked-handle GC guard, and host-staging reuse parity.
+"""Depth-k dispatch pipelining (sentinel_tpu/serving.py): bit-parity
+pins against the sequential serving loop, strict in-order settle under
+out-of-order ``result()`` calls, the leaked-handle GC guard, and
+host-staging reuse parity.
 
 All quick-tier, CPU: the pipeline changes HOST scheduling only — the
 device-visible dispatch order is pinned unchanged, so every verdict and
@@ -118,104 +118,6 @@ def test_pipelined_origin_batches_match(clk):
         assert np.array_equal(v1.allow, v2.allow)
         assert np.array_equal(v1.wait_ms, v2.wait_ms)
     _assert_state_equal(seq_s._state, pipe_s._state)
-
-
-# ---------------------------------------------------------------------------
-# fused decide+exit == decide-then-exit
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("with_origins", [False, True])
-def test_fused_matches_decide_then_exit(clk, with_origins):
-    """One fused program per step vs the two-dispatch form: verdicts AND
-    every state leaf bit-equal across interacting steps (the fused exits
-    land after the decides, exactly like the separate exit dispatch)."""
-    clk2 = ManualClock(start_ms=T0)
-    two_s = make(clk)
-    fus_s = make(clk2)
-    two_s.load_flow_rules(RULES)
-    fus_s.load_flow_rules(RULES)
-    rng = np.random.default_rng(9)
-    n = 16
-    pad_a = two_s.spec.alt_rows
-
-    def cols(sph):
-        rows = np.asarray([sph.resources.get_or_create(f"r{int(i)}")
-                           for i in rng.integers(0, 3, n)], np.int32)
-        if with_origins:
-            oid = sph.origins.pin("app-a")
-            origin_ids = np.full(n, oid, np.int32)
-            origin_rows = np.asarray(
-                [sph._alt_row(int(r), 0, oid) for r in rows], np.int32)
-        else:
-            origin_ids = np.zeros(n, np.int32)
-            origin_rows = np.full(n, pad_a, np.int32)
-        return rows, origin_ids, origin_rows
-
-    ones = np.ones(n, np.int32)
-    is_in = np.ones(n, np.bool_)
-    no_prio = np.zeros(n, np.bool_)
-    ctx0 = np.zeros(n, np.int32)
-    crow = np.full(n, pad_a, np.int32)
-    prev = None     # (rows, origin_rows, rt, err) of the previous step
-    for step in range(6):
-        rng_state = rng.bit_generator.state
-        r1, oid1, orow1 = cols(two_s)
-        rng.bit_generator.state = rng_state
-        r2, oid2, orow2 = cols(fus_s)
-        assert np.array_equal(r1, r2)
-        rt = rng.integers(1, 50, n).astype(np.int32)
-        err = (rng.random(n) < 0.3)
-
-        # two-call form: exits (previous completions) BEFORE this step's
-        # decide would reorder state vs the fused program, so mirror the
-        # fused ordering: decide first, then record the previous exits —
-        # exactly what decide_and_record_exits fuses
-        h = two_s.decide_raw_nowait(r1, oid1, orow1, ctx0, crow, ones,
-                                    is_in, no_prio)
-        if prev is not None:
-            two_s.exit_batch(rows=prev[0], origin_rows=prev[1],
-                             chain_rows=crow, acquire=ones,
-                             rt_ms=prev[2], error=prev[3], is_in=is_in)
-        v1 = h.result()
-
-        if prev is not None:
-            h2 = fus_s.decide_and_exit_raw_nowait(
-                r2, oid2, orow2, ctx0, crow, ones, is_in, no_prio,
-                exit_rows=prev[0], exit_origin_rows=prev[1],
-                exit_chain_rows=crow, exit_acquire=ones,
-                exit_rt_ms=prev[2], exit_error=prev[3], exit_is_in=is_in)
-        else:
-            h2 = fus_s.decide_raw_nowait(r2, oid2, orow2, ctx0, crow,
-                                         ones, is_in, no_prio)
-        v2 = h2.result()
-
-        assert np.array_equal(v1.allow, v2.allow), f"allow @ step {step}"
-        assert np.array_equal(v1.wait_ms, v2.wait_ms)
-        assert np.array_equal(v1.reason, v2.reason)
-        prev = (r1, orow1, rt, err)
-        clk.advance_ms(130)
-        clk2.advance_ms(130)
-    # flush the trailing exits on both so the final states align
-    two_s.exit_batch(rows=prev[0], origin_rows=prev[1], chain_rows=crow,
-                     acquire=ones, rt_ms=prev[2], error=prev[3],
-                     is_in=is_in)
-    fus_s.exit_batch(rows=prev[0], origin_rows=prev[1], chain_rows=crow,
-                     acquire=ones, rt_ms=prev[2], error=prev[3],
-                     is_in=is_in)
-    _assert_state_equal(two_s._state, fus_s._state)
-
-
-def test_fused_counts_route_counter(clk):
-    sph = make(clk)
-    rows = np.asarray([sph.resources.get_or_create("x")], np.int32)
-    pad_a = sph.spec.alt_rows
-    one = np.ones(1, np.int32)
-    h = sph.decide_and_exit_raw_nowait(
-        rows, np.zeros(1, np.int32), np.full(1, pad_a, np.int32),
-        np.zeros(1, np.int32), np.full(1, pad_a, np.int32), one,
-        np.ones(1, np.bool_), np.zeros(1, np.bool_), exit_rows=rows)
-    assert bool(h.result().allow[0])
-    assert sph.obs.counters.get(obs_keys.ROUTE_FUSED) == 1
 
 
 # ---------------------------------------------------------------------------

@@ -46,9 +46,7 @@ def _count_decides(sph):
     orig_sd = sph._sd_steps_locked
 
     def sd_wrapped():
-        steps = orig_sd()
-        return dict(steps,
-                    decide=tuple(wrap(f) for f in steps["decide"]))
+        return tuple(wrap(f) for f in orig_sd())
 
     sph._sd_steps_locked = sd_wrapped
     return counter

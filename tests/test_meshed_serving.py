@@ -2,7 +2,7 @@
 
 Bit-parity of the meshed (8-virtual-device) engine against the
 single-device engine through the full serving stack — DispatchPipeline,
-the fused decide+exit tier, split/prio/occupy routing, occupy-booking
+decide-then-exit steps, split/prio/occupy routing, occupy-booking
 carry across rule reloads, and the AdaptiveBatcher fan-out — plus the
 layout helpers (parallel/local_shard.py batch placement + topology) and
 the mesh-attribution counters. tests/test_sharded_local.py pins the
@@ -190,20 +190,27 @@ def test_pipeline_parity_and_mesh_counters():
     sh.close()
 
 
-def test_fused_decide_exit_parity():
+def test_decide_then_exit_parity():
+    """Steps WITH exits (RT, errors, thread gauges): decide, then the
+    completions, on both engines — verdicts and every state leaf stay
+    bit-identical, and each meshed decide is attributed."""
     ref, sh = _pair()
     cols = _raw_columns(ref, sh, n=2048, seed=5)
     for i in range(4):
-        hs = [s.decide_and_exit_raw_nowait(
+        hs = [s.decide_raw_nowait(
             cols["rows"], cols["oids"], cols["orow"], cols["ctx0"],
             cols["chain"], cols["ones"], cols["is_in"], cols["prio"],
-            exit_rows=cols["rows"], exit_origin_rows=cols["orow"],
-            exit_chain_rows=cols["chain"], exit_acquire=cols["ones"],
-            exit_rt_ms=cols["rt"], exit_error=cols["err"],
-            exit_is_in=cols["is_in"], at_ms=T0 + i * 250)
-            for s in (ref, sh)]
+            at_ms=T0 + i * 250) for s in (ref, sh)]
+        for s in (ref, sh):
+            s.exit_batch(
+                rows=cols["rows"], origin_rows=cols["orow"],
+                chain_rows=cols["chain"], acquire=cols["ones"],
+                rt_ms=cols["rt"], error=cols["err"], is_in=cols["is_in"],
+                at_ms=T0 + i * 250)
         _assert_verdicts_equal(hs[0].result(), hs[1].result(),
-                               ctx=f"fused step {i}")
+                               ctx=f"decide+exit step {i}")
+    for a, b in zip(jax.tree.leaves(ref._state), jax.tree.leaves(sh._state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert sh.obs.counters.get(obs_keys.ROUTE_MESHED) == 4
     ref.close()
     sh.close()

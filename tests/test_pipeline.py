@@ -404,66 +404,6 @@ def test_rate_limiter_pacing_is_per_rule_across_origins(clk):
     assert sorted(np.asarray(v.wait_ms).tolist()) == [0, 100, 200, 300]
 
 
-# ------------------------------------------------- fused entry+exit step
-
-def test_fused_entry_exit_step_matches_two_dispatch(clk):
-    """decide_and_record_exits (one dispatch) is bit-identical to
-    decide_entries followed by record_exits (two dispatches) — state and
-    verdicts — including the breaker feed from the exit half."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-
-    from sentinel_tpu.engine.pipeline import (
-        EntryBatch, ExitBatch, decide_and_record_exits, decide_entries,
-        record_exits,
-    )
-
-    sph = make_sentinel(clk)
-    sph.load_flow_rules([stpu.FlowRule(resource="f", count=4.0)])
-    sph.load_degrade_rules([stpu.DegradeRule(
-        resource="f", grade=stpu.GRADE_EXCEPTION_RATIO, count=0.4,
-        time_window=10, min_request_amount=2)])
-    spec, rules, state = sph.spec, sph._ruleset, sph._state
-    row = sph.resources.get_or_create("f")
-    B = 8
-    rng = np.random.default_rng(3)
-    eb = EntryBatch(
-        rows=jnp.full(B, row, jnp.int32),
-        origin_ids=jnp.zeros(B, jnp.int32),
-        origin_rows=jnp.full(B, spec.alt_rows, jnp.int32),
-        context_ids=jnp.zeros(B, jnp.int32),
-        chain_rows=jnp.full(B, spec.alt_rows, jnp.int32),
-        acquire=jnp.ones(B, jnp.int32), is_in=jnp.ones(B, jnp.bool_),
-        prioritized=jnp.zeros(B, jnp.bool_), valid=jnp.ones(B, jnp.bool_))
-    xb = ExitBatch(
-        rows=jnp.full(B, row, jnp.int32),
-        origin_rows=jnp.full(B, spec.alt_rows, jnp.int32),
-        chain_rows=jnp.full(B, spec.alt_rows, jnp.int32),
-        acquire=jnp.ones(B, jnp.int32),
-        rt_ms=jnp.asarray(rng.integers(1, 50, B).astype(np.int32)),
-        error=jnp.asarray(rng.random(B) < 0.5),
-        is_in=jnp.ones(B, jnp.bool_), valid=jnp.ones(B, jnp.bool_))
-    times = sph._time_scalars(clk.now_ms())
-    sysv = jnp.asarray(np.array([0.1, 0.1], np.float32))
-
-    two = jax.jit(functools.partial(decide_entries, spec,
-                                    enable_occupy=False))
-    ex = jax.jit(functools.partial(record_exits, spec))
-    one = jax.jit(functools.partial(decide_and_record_exits, spec))
-
-    s2, v2 = two(rules, state, eb, times, sysv)
-    s2 = ex(rules, s2, xb, times)
-    s1, v1 = one(rules, state, eb, xb, times, sysv)
-
-    assert np.array_equal(v1.allow, v2.allow)
-    assert np.array_equal(v1.reason, v2.reason)
-    assert np.array_equal(v1.wait_ms, v2.wait_ms)
-    for a, b in zip(jax.tree.leaves(s1), jax.tree.leaves(s2)):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-
-
 def test_alt_free_variant_matches_full_on_originless_batch(clk):
     """record_alt=False (the runtime's choice for batches with no
     origin/chain rows) must produce identical verdicts and main-table
@@ -545,10 +485,9 @@ def test_runtime_selects_alt_free_variant(clk):
     orig_sd = sph._sd_steps_locked
 
     def sd_wrapped():
-        steps = orig_sd()
-        d = steps["decide"]
-        return dict(steps, decide=(w(d[0], "full"), w(d[1], "full"),
-                                   w(d[2], "noalt"), w(d[3], "noalt")))
+        d = orig_sd()
+        return (w(d[0], "full"), w(d[1], "full"),
+                w(d[2], "noalt"), w(d[3], "noalt"))
 
     sph._sd_steps_locked = sd_wrapped
     with sph.entry("plain"):

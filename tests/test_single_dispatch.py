@@ -1,15 +1,13 @@
-"""Round-16 single-dispatch serving tick: the sketch observe rides the
-decide/fused program and the telemetry + tiering ticks ride a
-``lax.cond``-gated epilogue of the fused program, so a steady-state
-serving batch costs exactly ONE device dispatch.
+"""Sketch-fused decide (``SENTINEL_SINGLE_DISPATCH``) and the one clock
+of the telemetry + tiering ticks (``CadenceScheduler``).
 
 Pins: verdict AND sketch-table bit-parity between
 ``SENTINEL_SINGLE_DISPATCH`` on and off (tiered engine, mid-run rule
 reload, prioritized traffic, per-origin alt rows); tiered-vs-resident
-parity with the fused path on; the epilogue firing once per due
-cadence slot regardless of batch rate; the CadenceScheduler's
-zero-traffic self-dispatch fallback; and the disable env restoring the
-legacy two-dispatch composition verbatim.
+parity with the fused observe on; the schedule — a service is ticked by
+``CadenceScheduler.poll`` when 1.5 × its interval has passed since its
+last tick, and by nothing else; and the disable env restoring the
+legacy decide + observe composition verbatim.
 """
 
 import numpy as np
@@ -122,7 +120,7 @@ def test_parity_on_vs_off_bitwise(monkeypatch, origins):
 def test_parity_tiered_vs_resident_single_dispatch(monkeypatch):
     """tests/test_tiering.py's load-bearing property survives the fused
     observe: a 24-row tiered engine == a 512-row resident engine, bit
-    for bit, with both on the single-dispatch route. Staging stays ON
+    for bit, with both on the sketch-fused decide. Staging stays ON
     (settlement-tied slot reuse — see test_parity_on_vs_off_bitwise)."""
     monkeypatch.setenv("SENTINEL_TPU_NATIVE", "0")
     monkeypatch.setenv("SENTINEL_SINGLE_DISPATCH", "1")
@@ -137,207 +135,103 @@ def test_parity_tiered_vs_resident_single_dispatch(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# epilogue cadence
+# the schedule
 # ---------------------------------------------------------------------------
 
-def _drive_fused(s, clk, steps, advance_ms, drain=True):
-    """Steady fused serving loop (decide+exit in one call per step);
-    returns the dispatch-time ``now_ms`` list."""
-    rows_all = s.intern_resources(["a", "b", "c"])
-    pad_a = s.spec.alt_rows
-    n = 4
-    rng = np.random.default_rng(7)
-    ones = np.ones(n, np.int32)
-    is_in = np.ones(n, np.bool_)
-    no_prio = np.zeros(n, np.bool_)
-    ctx0 = np.zeros(n, np.int32)
-    crow = np.full(n, pad_a, np.int32)
-    orow = np.full(n, pad_a, np.int32)
-    oid = np.zeros(n, np.int32)
-    times = []
-    prev = None
-    for _ in range(steps):
-        rows = np.asarray(rng.choice(rows_all, size=n), np.int32)
-        times.append(int(clk.now_ms()))
-        h = s.decide_and_exit_raw_nowait(
-            rows, oid, orow, ctx0, crow, ones, is_in, no_prio,
-            exit_rows=prev if prev is not None else rows,
-            exit_valid=(np.ones(n, np.bool_) if prev is not None
-                        else np.zeros(n, np.bool_)))
-        h.result()
-        prev = rows
-        if drain:       # what the CadenceScheduler thread does
-            s.telemetry.drain()
-            s.tiering.drain()
-        clk.advance_ms(advance_ms)
-    return times
+def _serve_one(s, names):
+    """One serving step in the two-call form: decide, then the exits."""
+    rows = np.asarray(s.intern_resources(names), np.int32)
+    n = len(names)
+    s.entry_batch(names, acquire=[1] * n)
+    pad = np.full(n, s.spec.alt_rows, np.int32)
+    s.exit_batch(rows=rows, origin_rows=pad, chain_rows=pad,
+                 acquire=np.ones(n, np.int32), rt_ms=np.full(n, 3, np.int32),
+                 error=np.zeros(n, np.bool_), is_in=np.ones(n, np.bool_))
 
 
-def _expected_claims(t_start, times, interval):
-    last, n = t_start, 0
-    for t in times:
-        if t - last >= interval:
-            last, n = t, n + 1
-    return n
-
-
-def test_epilogue_once_per_due_tick(clk, monkeypatch):
-    """With both carries armed, a fused serving step runs the telemetry
-    tick and the sketch decay exactly when its cadence slot is due —
-    once per slot, independent of the batch rate — and every batch is
-    one dispatch (``pipeline.dispatches`` == batches, no standalone
-    observe/tick programs)."""
-    monkeypatch.setenv("SENTINEL_SINGLE_DISPATCH", "1")
-    s = make(clk)
+@pytest.mark.parametrize("due", [False, True], ids=["just_under", "at"])
+@pytest.mark.parametrize("service,interval_ms",
+                         [("telemetry", 1000), ("tiering", 200)])
+def test_scheduler_is_the_one_clock(clk, service, interval_ms, due):
+    """``start()`` stamps both services' last tick; from there a service
+    is ticked by the first ``poll()`` at or after 1.5 × its interval —
+    not one millisecond before — and serving traffic between polls
+    neither ticks it nor moves ``last_tick_ms()``."""
+    s = make(clk, host_fast_path=False)
     try:
-        assert s.telemetry.enabled and s.tiering.enabled
-        t_arm = int(clk.now_ms())
-        s.telemetry.arm_carry(400)
-        s.tiering.arm_carry(150)
-        base = s.obs.counters.get(obs_keys.PIPE_DISPATCH)
-        tel0 = s.telemetry.snapshot()["ticks"]
-        tier0 = s.tiering.snapshot()["ticks"]
-        times = _drive_fused(s, clk, steps=30, advance_ms=50)
-        tel_claims = _expected_claims(t_arm, times, 400)
-        tier_claims = _expected_claims(t_arm, times, 150)
-        assert tel_claims >= 3 and tier_claims >= 8   # non-vacuous
-        assert s.telemetry.snapshot()["ticks"] - tel0 == tel_claims
-        assert s.tiering.snapshot()["ticks"] - tier0 == tier_claims
-        assert s.telemetry.snapshot()["drops"] == 0
-        # one dispatch per batch — the epilogue added NONE
-        assert (s.obs.counters.get(obs_keys.PIPE_DISPATCH) - base
-                == len(times))
-        assert (s.obs.counters.get(obs_keys.ROUTE_SINGLE_DISPATCH)
-                >= len(times))
-        # the carried estimates actually landed for demotion ranking
-        assert s.tiering._last_est is not None
-        # carried telemetry produced hot rows like a standalone tick
-        assert s.telemetry.snapshot()["hot"]
+        sched = CadenceScheduler(s, telemetry_interval_sec=1.0,
+                                 tiering_interval_sec=0.2)
+        svc = getattr(s, service)
+        assert svc.enabled
+        clk.advance_ms(777)                  # the stamp is start()'s, not
+        sched.start()                        # the constructor's
+        sched.stop()                         # poll() below is the body
+        t_start = T0 + 777
+        assert s.telemetry.last_tick_ms() == t_start
+        assert s.tiering.last_tick_ms() == t_start
+        ticks0 = svc.snapshot()["ticks"]
+        threshold = int(interval_ms * CadenceScheduler.IDLE_FACTOR)
+        for _ in range(3):                   # traffic, no poll
+            clk.advance_ms(threshold // 4)
+            _serve_one(s, ["a", "b", "c"])
+        assert svc.snapshot()["ticks"] == ticks0
+        assert svc.last_tick_ms() == t_start
+        target = t_start + threshold - (0 if due else 1)
+        clk.advance_ms(target - clk.now_ms())
+        sched.poll()
+        assert svc.snapshot()["ticks"] == ticks0 + (1 if due else 0)
+        assert svc.last_tick_ms() == (target if due else t_start)
+        assert sched.errors == 0
     finally:
         s.close()
 
-
-def test_epilogue_estimates_match_standalone_tick(clk, monkeypatch):
-    """The tier branch of the epilogue is sketch.tick_read — the SAME
-    math the self-dispatched ticker jits. Replaying the decay on the
-    pre-epilogue table must reproduce the carried estimate bitwise."""
-    import jax.numpy as jnp
-
-    from sentinel_tpu.tiering import sketch as sk
-    monkeypatch.setenv("SENTINEL_SINGLE_DISPATCH", "1")
-    s = make(clk)
-    try:
-        _drive_fused(s, clk, steps=4, advance_ms=10)   # warm traffic
-        pre = np.asarray(s.tiering._sketch).copy()
-        s.tiering.arm_carry(1)
-        clk.advance_ms(5)
-        _drive_fused(s, clk, steps=1, advance_ms=0)
-        est = np.asarray(s.tiering._last_est)
-        # replay: observe THIS batch's rows is fused before the decay,
-        # so recompute from the post-observe pre-decay table
-        post = np.asarray(s.tiering._sketch)
-        ref_counts, ref_est = sk.tick_read(jnp.asarray(pre_observe(s, pre)),
-                                           s.spec.rows)
-        np.testing.assert_array_equal(est, np.asarray(ref_est))
-        np.testing.assert_array_equal(post, np.asarray(ref_counts))
-    finally:
-        s.close()
-
-
-def pre_observe(s, pre):
-    """The epilogue's input table: the pre-step sketch plus this step's
-    observe (recomputed host-side via the shared update op)."""
-    import jax.numpy as jnp
-
-    from sentinel_tpu.tiering import sketch as sk
-    batch = _LAST_BATCH[0]
-    counts, _ = sk.update_sketch(jnp.asarray(pre),
-                                 jnp.asarray(batch[0]),
-                                 jnp.asarray(batch[1]))
-    return np.asarray(counts)
-
-
-_LAST_BATCH = [None]
-
-
-@pytest.fixture(autouse=True)
-def _capture_batches(monkeypatch):
-    """Record each fused dispatch's padded (rows, valid) so the
-    estimate-replay test can recompute the observe host-side."""
-    from sentinel_tpu import runtime as rt
-    orig = rt.Sentinel.decide_and_exit_raw_nowait
-
-    def spy(self, rows, *a, **kw):
-        out = orig(self, rows, *a, **kw)
-        b = self._pad(rows.shape[0])
-        padded = np.full(b, self.spec.rows, np.int32)
-        padded[:rows.shape[0]] = rows
-        valid = np.zeros(b, np.bool_)
-        valid[:rows.shape[0]] = (kw.get("valid")
-                                 if kw.get("valid") is not None
-                                 else np.ones(rows.shape[0], np.bool_))
-        _LAST_BATCH[0] = (padded, valid)
-        return out
-
-    monkeypatch.setattr(rt.Sentinel, "decide_and_exit_raw_nowait", spy)
-    yield
-    _LAST_BATCH[0] = None
-
-
-# ---------------------------------------------------------------------------
-# scheduler fallback + disable env
-# ---------------------------------------------------------------------------
 
 def test_scheduler_self_dispatch_on_idle(clk, monkeypatch):
-    """Zero traffic: the CadenceScheduler self-dispatches a standalone
-    tick once a service's armed cadence goes ``IDLE_FACTOR`` stale, and
-    stays quiet while carried ticks keep the cadence fresh."""
+    """Zero traffic: the CadenceScheduler ticks a service once
+    ``IDLE_FACTOR`` × its interval has passed since its last tick, and
+    each tick re-bases that service's interval."""
     monkeypatch.setenv("SENTINEL_SINGLE_DISPATCH", "1")
     s = make(clk)
     try:
         sched = CadenceScheduler(s, telemetry_interval_sec=1.0,
                                  tiering_interval_sec=0.2)
-        # arm without starting the wall-clock thread — poll() is the body
-        s.telemetry.arm_carry(1000)
-        s.tiering.arm_carry(200)
+        # no wall-clock thread — poll() is the body
         s.intern_resources(["a"])            # give the hot set a row
         tel0 = s.telemetry.snapshot()["ticks"]
         tier0 = s.tiering.snapshot()["ticks"]
         sched.poll()                         # fresh: nothing due
         assert s.telemetry.snapshot()["ticks"] == tel0
         assert s.tiering.snapshot()["ticks"] == tier0
-        clk.advance_ms(350)                  # tiering stale (>= 1.5x200)
+        clk.advance_ms(350)                  # tiering due (>= 1.5x200)
         sched.poll()
         assert s.tiering.snapshot()["ticks"] == tier0 + 1
         assert s.telemetry.snapshot()["ticks"] == tel0
-        clk.advance_ms(1200)                 # both stale now
+        clk.advance_ms(1200)                 # both due now
         sched.poll()
         assert s.telemetry.snapshot()["ticks"] == tel0 + 1
         assert s.tiering.snapshot()["ticks"] == tier0 + 2
-        # fresh traffic carries the epilogue; the scheduler stays quiet
-        clk.advance_ms(250)
-        _drive_fused(s, clk, steps=1, advance_ms=0)
-        tier_now = s.tiering.snapshot()["ticks"]
+        clk.advance_ms(250)                  # 250 < 300 since that tick
         sched.poll()
-        assert s.tiering.snapshot()["ticks"] == tier_now
-        sched.stop()                         # idempotent, disarms
-        assert s.telemetry._carry_ms is None
-        assert s.tiering._carry_ms is None
+        assert s.tiering.snapshot()["ticks"] == tier0 + 2
+        sched.stop()                         # idempotent without a thread
     finally:
         s.close()
 
 
 def test_scheduler_start_stop_thread(monkeypatch):
-    """start() arms both carries + spawns one daemon; stop() joins it.
-    Registered with the engine's shutdown hooks (close() stops it)."""
-    s = make(ManualClock(start_ms=T0))
+    """start() stamps both services + spawns one daemon; stop() joins
+    it. Registered with the engine's shutdown hooks (close() stops
+    it)."""
+    clk = ManualClock(start_ms=T0)
+    s = make(clk)
     try:
         sched = CadenceScheduler(s)
+        clk.advance_ms(40)
         sched.start()
         assert sched._thread is not None and sched._thread.is_alive()
         assert sched._thread.name == "sentinel-cadence"
-        assert s.telemetry._carry_ms is not None
-        assert s.tiering._carry_ms is not None
+        assert s.telemetry.last_tick_ms() == T0 + 40
+        assert s.tiering.last_tick_ms() == T0 + 40
         sched.start()                        # idempotent
         sched.stop()
         assert sched._thread is None

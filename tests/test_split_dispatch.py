@@ -273,3 +273,134 @@ def test_split_with_prio_and_live_bookings_equals_sequential(clk):
             (np.asarray(A._state.flow_dyn.occupied_count) > 0).any())
         clk.advance_ms(int(rng.integers(100, 400)))
     assert saw_booking, "no occupy booking exercised — weak test"
+
+
+# ---------------------------------------------------------------------------
+# the route table: which program family one raw batch is dispatched to
+# ---------------------------------------------------------------------------
+
+def _route_cols(sph, n):
+    """A pure-scalar raw batch of ``n`` lanes over the loaded resources."""
+    names = ["api", "paced", "rel", "free"]
+    rows = np.array([sph.resources.get_or_create(names[i % len(names)])
+                     for i in range(n)], np.int32)
+    pad_a = sph.spec.alt_rows
+    return dict(rows=rows, origin_ids=np.zeros(n, np.int32),
+                origin_rows=np.full(n, pad_a, np.int32),
+                context_ids=np.zeros(n, np.int32),
+                chain_rows=np.full(n, pad_a, np.int32),
+                acquire=np.ones(n, np.int32), is_in=np.ones(n, bool),
+                prioritized=np.zeros(n, bool))
+
+
+def _with_origin(sph, c, lanes, alt_rows=True):
+    oid = sph.origins.pin("app-a")
+    c["origin_ids"][lanes] = oid
+    if alt_rows:
+        for i in np.arange(c["rows"].shape[0])[lanes]:
+            c["origin_rows"][i] = sph._alt_row(int(c["rows"][i]), 0, oid)
+
+
+def _origin_ids_only(sph, c):
+    _with_origin(sph, c, slice(None), alt_rows=False)
+
+
+def _alt_rows(sph, c):
+    _with_origin(sph, c, slice(None))
+
+
+def _prioritized(sph, c):
+    c["prioritized"][::7] = True
+
+
+def _non_uniform_acquire(sph, c):
+    c["acquire"][::3] = 2
+
+
+def _garbage_on_invalid_lanes(sph, c):
+    c["valid"] = np.ones(c["rows"].shape[0], bool)
+    c["valid"][::2] = False
+    c["acquire"][::2] = 5
+    c["origin_ids"][::2] = sph.origins.pin("app-a")
+
+
+def _zero_cluster_bits(sph, c):
+    c["cluster_fallback"] = np.zeros(c["rows"].shape[0], np.int32)
+
+
+def _mixed(sph, c):
+    _with_origin(sph, c, slice(0, 32))
+
+
+SCALAR, FAST, FAST_OCC, GENERAL, SPLIT = (
+    "split_route.scalar", "split_route.fast", "split_route.fast_occupy",
+    "split_route.general_sorted", "split_route.split_fired")
+
+#: id → (lanes, batch mutation, env, rank key fits, route, dispatches,
+#: sketch-fused). ``dispatches`` is what ``pipeline.dispatches`` rises by.
+ROUTE_TABLE = {
+    "pure_scalar": (64, None, {}, True, SCALAR, 1, True),
+    "origin_ids_only": (64, _origin_ids_only, {}, True, FAST, 1, True),
+    "alt_rows": (64, _alt_rows, {}, True, FAST, 1, True),
+    "prioritized": (64, _prioritized, {}, True, FAST_OCC, 1, True),
+    "non_uniform_acquire": (64, _non_uniform_acquire, {}, True, GENERAL,
+                            1, True),
+    "invalid_lanes_do_not_count": (64, _garbage_on_invalid_lanes, {}, True,
+                                   SCALAR, 1, True),
+    "cluster_bits_present": (64, _zero_cluster_bits, {}, True, FAST, 1,
+                             True),
+    "rank_key_too_wide": (64, _alt_rows, {}, False, GENERAL, 1, True),
+    "rank_key_too_wide_scalar": (64, None, {}, False, SCALAR, 1, True),
+    "mixed_small": (4095 + 32, _mixed, {}, True, FAST, 1, True),
+    "mixed_split": (4096 + 32, _mixed, {}, True, SPLIT, 2, False),
+    "mixed_split_rank_key_too_wide": (4096 + 32, _mixed, {}, False,
+                                      GENERAL, 1, True),
+    "untiered_scalar": (64, None, {"SENTINEL_TIERING_DISABLE": "1"}, True,
+                        SCALAR, 1, False),
+    "untiered_split": (4096 + 32, _mixed,
+                       {"SENTINEL_TIERING_DISABLE": "1"}, True, SPLIT, 2,
+                       False),
+    "standalone_observe": (64, None, {"SENTINEL_SINGLE_DISPATCH": "0"},
+                           True, SCALAR, 2, False),
+}
+
+
+@pytest.mark.parametrize("case", list(ROUTE_TABLE))
+def test_route_table(clk, monkeypatch, case):
+    """One raw batch → exactly one route counter, the dispatches that
+    route costs, and ``split_route.single_dispatch`` only when a
+    whole-batch decide carried the sketch observe. The rank key that does
+    not fit int32 is faked through the eligibility helper's inputs."""
+    from sentinel_tpu.obs import counters as ck
+    n, mutate, env, key_fits, route, dispatches, fused = ROUTE_TABLE[case]
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    sph = make_sentinel(clk)
+    try:
+        sph.load_flow_rules(RULES)
+        cols = _route_cols(sph, n)
+        if mutate is not None:
+            mutate(sph, cols)
+        if not key_fits:
+            real = sph._lane_eligibility
+            monkeypatch.setattr(
+                sph, "_lane_eligibility",
+                lambda n, oid, acq, prio, valid, _slots, pad_a: real(
+                    n, oid, acq, prio, valid, 2 ** 31, pad_a))
+        args = [cols.pop(k) for k in (
+            "rows", "origin_ids", "origin_rows", "context_ids",
+            "chain_rows", "acquire", "is_in", "prioritized")]
+        routes = (SCALAR, FAST, FAST_OCC, GENERAL, SPLIT)
+        c = sph.obs.counters
+        before = {k: c.get(k) for k in routes}
+        disp0 = c.get(ck.PIPE_DISPATCH)
+        sd0 = c.get(ck.ROUTE_SINGLE_DISPATCH)
+        v = sph.decide_raw_nowait(*args, **cols).result()
+        assert v.allow.shape == (n,)
+        fired = {k: c.get(k) - before[k] for k in routes}
+        assert fired == {k: int(k == route) for k in routes}
+        assert c.get(ck.PIPE_DISPATCH) - disp0 == dispatches
+        assert c.get(ck.ROUTE_SINGLE_DISPATCH) - sd0 == int(fused)
+        assert c.get(ck.ROUTE_FUSED) == 0
+    finally:
+        sph.close()

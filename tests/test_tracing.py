@@ -5,7 +5,7 @@ tracing"):
   the batch, fan-out expands only from the root — sibling requests
   stay out of each other's chains);
 * trace-id threading through the DispatchPipeline into the device
-  spans, on the split route AND the fused decide+exit route;
+  spans, on the split route;
 * the full request lifecycle chain through the real AdaptiveBatcher
   (enqueue → flush → pipeline → device → settle) with per-request
   fan-out links;
@@ -118,27 +118,6 @@ def test_pipeline_threads_trace_through_split_route(clk):
                      "split.device", "pipeline.settle"):
         assert expected in names, f"chain missing {expected}: {names}"
     assert all(s["trace"] == tr for s in sph.obs.spans.chain(tr))
-    sph.close()
-
-
-def test_pipeline_threads_trace_through_fused_route(clk):
-    sph = make(clk)
-    rows = np.asarray([sph.resources.get_or_create("x")], np.int32)
-    pad_a = sph.spec.alt_rows
-    one = np.ones(1, np.int32)
-    pipe = stpu.DispatchPipeline(sph, depth=2)
-    tr = sph.obs.spans.mint()
-    t = pipe.submit_fused(
-        rows, np.zeros(1, np.int32), np.full(1, pad_a, np.int32),
-        np.zeros(1, np.int32), np.full(1, pad_a, np.int32), one,
-        np.ones(1, np.bool_), np.zeros(1, np.bool_), exit_rows=rows,
-        trace_id=tr)
-    assert bool(t.result().allow[0])
-    names = [s["name"] for s in sph.obs.spans.chain(tr)]
-    for expected in ("pipeline.enqueue", "fused.dispatch",
-                     "pipeline.settle"):
-        assert expected in names, f"chain missing {expected}: {names}"
-    assert sph.obs.counters.get(ck.ROUTE_FUSED) == 1
     sph.close()
 
 
