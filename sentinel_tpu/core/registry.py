@@ -22,10 +22,66 @@ from __future__ import annotations
 
 import collections
 import threading
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import (Callable, Dict, Iterable, List, NamedTuple, Optional,
+                    Sequence, Tuple)
+
+import numpy as np
 
 ENTRY_NODE_ROW = 0
 ENTRY_NODE_NAME = "__entry_node__"
+
+
+class InternedBatch(NamedTuple):
+    """A batch of names after interning, per occurrence and per name."""
+    rows: np.ndarray        # int32[n]: the row of every occurrence
+    names_u: List[str]      # the d distinct names, first-occurrence order
+    rows_u: np.ndarray      # int32[d]: the row of each distinct name
+    counts: np.ndarray      # int[d]: occurrences of each distinct name
+
+
+def intern_batch(names: Sequence[str],
+                 intern_one: Callable[[str], int],
+                 intern_many: Callable[[Sequence[str]], np.ndarray],
+                 distinct_pays: bool) -> InternedBatch:
+    """THE dedup of a batch of names — every door that interns a batch
+    (``Sentinel.entry_batch_nowait``, ``Sentinel.intern_resources``,
+    ``NativeRegistry.get_or_create_batch``) comes through here, and what
+    is per NAME downstream (tiering's classification, the counters) runs
+    over ``names_u``. Per OCCURRENCE there are two C-speed passes (the
+    dict of distinct names, the inverse index) and one NumPy gather; no
+    Python-level loop.
+
+    What the registry sees: an all-identical batch (per-resource serving
+    loops send ONE name 4k times) is one ``intern_one``; where
+    ``distinct_pays`` (hashing a name is ~30x cheaper than encoding and
+    marshalling it for the C++ table) a batch of more than 64 names that
+    repeats each at least twice on average interns its distinct names in
+    first-occurrence order; every other batch interns every occurrence in
+    order, and ``rows_u`` is ``rows`` at the first occurrences (a name
+    evicted and re-interned inside the batch keeps each occurrence's own
+    row)."""
+    n = len(names)
+    # names.count is a C-speed scan, but of the whole list: look at the
+    # far end before paying it on a batch that is not identical
+    if (n > 64 and isinstance(names, list) and names[-1] == names[0]
+            and names.count(names[0]) == n):
+        row = intern_one(names[0])
+        return InternedBatch(np.full(n, row, np.int32), names[:1],
+                             np.array([row], np.int32), np.array([n]))
+    names_u = list(dict.fromkeys(names))
+    d = len(names_u)
+    pos = dict(zip(names_u, range(d)))
+    inv = np.fromiter(map(pos.__getitem__, names), np.intp, count=n)
+    if distinct_pays and n > 64 and 2 * d < n:
+        rows_u = intern_many(names_u)
+        rows = rows_u[inv]
+    else:
+        rows = intern_many(names)
+        first_at = np.empty(d, np.intp)
+        # a repeated index keeps its LAST assignment: walk backwards
+        first_at[inv[::-1]] = np.arange(n - 1, -1, -1)
+        rows_u = rows[first_at]
+    return InternedBatch(rows, names_u, rows_u, np.bincount(inv, minlength=d))
 
 
 class Registry:
@@ -73,14 +129,29 @@ class Registry:
                 return rid
         raise RuntimeError("registry full and all rows pinned")
 
+    def _get_or_create_locked(self, name: str) -> int:
+        rid = self._name_to_id.get(name)
+        if rid is None:
+            rid = self._alloc_locked(name)
+        else:
+            self._name_to_id.move_to_end(name)
+        return rid
+
     def get_or_create(self, name: str) -> int:
         with self._lock:
-            rid = self._name_to_id.get(name)
-            if rid is None:
-                rid = self._alloc_locked(name)
-            else:
-                self._name_to_id.move_to_end(name)
-            return rid
+            return self._get_or_create_locked(name)
+
+    def intern_batch(self, names: Sequence[str]) -> InternedBatch:
+        """Intern a batch under ONE lock hold, every occurrence in order
+        (the LRU order a caller's loop of :meth:`get_or_create` leaves),
+        with the distinct view beside the rows: :func:`intern_batch`."""
+        return intern_batch(names, self.get_or_create, self._intern_each,
+                            distinct_pays=False)
+
+    def _intern_each(self, names: Sequence[str]) -> np.ndarray:
+        with self._lock:
+            return np.fromiter(map(self._get_or_create_locked, names),
+                               np.int32, count=len(names))
 
     def lookup(self, name: str) -> Optional[int]:
         with self._lock:

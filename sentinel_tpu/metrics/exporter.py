@@ -220,6 +220,13 @@ class SentinelCollector:
             "queue_wait_us (their summed wait in the server's queue) "
             "(cluster/server.py)",
             labels=["event"])
+        intern = CounterMetricFamily(
+            f"{ns}_intern_total",
+            "Names handed to the batch doors as strings (names) and the "
+            "distinct names among them, batch by batch (distinct): the "
+            "registry and tiering work per distinct name "
+            "(runtime.Sentinel._intern_batch)",
+            labels=["event"])
         if not describe_only and obs is not None and obs.enabled:
             from sentinel_tpu.obs import counters as ck
             counts = obs.counters.snapshot()
@@ -306,6 +313,9 @@ class SentinelCollector:
                             (ck.CLUSTER_SERVER_QUEUE_WAIT_US,
                              "queue_wait_us")):
                 cluster_srv.add_metric([ev], counts.get(key, 0))
+            for key, ev in ((ck.INTERN_NAMES, "names"),
+                            (ck.INTERN_DISTINCT, "distinct")):
+                intern.add_metric([ev], counts.get(key, 0))
             # bounded by construction: at most telemetry.k ≤ MAX_K labels
             # (×3 quantile labels for res_rt — still top-K-bounded)
             telemetry = getattr(self.sentinel, "telemetry", None)
@@ -322,7 +332,7 @@ class SentinelCollector:
                     blocks, occupy, pipeline, frontend, fe_flush, wraps,
                     flight_pinned, flight_trig, sf_ovf, tune,
                     res_qps, res_rt, telem, label_ovf, tier, control,
-                    cluster_srv)
+                    cluster_srv, intern)
 
     def collect(self):
         ns = self.namespace

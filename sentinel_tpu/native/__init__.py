@@ -22,6 +22,8 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from sentinel_tpu.core.registry import InternedBatch, intern_batch
+
 _SRC = Path(__file__).parent / "src" / "registry.cpp"
 
 
@@ -163,28 +165,18 @@ class NativeRegistry:
             raise RuntimeError("registry full and all rows pinned")
         return rid
 
+    def intern_batch(self, names) -> InternedBatch:
+        """Vector path: the batch is deduplicated once
+        (:func:`sentinel_tpu.core.registry.intern_batch`) and, when that
+        pays, only its DISTINCT names are encoded and cross the FFI, in
+        one call under one lock; ``rows`` is then a NumPy gather. Returns
+        the rows per occurrence with the distinct view beside them."""
+        return intern_batch(names, self.get_or_create, self._intern_encoded,
+                            distinct_pays=True)
+
     def get_or_create_batch(self, names) -> np.ndarray:
-        """Vector path: one lock + one FFI call for the whole batch.
-        Batches repeat few distinct names (per-resource serving loops often
-        send ONE name 4k times), so dedup first when it pays — dict hashing
-        a name is ~30× cheaper than encoding + marshalling it."""
-        n = len(names)
-        if n > 64:
-            # all-identical batch (per-resource serving loops): ONE intern,
-            # no dict pass — names.count is a C-speed scan
-            first = names[0]
-            if isinstance(names, list) and names.count(first) == n:
-                row = self.get_or_create(first)
-                return np.full(n, row, np.int32)
-            pos: dict = {}
-            for s in names:
-                if s not in pos:
-                    pos[s] = len(pos)
-            if len(pos) * 2 < n:
-                rows_u = self._intern_encoded(list(pos))
-                return rows_u[np.fromiter((pos[s] for s in names),
-                                          np.int32, count=n)]
-        return self._intern_encoded(names)
+        """The rows of :meth:`intern_batch`, per occurrence."""
+        return self.intern_batch(names).rows
 
     def _intern_encoded(self, names) -> np.ndarray:
         enc = [n.encode("utf-8") for n in names]

@@ -52,8 +52,9 @@ from sentinel_tpu.core.errors import (
 from sentinel_tpu.core import errors as err_mod
 from sentinel_tpu.core.property import SentinelProperty
 from sentinel_tpu.core.registry import (
-    ENTRY_NODE_ROW, OriginRegistry, Registry, ResourceRegistry,
-    make_origin_registry, make_registry, make_resource_registry,
+    ENTRY_NODE_ROW, InternedBatch, OriginRegistry, Registry,
+    ResourceRegistry, make_origin_registry, make_registry,
+    make_resource_registry,
 )
 from sentinel_tpu.engine.pipeline import (
     EngineSpec, EntryBatch, ExitBatch, RuleSet, SentinelState, Verdicts,
@@ -2009,34 +2010,40 @@ class Sentinel:
     def _pad(self, n: int) -> int:
         return pad_pow2(n)
 
-    def intern_resources(self, resources: Sequence[str]) -> np.ndarray:
-        """Pre-stage a batch's resource rows: intern every DISTINCT name
-        once and return the int32 row array. Serving loops that dispatch
-        the same resource set step after step pass the returned array
-        straight to :meth:`entry_batch` / :meth:`entry_batch_nowait` as
-        ``resources``, moving the string-encode + intern cost out of the
-        per-step path (one FFI call here instead of one per step).
+    def _intern_batch(self, resources: Sequence[str]) -> InternedBatch:
+        """Intern a batch of names for the batch doors: ONE dedup
+        (:func:`sentinel_tpu.core.registry.intern_batch`), the registry
+        and tiering's classification over the DISTINCT names, and the
+        count of how far that helped (``intern.names`` /
+        ``intern.distinct``: their ratio is the share of the per-name
+        work a batch still pays — 1.0 for traffic that never repeats a
+        name)."""
+        batch = self.resources.intern_batch(resources)
+        # tiering: classify hot hit / cold miss and queue promotions for
+        # any re-interned cold keys (restored in the next eviction drain,
+        # before that dispatch's decide)
+        self.tiering.note_interned(batch.names_u, batch.rows_u, batch.counts)
+        if self.obs.enabled:
+            self.obs.counters.add(obs_keys.INTERN_NAMES, len(resources))
+            self.obs.counters.add(obs_keys.INTERN_DISTINCT,
+                                  len(batch.names_u))
+        return batch
 
-        Duplicates resolve through a host map rather than repeated
-        registry allocations — a Zipf batch over a huge keyspace (round
-        15's 16M–64M-key workloads) interns its few hundred distinct
-        names once instead of pre-building a row per occurrence, so a
-        single skewed batch can no longer churn the LRU with cold keys."""
-        distinct = dict.fromkeys(resources)
-        names = list(distinct)
-        batch_intern = getattr(self.resources, "get_or_create_batch", None)
-        if batch_intern is not None:
-            drows = np.asarray(batch_intern(names), np.int32)
-        else:
-            drows = np.fromiter(
-                (self.resources.get_or_create(r) for r in names),
-                np.int32, count=len(names))
-        self.tiering.note_interned(names, drows)
-        if len(names) == len(resources):
-            return drows
-        by_name = dict(zip(names, drows))
-        return np.fromiter((by_name[r] for r in resources), np.int32,
-                           count=len(resources))
+    def intern_resources(self, resources: Sequence[str]) -> np.ndarray:
+        """Pre-stage a batch's resource rows: intern the names and return
+        the int32 row array, one row per OCCURRENCE. Serving loops that
+        dispatch the same resource set step after step pass the returned
+        array straight to :meth:`entry_batch` / :meth:`entry_batch_nowait`
+        as ``resources``, moving the string-encode + intern cost out of
+        the per-step path (one FFI call here instead of one per step).
+
+        The same dedup as a string batch's own (:meth:`_intern_batch`):
+        the registry touches and tiering classifies each distinct NAME
+        once where the batch repeats its names, so a Zipf batch over a
+        huge keyspace (round 15's 16M–64M-key workloads) interns its few
+        hundred distinct names once; ``tier.hot_hit`` / ``tier.cold_miss``
+        count occurrences."""
+        return self._intern_batch(resources).rows
 
     def entry_batch(self, resources: Sequence[str], *,
                     origins: Optional[Sequence[str]] = None,
@@ -2094,23 +2101,14 @@ class Sentinel:
         obs_on = obs.enabled
         tr = (trace_id or obs.spans.maybe_trace()) if obs_on else 0
         t0 = obs.spans.now_ns() if obs_on else 0
-        with obs.phase("entry.prep", n=n, trace=tr):
+        with obs.phase("entry.prep", n=n, trace=tr) as prep:
             if isinstance(resources, np.ndarray) and resources.dtype.kind in "iu":
                 rows = np.ascontiguousarray(resources, np.int32)
                 resources = None
             else:
-                batch_intern = getattr(self.resources, "get_or_create_batch",
-                                       None)
-                if batch_intern is not None:  # native table: one FFI call, no GIL
-                    rows = batch_intern(resources)
-                else:
-                    rows = np.fromiter(
-                        (self.resources.get_or_create(r) for r in resources),
-                        np.int32, count=n)
-                # tiering: classify hot hit / cold miss and queue promotions
-                # for any re-interned cold keys (restored in this dispatch's
-                # eviction drain, before its decide)
-                self.tiering.note_interned(resources, rows)
+                batch = self._intern_batch(resources)
+                rows = batch.rows
+                prep.note = f"distinct={len(batch.names_u)}"
             if resources is None and (self._host_gates  # graftlint: disable=LOCK002 -- hot-path feature gate: a stale read routes one batch through the exact device path, never unsafely
                                       or self._cluster_rules_by_row
                                       or self._cluster_param_rules_by_row):

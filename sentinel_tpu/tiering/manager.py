@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import itertools
 import os
 import threading
 import time
@@ -198,23 +199,30 @@ class TierManager:
 
     # ---- intern-time hooks (outside the engine lock) ------------------
 
-    def note_interned(self, names, rows, tick: bool = True) -> None:
-        """Record name→row ownership for a just-interned batch and
-        classify each occurrence: resident name → ``tier.hot_hit``;
-        name the cold tier (or an in-flight demote) knows →
-        ``tier.cold_miss`` + queued promotion; first-sight name →
-        neither (a brand-new key is not a *miss* of anything — see the
-        hit-rate note in OPERATIONS.md). O(distinct names) python —
-        serving loops front this with the batcher's name→row cache, so
-        only cache misses pay it. ``tick=False`` (rule-load pin paths,
-        runtime._update_rule_pins_locked) keeps the shadow map and
-        promotion queue exact without counting control-plane interns
+    def note_interned(self, names, rows, counts=None,
+                      tick: bool = True) -> None:
+        """Record name→row ownership for just-interned names and classify
+        each NAME once: ``names`` are DISTINCT (an
+        :class:`~sentinel_tpu.core.registry.InternedBatch`'s ``names_u``
+        / ``rows_u`` / ``counts``; ``counts=None`` is one occurrence
+        each). Resident name → ``tier.hot_hit``; name the cold tier (or
+        an in-flight demote) knows → ``tier.cold_miss`` + queued
+        promotion; first-sight name → neither (a brand-new key is not a
+        *miss* of anything — see the hit-rate note in OPERATIONS.md).
+        Both counters add the name's OCCURRENCES. O(distinct names)
+        Python, nothing per occurrence. ``tick=False`` (rule-load pin
+        paths, runtime._update_rule_pins_locked) keeps the shadow map
+        and promotion queue exact without counting control-plane interns
         into the serving hit rate."""
         if not self.enabled:
             return
+        if isinstance(rows, np.ndarray):
+            rows = rows.tolist()        # Python ints: no NumPy scalars below
+        counts = (itertools.repeat(1) if counts is None
+                  else np.asarray(counts).tolist())
         hot = cold = 0
         with self._lock:
-            seen: Dict[str, list] = {}    # name → [count, classification]
+            shadow = self._shadow
             # Two passes so classification cannot depend on intra-batch
             # ORDER: when name A's fresh row displaced name B and B is
             # ALSO in this batch at a new row (a rule reload re-interning
@@ -225,33 +233,23 @@ class TierManager:
             # state was dropped or kept by hash order (the real cause of
             # the seed-1602 tiered-vs-resident divergence once blamed on
             # the staging ring).
-            fresh: List[Tuple[str, int]] = []
-            for i, name in enumerate(names):
-                rec = seen.get(name)
-                if rec is not None:
-                    rec[0] += 1
-                    continue
-                row = int(rows[i])
-                prev = self._shadow.get(row)
+            fresh: List[Tuple[str, int, int]] = []
+            for name, row, cnt in zip(names, rows, counts):
+                prev = shadow.get(row)
                 if prev == name:
-                    seen[name] = [1, "hot"]
+                    hot += cnt
                     continue
-                self._shadow[row] = name
+                shadow[row] = name
                 if prev is not None:
                     self._pending_demote.setdefault(row, prev)
-                seen[name] = [1, "new"]
-                fresh.append((name, row))
-            for name, row in fresh:
-                if (name in self.cold or name in self._pending_land
-                        or any(v == name
-                               for v in self._pending_demote.values())):
-                    self._pending_promote[name] = row
-                    seen[name][1] = "cold"
-            for _name, (cnt, kind) in seen.items():
-                if kind == "hot":
-                    hot += cnt
-                elif kind == "cold":
-                    cold += cnt
+                fresh.append((name, row, cnt))
+            if fresh:
+                demoting = set(self._pending_demote.values())
+                for name, row, cnt in fresh:
+                    if (name in self.cold or name in self._pending_land
+                            or name in demoting):
+                        self._pending_promote[name] = row
+                        cold += cnt
         if tick and self._obs.enabled:
             if hot:
                 self._obs.counters.add(obs_keys.TIER_HOT_HIT, hot)
