@@ -184,6 +184,16 @@ TIER_COLD_MISS = "tier.cold_miss"
 TIER_PROMOTED = "tier.promoted"
 TIER_DEMOTED = "tier.demoted"
 TIER_SKETCH_OVERFLOW = "tier.sketch_overflow"
+# PR 33 — what the two classification counters leave out, and where a
+# landing ran: ``first_sight`` counts the distinct NAMES interned that
+# neither tier knew (each takes a row and, with the table full, evicts
+# one: the rate the cold tier grows at); ``land_inline`` counts the
+# victims whose demote payload was landed into the cold tier on the
+# engine's own thread, under its lock — a promotion that found its
+# payload still in flight, or the backlog bound ``PENDING_LAND_MAX`` —
+# instead of on the tiering thread.
+TIER_FIRST_SIGHT = "tier.first_sight"
+TIER_LAND_INLINE = "tier.land_inline"
 
 # ``pipeline.dispatches`` counts DEVICE DISPATCHES issued by the
 # serving hot path and its tickers (decide = 1, split = 2, exit = 1, a
@@ -285,6 +295,7 @@ CATALOG = (
     CLUSTER_SERVER_CYCLES, CLUSTER_SERVER_TAKEN,
     CLUSTER_SERVER_QUEUE_WAIT_US,
     INTERN_NAMES, INTERN_DISTINCT,
+    TIER_FIRST_SIGHT, TIER_LAND_INLINE,
 )
 
 

@@ -60,6 +60,7 @@ from sentinel_tpu.engine.pipeline import (
     EngineSpec, EntryBatch, ExitBatch, RuleSet, SentinelState, Verdicts,
     decide_entries, init_state, init_state_shapes,
     invalidate_resource_rows, record_blocks, record_exits,
+    restore_resource_rows,
 )
 from sentinel_tpu.engine import fastpath as fp_mod
 from sentinel_tpu.rules import authority as auth_mod
@@ -155,6 +156,17 @@ _STEP_STATICS = ("scalar_flow", "fast_flow", "skip_auth", "skip_sys",
                  "scalar_has_rl", "skip_threads", "sortfree")
 
 
+def named_partial(name: str, fn, *args, **kwargs):
+    """``functools.partial`` with a name: jitted, its program is the
+    module ``jit_<name>`` — what a device trace shows of it (a bare
+    partial compiles as ``jit__unknown``). The tier migration programs
+    carry theirs (``tier_extract``, ``tier_invalidate``, ``tier_restore``)
+    so that their device time can be read by name."""
+    bound = functools.partial(fn, *args, **kwargs)
+    bound.__name__ = name
+    return bound
+
+
 def _build_steps(spec: EngineSpec, custom_slots: tuple, shardings=None,
                  donate: bool = True):
     """``shardings`` = (state_shardings, verdict_shardings) pins every
@@ -191,9 +203,15 @@ def _build_steps(spec: EngineSpec, custom_slots: tuple, shardings=None,
             jax.jit(functools.partial(record_exits, spec,
                                       record_alt=False),
                     static_argnames=("skip_threads",), **kw_s, **kw_d1),
-            jax.jit(functools.partial(invalidate_resource_rows, spec),
+            jax.jit(named_partial("tier_invalidate",
+                                  invalidate_resource_rows, spec),
                     **kw_s, **kw_d0),
             jax.jit(functools.partial(record_blocks, spec),
+                    **kw_s, **kw_d0),
+            # tier promotion (tiering/manager.py): the state is rewritten
+            # in place and, on a mesh, lands under its own shardings
+            jax.jit(named_partial("tier_restore",
+                                  restore_resource_rows, spec),
                     **kw_s, **kw_d0))
 
 
@@ -439,9 +457,15 @@ class PendingVerdicts(PendingResult):
     A handle the caller drops anyway is settled by a GC finalizer (see
     :func:`_settle_leaked`) and counted in ``pipeline.leaked_handles`` —
     correctness is preserved, but the settle then runs at an arbitrary
-    point on the GC's thread, so a leak is still a caller bug."""
+    point on the GC's thread, so a leak is still a caller bug.
 
-    __slots__ = ("_leak_finalizer",)
+    ``rows`` (names batches: ``entry_batch_nowait``) is the int32 row each
+    event was admitted on, known at dispatch: what ``exit_batch`` takes
+    for the entries that pass. Under tiering churn a name's row changes
+    from one batch to the next, so this is the only way to exit what was
+    entered without interning the names a second time."""
+
+    __slots__ = ("_leak_finalizer", "rows")
 
     def attach_leak_guard(self, on_leak) -> None:
         f = weakref.finalize(self, _settle_leaked, self._cell, on_leak)
@@ -737,19 +761,15 @@ class Sentinel:
         self._staging_depth = max(4, 2 * int(self._tuned.get(
             PIPELINE_DEPTH_ENV, pipeline_depth())) + 2)
 
-        (self._jit_decide, self._jit_decide_prio,
-         self._jit_decide_noalt, self._jit_decide_prio_noalt,
-         self._jit_exit, self._jit_exit_noalt,
-         self._jit_invalidate, self._jit_record_blocks) = \
-            _jitted_steps(self.spec, shardings=self._mesh_shardings,
-                          donate=self._donate)
-        # sketch-fused decide programs, built lazily (_sd_steps_locked;
-        # reset wherever the legacy tuple above is reassigned). The knob
-        # off leaves every legacy path — and its program cache keys —
-        # byte-identical to pre-r16.
+        # device slots compile into the steps at registration
+        # (register_slot); none yet
+        self._device_slots: tuple = ()
+        # sketch-fused decide programs are built lazily (_sd_steps_locked)
+        # and reset with the step tuple. The knob off leaves every legacy
+        # path — and its program cache keys — byte-identical to pre-r16.
         self._single_dispatch = bool(self._tuned.get(
             "SENTINEL_SINGLE_DISPATCH", single_dispatch_enabled()))
-        self._sd_steps = None
+        self._bind_steps_locked()
         # (variant, geometry, statics) combos already dispatched once —
         # see _note_program_locked
         self._fetched_programs: set = set()
@@ -765,9 +785,8 @@ class Sentinel:
 
         # pluggable processor slots (SlotChainBuilder SPI analog,
         # engine/slots.py): host gates veto before dispatch, device slots
-        # compile into the fused decide at registration
+        # (above) compile into the fused decide at registration
         self._host_gates: tuple = ()
-        self._device_slots: tuple = ()
 
         # host-side fast path (SURVEY §7 hard-part 1): rule-free rows admit
         # on host with batched stat recording; single-simple-QPS rows serve
@@ -1168,17 +1187,24 @@ class Sentinel:
             from sentinel_tpu.parallel.local_shard import pin_state
             self._state = pin_state(self._state, self._mesh_shardings[0])
 
+    def _bind_steps_locked(self) -> None:
+        """(Re)bind the jitted steps to the live geometry, device slots
+        and shardings — at construction and wherever one of them
+        changes."""
+        (self._jit_decide, self._jit_decide_prio,
+         self._jit_decide_noalt, self._jit_decide_prio_noalt,
+         self._jit_exit, self._jit_exit_noalt,
+         self._jit_invalidate, self._jit_record_blocks,
+         self._jit_restore) = \
+            _jitted_steps(self.spec, self._device_slots,
+                          self._mesh_shardings, donate=self._donate)
+        self._sd_steps = None       # sketch-fused variants track the tuple
+
     def _reload_custom_jits_locked(self) -> None:
         self._state = self._state._replace(custom=tuple(
             s.init_state(self.spec) for s in self._device_slots))
         self._refresh_shardings_locked()    # custom states change structure
-        (self._jit_decide, self._jit_decide_prio,
-         self._jit_decide_noalt, self._jit_decide_prio_noalt,
-         self._jit_exit, self._jit_exit_noalt,
-         self._jit_invalidate, self._jit_record_blocks) = \
-            _jitted_steps(self.spec, self._device_slots,
-                          self._mesh_shardings, donate=self._donate)
-        self._sd_steps = None       # sketch-fused variants track the tuple
+        self._bind_steps_locked()
 
     def _sd_steps_locked(self):
         """Sketch-fused decide programs, built lazily (engine lock held —
@@ -1382,13 +1408,7 @@ class Sentinel:
                     self.cfg.max_flow_rules, new_second.buckets,
                     self.spec.rows))
             self._refresh_shardings_locked()
-            (self._jit_decide, self._jit_decide_prio,
-             self._jit_decide_noalt, self._jit_decide_prio_noalt,
-             self._jit_exit, self._jit_exit_noalt,
-             self._jit_invalidate, self._jit_record_blocks) = \
-                _jitted_steps(self.spec, self._device_slots,
-                              self._mesh_shardings, donate=self._donate)
-            self._sd_steps = None   # sketch-fused variants track the tuple
+            self._bind_steps_locked()
             self._occupy_live_until_ms = -1
             self._seen_idx = -(2 ** 62)
             self._fast.win_ms = max(1, new_second.win_ms)
@@ -2073,7 +2093,9 @@ class Sentinel:
         ``.result()``. Callers double-buffer — dispatch batch N+1 while N's
         verdicts are in flight — to hide the device→host latency entirely.
         ``.result()`` MUST be called for every handle: it also releases
-        blocked events' key pins and writes the block log.
+        blocked events' key pins and writes the block log. The handle's
+        ``rows`` are the rows the events were admitted on: pass those of
+        the entries that passed to :meth:`exit_batch`.
 
         ``args_list`` may be a 2D numpy integer array (one row per event) —
         the fastest form: single-rule integer-key workloads then resolve
@@ -2283,7 +2305,7 @@ class Sentinel:
                     obs.spans.record(tr, "entry.total", t0, t_end, n=n)
             return verdicts
 
-        return self._pending_verdicts(_finalize)
+        return self._pending_verdicts(_finalize, rows=rows)
 
     def _log_cluster_block(self, reason: int, resource: str, origin: str,
                            acquire: int, exc=None,
@@ -2511,11 +2533,12 @@ class Sentinel:
         _log.warning("PendingVerdicts dropped without .result(); "
                      "settled by the GC finalizer")
 
-    def _pending_verdicts(self, fn) -> "PendingVerdicts":
+    def _pending_verdicts(self, fn, rows=None) -> "PendingVerdicts":
         """Wrap a deferred settle in a leak-guarded handle (every nowait
         path returns through here so no handle can silently drop its
         bookkeeping)."""
         h = PendingVerdicts(fn)
+        h.rows = rows
         h.attach_leak_guard(self._on_leaked_handle)
         return h
 
@@ -3182,21 +3205,25 @@ class Sentinel:
                 # live occupy bookings are invalidated below
                 self.obs.counters.add(obs_keys.OCCUPY_EVICTED,
                                       len(evicted))
-            # tiering demote: snapshot the recycled rows' state into the
-            # cold tier BEFORE the invalidate destroys it (dispatch-only;
-            # stream order keeps the gather reading pre-invalidate
-            # values). Must run before the alt-edge pop below — the
-            # snapshot needs the slots' host identities.
-            self.tiering.pre_invalidate_locked(evicted, self.clock.now_ms())
-            alt: List[int] = []
-            for row in evicted:
-                alt.extend(self._alt_rows_by_row.pop(row, ()))
-            rows_arr = _pad_to(np.asarray(evicted, np.int32),
-                               self._pad(len(evicted)), self.spec.rows, np.int32)
-            alt_arr = _pad_to(np.asarray(alt, np.int32), self._pad(len(alt)),
-                              self.spec.alt_rows, np.int32)
-            self._state = self._jit_invalidate(
-                self._state, jnp.asarray(rows_arr), jnp.asarray(alt_arr))
+            with self.obs.phase("tier.demote", n=len(evicted)):
+                # tiering demote: snapshot the recycled rows' state into
+                # the cold tier BEFORE the invalidate destroys it
+                # (dispatch-only; stream order keeps the gather reading
+                # pre-invalidate values). Must run before the alt-edge pop
+                # below — the snapshot needs the slots' host identities.
+                self.tiering.pre_invalidate_locked(evicted,
+                                                   self.clock.now_ms())
+                alt: List[int] = []
+                for row in evicted:
+                    alt.extend(self._alt_rows_by_row.pop(row, ()))
+                rows_arr = _pad_to(np.asarray(evicted, np.int32),
+                                   self._pad(len(evicted)), self.spec.rows,
+                                   np.int32)
+                alt_arr = _pad_to(np.asarray(alt, np.int32),
+                                  self._pad(len(alt)), self.spec.alt_rows,
+                                  np.int32)
+                self._state = self._jit_invalidate(
+                    self._state, jnp.asarray(rows_arr), jnp.asarray(alt_arr))
         # tiering promote (the documented slow path): restore re-interned
         # cold keys into their freshly allocated rows — after the
         # invalidate, before the decide that triggered the intern, so
@@ -3251,13 +3278,52 @@ class Sentinel:
         return nodes
 
     def node_totals(self, resource: str) -> dict:
-        """Current rolling-second totals for a resource (ClusterNode view)."""
+        """Current rolling-second totals for a resource (ClusterNode view),
+        whichever tier holds it: a demoted name reads from its cold entry
+        what its row would read had it stayed."""
         row = self.resources.lookup(resource)
         if row is None:
-            return {}
-        t = self.node_totals_by_row(row)
+            entry = self.tiering.cold_entry(resource)
+            if entry is None:
+                return {}
+            now_idx = self.spec.second.index_of(self.clock.now_ms())
+            tot, rt = entry.rolling_totals(self.spec.second.buckets, now_idx)
+            t = self._totals_dict(tot, rt, entry.threads)
+        else:
+            t = self.node_totals_by_row(row)
         t.pop("avg_rt", None)
         return t
+
+    def rt_hist_by_name(self, resources: Sequence[str]) -> np.ndarray:
+        """The cumulative RT histogram (``int32[n, HB]``, the buckets of
+        obs/resource_hist.py) of each name, whichever tier holds it: a
+        resident name's row of ``state.rt_hist``, a demoted name's cold
+        entry, zeros for a name neither tier knows (or with the table
+        disabled). Pending evictions and promotions are applied first, so
+        the rows the registry names hold their owners' state; the engine
+        lock is held for that and for the dispatch of a copy of the table
+        (the telemetry-tick discipline), not for the read or the look-ups.
+        One full-table device read: a dashboard's or a check's call, not
+        the serving path's. Exact while no other thread interns names
+        during the call."""
+        hb = self.spec.hist_buckets
+        out = np.zeros((len(resources), hb), np.int32)
+        if not hb or not len(resources):
+            return out
+        with self._lock:
+            self._drain_evictions_locked()
+            table = _jit_copy_column(self._state.rt_hist)
+        rows = np.fromiter(
+            (-1 if r is None else r
+             for r in map(self.resources.lookup, resources)),
+            np.int64, count=len(resources))
+        hot = rows >= 0
+        out[hot] = np.asarray(table)[rows[hot]]
+        for i in np.nonzero(~hot)[0].tolist():
+            entry = self.tiering.cold_entry(resources[i])
+            if entry is not None and entry.rt_hist is not None:
+                out[i] = entry.rt_hist
+        return out
 
     def get_flow_rules(self) -> List[flow_mod.FlowRule]:
         return list(self._flow.rules)

@@ -47,15 +47,19 @@ class PipelinedVerdicts:
     """Ticket for one submitted batch: ``result()`` settles every older
     in-flight batch first (strict in-order settle), then memoizes this
     batch's :class:`~sentinel_tpu.engine.pipeline.Verdicts`. Safe to call
-    out of submission order and more than once."""
+    out of submission order and more than once. ``rows`` is the handle's
+    (:class:`~sentinel_tpu.runtime.PendingVerdicts`): for a batch of names
+    the row each event was admitted on, there from the submit on — what
+    the caller hands ``exit_batch`` for the entries that pass."""
 
-    __slots__ = ("_pipe", "_seq", "_done", "_res")
+    __slots__ = ("_pipe", "_seq", "_done", "_res", "rows")
 
-    def __init__(self, pipe: "DispatchPipeline", seq: int):
+    def __init__(self, pipe: "DispatchPipeline", seq: int, rows=None):
         self._pipe = pipe
         self._seq = seq
         self._done = False
         self._res = None
+        self.rows = rows
 
     @property
     def seq(self) -> int:
@@ -169,7 +173,9 @@ class DispatchPipeline:
         if tr:
             obs.spans.record(tr, "pipeline.enqueue", t0, obs.spans.now_ns(),
                              n=n, note=f"seq={seq}")
-        return PipelinedVerdicts(self, seq)
+        # getattr: a caller may have wrapped entry_batch_nowait with a
+        # handle of its own (the benchmark's tests do)
+        return PipelinedVerdicts(self, seq, getattr(handle, "rows", None))
 
     # ------------------------------------------------------------------
     # settlement

@@ -67,6 +67,19 @@ class ColdEntry:
     # carries it over — the table is cumulative-forever, not windowed.
     rt_hist: Optional[np.ndarray] = None
 
+    def rolling_totals(self, buckets: int,
+                       now_idx: int) -> Tuple[np.ndarray, float]:
+        """What ``stats.window.rolling_totals`` / ``rt_totals`` would read
+        from this entry's row at ``now_idx`` had it stayed resident →
+        (int[E] event totals over the live second buckets, their RT sum).
+        A bucket is live while ``0 <= now_idx - stamp < buckets`` (int32
+        wraparound-safe, as on the device)."""
+        delta = np.int32(now_idx) - self.sec_stamps
+        live = (delta >= 0) & (delta < buckets)
+        rt = float(self.sec_rt_sum[live].sum()) if self.sec_rt_sum.size \
+            else 0.0
+        return self.sec_counters[live].sum(axis=0), rt
+
 
 def settle_entry_np(buckets: int, entry: ColdEntry, now_idx: int,
                     event: int) -> None:
@@ -152,6 +165,11 @@ class ColdTier:
     def pop(self, name: str) -> Optional[ColdEntry]:
         with self._lock:
             return self._entries.pop(name, None)
+
+    def get(self, name: str) -> Optional[ColdEntry]:
+        """The entry, left where it is (by-name reads of a cold key)."""
+        with self._lock:
+            return self._entries.get(name)
 
     def convert_geometry(self, buckets: int) -> None:
         """Cold-reset every entry's second windows + booking ring to a

@@ -61,6 +61,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from sentinel_tpu.core.batching import pad_pow2
 from sentinel_tpu.core.pending import start_host_copy
 from sentinel_tpu.core.registry import ENTRY_NODE_ROW
 from sentinel_tpu.obs import counters as obs_keys
@@ -128,21 +129,8 @@ def tiering_disabled() -> bool:
 @functools.lru_cache(maxsize=None)
 def _jit_extract(spec):
     from sentinel_tpu.engine.pipeline import extract_resource_rows
-    return jax.jit(functools.partial(extract_resource_rows, spec))
-
-
-@functools.lru_cache(maxsize=None)
-def _jit_restore(spec):
-    from sentinel_tpu.engine.pipeline import restore_resource_rows
-    return jax.jit(functools.partial(restore_resource_rows, spec))
-
-
-def _pad_pow2(n: int) -> int:
-    # pow2 padding keeps the extract/restore jit cache bounded per spec
-    p = 1
-    while p < n:
-        p <<= 1
-    return p
+    from sentinel_tpu.runtime import named_partial
+    return jax.jit(named_partial("tier_extract", extract_resource_rows, spec))
 
 
 class TierManager:
@@ -208,8 +196,9 @@ class TierManager:
         each). Resident name → ``tier.hot_hit``; name the cold tier (or
         an in-flight demote) knows → ``tier.cold_miss`` + queued
         promotion; first-sight name → neither (a brand-new key is not a
-        *miss* of anything — see the hit-rate note in OPERATIONS.md).
-        Both counters add the name's OCCURRENCES. O(distinct names)
+        *miss* of anything — see the hit-rate note in OPERATIONS.md) but
+        one ``tier.first_sight``, per NAME. The two classification
+        counters add the name's OCCURRENCES. O(distinct names)
         Python, nothing per occurrence. ``tick=False`` (rule-load pin
         paths, runtime._update_rule_pins_locked) keeps the shadow map
         and promotion queue exact without counting control-plane interns
@@ -220,7 +209,7 @@ class TierManager:
             rows = rows.tolist()        # Python ints: no NumPy scalars below
         counts = (itertools.repeat(1) if counts is None
                   else np.asarray(counts).tolist())
-        hot = cold = 0
+        hot = cold = first = 0
         with self._lock:
             shadow = self._shadow
             # Two passes so classification cannot depend on intra-batch
@@ -250,11 +239,15 @@ class TierManager:
                             or name in demoting):
                         self._pending_promote[name] = row
                         cold += cnt
+                    else:
+                        first += 1
         if tick and self._obs.enabled:
             if hot:
                 self._obs.counters.add(obs_keys.TIER_HOT_HIT, hot)
             if cold:
                 self._obs.counters.add(obs_keys.TIER_COLD_MISS, cold)
+            if first:
+                self._obs.counters.add(obs_keys.TIER_FIRST_SIGHT, first)
 
     def note_hot_hits(self, n: int) -> None:
         """Frontend name→row cache hits: resident by construction (the
@@ -359,8 +352,10 @@ class TierManager:
                 alt_ids.append((vi, ident[0], ident[1]))
                 alt_slots.append(slot)
         k = len(victims)
-        kp = _pad_pow2(k)
-        ka = _pad_pow2(len(alt_slots)) if alt_slots else 1
+        # the invalidate's padding (Sentinel._pad), so one size warms all
+        # three migration programs (warm_migration)
+        kp = pad_pow2(k)
+        ka = pad_pow2(len(alt_slots))
         rows_arr = np.full(kp, sn.spec.rows, np.int32)    # pad → dropped
         rows_arr[:k] = [r for _n, r in victims]
         alt_arr = np.full(ka, sn.spec.alt_rows, np.int32)
@@ -381,7 +376,7 @@ class TierManager:
         if self._obs.enabled:
             self._obs.counters.add(obs_keys.TIER_DEMOTED, k)
         if force:
-            self._land_all()
+            self._land_all(inline=True)
         if self._demote_listeners:
             names = [n for n, _r in victims]
             for fn in self._demote_listeners:
@@ -402,6 +397,12 @@ class TierManager:
                 return
             todo = list(self._pending_promote.items())
             self._pending_promote.clear()
+        with self._obs.phase("tier.promote", n=len(todo)) as phase:
+            phase.n = self._promote_locked(todo)
+
+    def _promote_locked(self, todo: List[Tuple[str, int]]) -> int:
+        """→ rows restored (≤ ``len(todo)``: a row recycled again before
+        this drain, or an entry the bounded cold tier dropped, is not)."""
         sn = self._sentinel
         t0 = time.monotonic_ns()
         entries: List[Tuple[str, int, ColdEntry]] = []
@@ -419,7 +420,7 @@ class TierManager:
                 # promote below would then pop a missing entry and
                 # silently serve a zeroed row; _land_one's per-rec
                 # lock instead blocks until the in-flight land is done
-                self._land_one(pend)
+                self._land_one(pend, inline=True)
             entry = self.cold.pop(name)
             if entry is None:
                 continue            # dropped (bounded cold tier)
@@ -439,32 +440,34 @@ class TierManager:
                 settle_entry_np(sn.spec.second.buckets, entry, idx, ev.PASS)
             entries.append((name, row, entry))
         if not entries:
-            return
+            return 0
         self._restore_locked(entries)
         if self._obs.enabled:
             self._obs.counters.add(obs_keys.TIER_PROMOTED, len(entries))
         self.migration_hist.record(time.monotonic_ns() - t0)
+        return len(entries)
 
-    def _restore_locked(self, entries) -> None:
-        """One jitted scatter for the whole promote batch."""
+    def _restore_locked(self, entries, rows: int = 0) -> None:
+        """One jitted scatter for the whole promote batch, padded to a
+        power of two (``rows``: at least that many — the warm-up's, with
+        no entry at all)."""
         from sentinel_tpu.engine.pipeline import ResourceRowSlice
         from sentinel_tpu.runtime import _alt_hash
         from sentinel_tpu.stats.window import WindowState
         sn = self._sentinel
-        spec = sn.spec
-        k = len(entries)
-        kp = _pad_pow2(k)
+        spec, st = sn.spec, sn._state
+        kp = pad_pow2(max(len(entries), rows))
         B = spec.second.buckets
-        e0 = entries[0][2]
-        ne = e0.sec_counters.shape[-1]
-        brt = e0.sec_rt_sum.shape[0]
-        mb, mbrt = e0.min_stamps.shape[0], e0.min_rt_sum.shape[0]
+        ne = st.second.counters.shape[-1]
+        brt = st.second.rt_sum.shape[1]
+        # minute ring disabled: the demotion's placeholder slice, ignored
+        mb, mbrt = st.minute.stamps.shape[1], st.minute.rt_sum.shape[1]
         sec_c = np.zeros((kp, B, ne), np.int32)
         sec_s = np.full((kp, B), NEVER, np.int32)
         sec_rt = np.zeros((kp, brt), np.float32)
         sec_mr = np.full((kp, brt), _I32MAX, np.int32)
-        min_c = np.zeros((kp, max(mb, 1), ne), np.int32)
-        min_s = np.full((kp, max(mb, 1)), NEVER, np.int32)
+        min_c = np.zeros((kp, mb, ne), np.int32)
+        min_s = np.full((kp, mb), NEVER, np.int32)
         min_rt = np.zeros((kp, mbrt), np.float32)
         min_mr = np.full((kp, mbrt), _I32MAX, np.int32)
         thr = np.zeros(kp, np.int32)
@@ -482,7 +485,7 @@ class TierManager:
             rows_arr[i] = row
             sec_c[i], sec_s[i] = e.sec_counters, e.sec_stamps
             sec_rt[i], sec_mr[i] = e.sec_rt_sum, e.sec_min_rt
-            if mb:
+            if spec.minute:
                 min_c[i], min_s[i] = e.min_counters, e.min_stamps
                 min_rt[i], min_mr[i] = e.min_rt_sum, e.min_min_rt
             thr[i] = e.threads
@@ -499,7 +502,7 @@ class TierManager:
                     slots.add(slot)
                 alt_rows.append(slot)
                 alt_payload.append(alt)
-        ka = _pad_pow2(len(alt_rows)) if alt_rows else 1
+        ka = pad_pow2(len(alt_rows))
         alt_arr = np.full(ka, spec.alt_rows, np.int32)
         alt_c = np.zeros((ka, B, ne), np.int32)
         alt_s = np.full((ka, B), NEVER, np.int32)
@@ -520,7 +523,9 @@ class TierManager:
                                    jnp.asarray(alt_rt), jnp.asarray(alt_mr)),
             alt_threads=jnp.asarray(alt_thr),
             rt_hist=jnp.asarray(rt_h) if rt_h is not None else None)
-        sn._state = _jit_restore(spec)(
+        # the engine's own step: donated, and on a mesh pinned to the
+        # state's shardings (runtime._build_steps)
+        sn._state = sn._jit_restore(
             sn._state, jnp.asarray(rows_arr), payload, jnp.asarray(alt_arr))
 
     def on_rules_reloaded_locked(self, now_idx: int) -> None:
@@ -553,7 +558,7 @@ class TierManager:
             recs = list({id(r): r for r in
                          self._pending_land.values()}.values())
         for rec in recs:
-            self._land_one(rec)
+            self._land_one(rec, inline=True)
         with self._lock:
             self._land_q.clear()    # all landed (or marked) above
             self._reload_idxs.clear()
@@ -561,15 +566,16 @@ class TierManager:
 
     # ---- landing (tiering thread / forced) ----------------------------
 
-    def _land_all(self) -> int:
+    def _land_all(self, inline: bool = False) -> int:
         with self._lock:
             batch = list(self._land_q)
             self._land_q.clear()
         for rec in batch:
-            self._land_one(rec)
+            self._land_one(rec, inline)
         return len(batch)
 
-    def _land_one(self, rec) -> None:
+    def _land_one(self, rec, inline: bool = False) -> None:
+        """``inline``: the engine side is landing it, under its lock."""
         # per-rec lock: the engine side (post_invalidate_locked,
         # on_geometry_changed_locked) may force-land a rec the tiering
         # thread has already dequeued from _land_q — whoever arrives
@@ -577,11 +583,16 @@ class TierManager:
         # then no-ops, so a force-land always leaves the entry visible
         # to the cold.pop that follows it
         with rec["lock"]:
-            self._land_one_held(rec)
+            if rec["landed"]:
+                return
+            k = len(rec["victims"])
+            with self._obs.phase("tier.land", n=k,
+                                 note="inline=1" if inline else ""):
+                self._land_one_held(rec)
+            if inline and self._obs.enabled:
+                self._obs.counters.add(obs_keys.TIER_LAND_INLINE, k)
 
     def _land_one_held(self, rec) -> None:
-        if rec["landed"]:
-            return
         p = rec["payload"]
         sec = tuple(np.asarray(x) for x in p.second)
         mnt = tuple(np.asarray(x) for x in p.minute)
@@ -754,6 +765,42 @@ class TierManager:
             pass
 
     # ---- read surface -------------------------------------------------
+
+    def cold_entry(self, name: str) -> Optional[ColdEntry]:
+        """A demoted name's state, left in the cold tier (by-name reads:
+        ``Sentinel.node_totals`` / ``rt_hist_by_name``); a demote payload
+        still in flight is landed first. None for a resident or unknown
+        name."""
+        if not self.enabled:
+            return None
+        with self._lock:
+            pend = self._pending_land.get(name)
+        if pend is not None:
+            self._land_one(pend)
+        return self.cold.get(name)
+
+    def warm_migration(self, sizes) -> None:
+        """Compile the three migration programs (the demotion's gather,
+        the invalidate, the promotion's scatter) for evictions and
+        promotions of up to each of ``sizes`` rows a drain — they are
+        padded to powers of two — before traffic, as a service warms its
+        exit sizes: a drain that meets a new size otherwise compiles under
+        the engine lock, in the middle of a decide. Pad rows only: the
+        state is rewritten with what it held."""
+        if not self.enabled:
+            return
+        sn = self._sentinel
+        spec = sn.spec
+        no_alt = np.full(pad_pow2(0), spec.alt_rows, np.int32)
+        for kp in sorted({pad_pow2(int(k)) for k in sizes}):
+            pad_rows = np.full(kp, spec.rows, np.int32)
+            with sn._lock:
+                _jit_extract(spec)(sn._state, jnp.asarray(pad_rows),
+                                   jnp.asarray(no_alt))
+                sn._state = sn._jit_invalidate(
+                    sn._state, jnp.asarray(pad_rows), jnp.asarray(no_alt))
+                self._restore_locked([], rows=kp)
+                jax.block_until_ready(sn._state)
 
     def snapshot(self) -> Dict:
         """The serving-bench artifact / transport-command body."""

@@ -448,40 +448,43 @@ def test_catalog_vector_roundtrip():
     assert ck.vector_counts(longer) == back
 
 
-def test_catalog_is_append_only_with_pr32_keys_last():
+def test_catalog_is_append_only_with_pr33_keys_last():
     """The multihost allgather aggregates CATALOG by POSITION (prefix
     compatibility with older peers), so the catalog may only ever grow at
-    the tail. Pin the newest (PR 32 batch-dedup) keys to the end, with
-    the PR 26 cluster-server cycle, round-20 resource-histogram, round-17
+    the tail. Pin the newest (PR 33 tier first-sight / inline-landing)
+    keys to the end, with the PR 32 batch-dedup, PR 26 cluster-server cycle, round-20 resource-histogram, round-17
     overload-controller, round-16 single-dispatch, round-15 tiering,
     round-12 telemetry/exporter, round-11 tune, round-10 sortfree and
     round-9 mesh keys immediately above them — an insertion above any
     group (or a re-ordering) would silently mis-attribute every counter
     on a mixed-version fleet."""
-    assert ck.CATALOG[-2:] == (ck.INTERN_NAMES, ck.INTERN_DISTINCT)
+    assert ck.CATALOG[-2:] == (ck.TIER_FIRST_SIGHT, ck.TIER_LAND_INLINE) \
+        == ("tier.first_sight", "tier.land_inline")
+    catalog = ck.CATALOG[:-2]
+    assert catalog[-2:] == (ck.INTERN_NAMES, ck.INTERN_DISTINCT)
     assert (ck.INTERN_NAMES, ck.INTERN_DISTINCT) == (
         "intern.names", "intern.distinct")
-    assert ck.CATALOG[-5:-2] == (ck.CLUSTER_SERVER_CYCLES,
+    assert catalog[-5:-2] == (ck.CLUSTER_SERVER_CYCLES,
                                  ck.CLUSTER_SERVER_TAKEN,
                                  ck.CLUSTER_SERVER_QUEUE_WAIT_US)
-    assert ck.CATALOG[-7:-5] == (ck.TELEMETRY_HIST_TICK,
+    assert catalog[-7:-5] == (ck.TELEMETRY_HIST_TICK,
                                  ck.CONTROL_TAIL_SIGNAL)
-    assert ck.CATALOG[-12:-7] == (ck.CONTROL_TICK, ck.CONTROL_SHED_ACTION,
+    assert catalog[-12:-7] == (ck.CONTROL_TICK, ck.CONTROL_SHED_ACTION,
                                   ck.CONTROL_RETUNE_ACTION,
                                   ck.CONTROL_DEGRADE_ACTION,
                                   ck.CONTROL_DROPPED)
-    assert ck.CATALOG[-14:-12] == (ck.PIPE_DISPATCH,
+    assert catalog[-14:-12] == (ck.PIPE_DISPATCH,
                                    ck.ROUTE_SINGLE_DISPATCH)
-    assert ck.CATALOG[-19:-14] == (ck.TIER_HOT_HIT, ck.TIER_COLD_MISS,
+    assert catalog[-19:-14] == (ck.TIER_HOT_HIT, ck.TIER_COLD_MISS,
                                    ck.TIER_PROMOTED, ck.TIER_DEMOTED,
                                    ck.TIER_SKETCH_OVERFLOW)
-    assert ck.CATALOG[-22:-19] == (ck.TELEMETRY_TICK, ck.TELEMETRY_DROP,
+    assert catalog[-22:-19] == (ck.TELEMETRY_TICK, ck.TELEMETRY_DROP,
                                    ck.EXPORTER_LABEL_OVERFLOW)
-    assert ck.CATALOG[-27:-22] == (ck.TUNE_LOADED, ck.TUNE_FALLBACK,
+    assert catalog[-27:-22] == (ck.TUNE_LOADED, ck.TUNE_FALLBACK,
                                    ck.TUNE_KNOB_REJECTED, ck.TUNE_TRIAL,
                                    ck.TUNE_PARITY_FAIL)
-    assert ck.CATALOG[-29:-27] == (ck.ROUTE_SORTFREE, ck.SORTFREE_OVERFLOW)
-    assert ck.CATALOG[-31:-29] == (ck.ROUTE_MESHED, ck.PIPE_MESHED)
+    assert catalog[-29:-27] == (ck.ROUTE_SORTFREE, ck.SORTFREE_OVERFLOW)
+    assert catalog[-31:-29] == (ck.ROUTE_MESHED, ck.PIPE_MESHED)
     assert ck.CLUSTER_SERVER_CYCLES == "cluster.server.cycles"
     assert ck.CLUSTER_SERVER_TAKEN == "cluster.server.taken"
     assert ck.CLUSTER_SERVER_QUEUE_WAIT_US == "cluster.server.queue_wait_us"

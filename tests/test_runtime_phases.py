@@ -198,3 +198,63 @@ def test_program_key_tells_column_patterns_apart():
     assert base == program_key("decide", 1, (64,), {"a": True}, ())
     assert program_key("decide", 1, (64,), {"a": True}, (True, False)) \
         != program_key("decide", 1, (64,), {"a": True}, (True, True))
+
+
+def test_migration_is_three_phases_under_the_dispatch_that_drained(
+        clk, monkeypatch):
+    """``tier.demote`` (n = rows evicted) and ``tier.promote`` (n = rows
+    restored) are children of the ``decide.dispatch`` / ``exit.dispatch``
+    whose eviction drain ran them; ``tier.land`` (n = victims) is the
+    cold tier's landing, on whichever thread lands the record — inline on
+    the engine's when a promotion needs its payload at once."""
+    monkeypatch.setenv("SENTINEL_TPU_NATIVE", "0")
+    sph = make(clk, max_resources=16)        # ENTRY + 15 names
+    first = [f"a{i}" for i in range(15)]
+    sph.entry_batch(first)                   # the table is full
+    h = sph.entry_batch_nowait(["b0", "b1", "b2"])   # evicts a0, a1, a2
+    assert np.asarray(h.result().allow).all()
+    by = _by_name(sph.obs.spans.snapshot())
+    ids = {s["id"]: s for s in sph.obs.spans.snapshot()}
+    (demote,) = by["tier.demote"]
+    assert demote["n"] == 3
+    assert ids[demote["parent"]]["name"] == "decide.dispatch"
+    assert "tier.promote" not in by and "tier.land" not in by
+    assert sph.tiering.poll() >= 1           # the ticker's thread lands it
+    (land,) = _by_name(sph.obs.spans.snapshot())["tier.land"]
+    assert land["n"] == 3 and land["note"] == "" and land["parent"] == 0
+    assert sph.obs.counters.get(ck.TIER_LAND_INLINE) == 0
+    # a1 comes back while a3's payload (it is evicted for a1) is in flight;
+    # then an exit drains: a3 returns at once, its payload landed inline
+    sph.entry_batch(["a1"])
+    rows = np.asarray(sph.intern_resources(["a3"]), np.int32)
+    pad = np.full(1, sph.spec.alt_rows, np.int32)
+    sph.exit_batch(rows=rows, origin_rows=pad, chain_rows=pad,
+                   acquire=np.ones(1, np.int32), rt_ms=np.ones(1, np.int32),
+                   error=np.zeros(1, bool), is_in=np.ones(1, bool))
+    spans = sph.obs.spans.snapshot()
+    by, ids = _by_name(spans), {s["id"]: s for s in spans}
+    promotes = by["tier.promote"]
+    assert [p["n"] for p in promotes] == [1, 1]
+    assert [ids[p["parent"]]["name"] for p in promotes] \
+        == ["decide.dispatch", "exit.dispatch"]
+    inline = [s for s in by["tier.land"] if s["note"] == "inline=1"]
+    assert len(inline) == 1 and inline[0]["n"] == 1
+    assert inline[0]["parent"] == promotes[1]["id"]
+    assert sph.obs.counters.get(ck.TIER_LAND_INLINE) == 1
+    assert sph.obs.counters.get(ck.TIER_PROMOTED) == 2
+    assert sph.obs.counters.get(ck.TIER_DEMOTED) == 3 + 1 + 1
+    # 15 + 3 names nobody knew; a1 and a3 were cold misses
+    assert sph.obs.counters.get(ck.TIER_FIRST_SIGHT) == 18
+    assert sph.obs.counters.get(ck.TIER_COLD_MISS) == 2
+    sph.close()
+
+
+def test_the_new_tier_counters_are_in_the_catalog_and_its_manifest():
+    import os
+    manifest = os.path.join(os.path.dirname(ck.__file__),
+                            "counters_catalog.txt")
+    with open(manifest) as f:
+        names = [ln.strip() for ln in f
+                 if ln.strip() and not ln.startswith("#")]
+    assert names == list(ck.CATALOG)
+    assert {ck.TIER_FIRST_SIGHT, ck.TIER_LAND_INLINE} <= set(names)

@@ -93,6 +93,8 @@ def compiled(topo):
                                   skip_threads=True).compile()
         return steps[7].lower(state, col(i32), col(i32), col(i32), col(i32),
                               col(b), col(b), times).compile()
+    build.spec, build.state, build.steps, build.on_chip = \
+        spec, state, steps, on_chip
     yield build
     small.close()
 
@@ -118,3 +120,53 @@ def test_no_step_relays_out_the_ring(compiled, step, lanes):
     program = compiled(step, lanes)
     assert program.memory_analysis().temp_size_in_bytes < TEMP_LIMIT
     assert largest_while_operand(program) < RING
+
+
+STATE_BYTES = 3_277_086_720     # what a 1,048,576-row engine keeps on the chip
+
+
+@pytest.mark.parametrize("program", ["extract", "invalidate", "restore"])
+def test_what_the_chip_makes_of_tier_migration(compiled, program):
+    """The three migration programs at the size ``tier-16m.batch-scalar``
+    pads a batch's evictions to (8,192 rows; PR 33). The invalidate and
+    the restore rewrite the donated state in place — a restore that is
+    not donated holds the 3.3 GB state twice (ROADMAP R3, closed). What
+    the restore still pays is S1's relayout: a copy of the minute ring
+    into the scatter's layout and one back, 4.3 GB of temp — the limit
+    below is that reading's, for the ``perf_opt`` that takes it out to
+    lower."""
+    from sentinel_tpu.stats.window import WindowState
+    from sentinel_tpu.tiering import manager
+    spec, state, on_chip = compiled.spec, compiled.state, compiled.on_chip
+    i32, f32 = jnp.int32, jnp.float32
+    k, ka, B, ne, hb = 8192, 8, spec.second.buckets, 8, spec.hist_buckets
+    mb = spec.minute.buckets
+
+    def window(rows, buckets):
+        return WindowState(on_chip((rows, buckets, ne), i32),
+                           on_chip((rows, buckets), i32),
+                           on_chip((rows, buckets), f32),
+                           on_chip((rows, buckets), i32))
+    rows, alt = on_chip((k,), i32), on_chip((ka,), i32)
+    if program == "extract":
+        made = manager._jit_extract(spec).lower(state, rows, alt).compile()
+    elif program == "invalidate":
+        made = compiled.steps[6].lower(state, rows, alt).compile()
+    else:
+        payload = pipeline.ResourceRowSlice(
+            second=window(k, B), minute=window(k, mb),
+            threads=on_chip((k,), i32), occ_cnt=on_chip((k, B + 1), f32),
+            occ_win=on_chip((k, B + 1), i32), alt_second=window(ka, B),
+            alt_threads=on_chip((ka,), i32), rt_hist=on_chip((k, hb), i32))
+        made = compiled.steps[8].lower(state, rows, payload, alt).compile()
+    memory = made.memory_analysis()
+    assert "jit_tier_" + program in made.as_text()[:200]   # its trace name
+    if program == "extract":
+        # fresh buffers (it is read back while later steps donate the state)
+        assert memory.alias_size_in_bytes == 0
+        assert memory.temp_size_in_bytes < 1 << 30
+    else:
+        assert memory.alias_size_in_bytes >= STATE_BYTES
+        limit = TEMP_LIMIT if program == "invalidate" else 5 << 30
+        assert memory.temp_size_in_bytes < limit
+
