@@ -194,6 +194,14 @@ TIER_SKETCH_OVERFLOW = "tier.sketch_overflow"
 # instead of on the tiering thread.
 TIER_FIRST_SIGHT = "tier.first_sight"
 TIER_LAND_INLINE = "tier.land_inline"
+# PR 34 — a demotion record lands as one columnar block and promotion
+# gathers from the blocks, so no per-name object exists on the serving
+# path; ``materialized`` counts the ``ColdEntry`` objects that WERE
+# built from a block row — a by-name read of a cold key
+# (``TierManager.cold_entry``) or the replay of a flow-rule reload a
+# key slept through. The slow form: it should stand still under
+# traffic that neither reads cold keys by name nor reloads rules.
+TIER_MATERIALIZED = "tier.materialized"
 
 # ``pipeline.dispatches`` counts DEVICE DISPATCHES issued by the
 # serving hot path and its tickers (decide = 1, split = 2, exit = 1, a
@@ -296,6 +304,7 @@ CATALOG = (
     CLUSTER_SERVER_QUEUE_WAIT_US,
     INTERN_NAMES, INTERN_DISTINCT,
     TIER_FIRST_SIGHT, TIER_LAND_INLINE,
+    TIER_MATERIALIZED,
 )
 
 

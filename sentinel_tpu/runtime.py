@@ -3297,8 +3297,8 @@ class Sentinel:
     def rt_hist_by_name(self, resources: Sequence[str]) -> np.ndarray:
         """The cumulative RT histogram (``int32[n, HB]``, the buckets of
         obs/resource_hist.py) of each name, whichever tier holds it: a
-        resident name's row of ``state.rt_hist``, a demoted name's cold
-        entry, zeros for a name neither tier knows (or with the table
+        resident name's row of ``state.rt_hist``, a demoted name's row of
+        its cold block, zeros for a name neither tier knows (or with the table
         disabled). Pending evictions and promotions are applied first, so
         the rows the registry names hold their owners' state; the engine
         lock is held for that and for the dispatch of a copy of the table
@@ -3319,10 +3319,10 @@ class Sentinel:
             np.int64, count=len(resources))
         hot = rows >= 0
         out[hot] = np.asarray(table)[rows[hot]]
-        for i in np.nonzero(~hot)[0].tolist():
-            entry = self.tiering.cold_entry(resources[i])
-            if entry is not None and entry.rt_hist is not None:
-                out[i] = entry.rt_hist
+        cold = np.nonzero(~hot)[0]
+        if cold.size:
+            out[cold] = self.tiering.cold_rt_hist(
+                [resources[i] for i in cold.tolist()], hb)
         return out
 
     def get_flow_rules(self) -> List[flow_mod.FlowRule]:

@@ -21,7 +21,7 @@ See docs/OPERATIONS.md "Tiered resource state (round 15)" for the
 operational runbook and the slow-path caveat.
 """
 
-from sentinel_tpu.tiering.coldtier import ColdEntry, ColdTier
+from sentinel_tpu.tiering.coldtier import ColdBlock, ColdEntry, ColdTier
 from sentinel_tpu.tiering.manager import (
     HOT_ROWS_ENV, SKETCH_BITS_ENV, SKETCH_ROWS_ENV, TIER_TICK_MS_ENV,
     TIERING_DISABLE_ENV, TierManager, tier_hot_rows, tier_sketch_bits,
@@ -32,7 +32,7 @@ from sentinel_tpu.tiering.sketch import (
 )
 
 __all__ = [
-    "ColdEntry", "ColdTier", "TierManager",
+    "ColdBlock", "ColdEntry", "ColdTier", "TierManager",
     "HOT_ROWS_ENV", "SKETCH_BITS_ENV", "SKETCH_ROWS_ENV",
     "TIER_TICK_MS_ENV", "TIERING_DISABLE_ENV",
     "tier_hot_rows", "tier_sketch_bits", "tier_sketch_rows",

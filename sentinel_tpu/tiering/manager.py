@@ -8,15 +8,19 @@ reverts to the pre-round-15 lossy eviction):
   ticker's proactive ``evict_name``), the engine's eviction drain FIRST
   dispatches a jitted gather of the row's complete state
   (``engine.pipeline.extract_resource_rows`` — fresh output buffers,
-  dispatch-only under the engine lock) and queues it; the tiering
-  thread lands it into the :class:`~sentinel_tpu.tiering.coldtier.ColdTier`
-  off-lock. THEN the usual invalidate runs. ``tier.demoted`` ticks.
+  dispatch-only under the engine lock) and queues it as ONE record.
+  THEN the usual invalidate runs. ``tier.demoted`` ticks. The record
+  LANDS — its host arrays become one
+  :class:`~sentinel_tpu.tiering.coldtier.ColdBlock`, its names index
+  into it; nothing is built per victim — on the tiering thread, or
+  inline when a promotion of the same drain needs one of its names.
 * **cold** — host memory only; unbounded cardinality.
 * **promotion (the documented slow path)** — when a cold key is
   interned again, the NEXT eviction drain (which runs under the engine
   lock before every decide) scatters the cold payload back into the
-  freshly allocated row (``restore_resource_rows``), after replaying
-  any flow-rule reloads the key slept through
+  freshly allocated row (``restore_resource_rows``) — gathered from
+  the blocks column by column — after replaying any flow-rule reloads
+  the key slept through
   (:func:`~sentinel_tpu.tiering.coldtier.settle_entry_np`). The decide
   that triggered the intern therefore sees the row EXACTLY as if it had
   never left the device — verdict bit-parity is by construction
@@ -68,7 +72,9 @@ from sentinel_tpu.obs import counters as obs_keys
 from sentinel_tpu.obs.hist import LogHistogram
 from sentinel_tpu.stats import events as ev
 from sentinel_tpu.tiering import sketch as sk
-from sentinel_tpu.tiering.coldtier import ColdEntry, ColdTier, settle_entry_np
+from sentinel_tpu.tiering.coldtier import (
+    ColdBlock, ColdEntry, ColdTier, fresh_window, settle_entry_np,
+)
 
 HOT_ROWS_ENV = "SENTINEL_HOT_ROWS"
 SKETCH_BITS_ENV = "SENTINEL_SKETCH_BITS"
@@ -402,125 +408,155 @@ class TierManager:
 
     def _promote_locked(self, todo: List[Tuple[str, int]]) -> int:
         """→ rows restored (≤ ``len(todo)``: a row recycled again before
-        this drain, or an entry the bounded cold tier dropped, is not)."""
+        this drain, or a name the bounded cold tier dropped, is not)."""
         sn = self._sentinel
         t0 = time.monotonic_ns()
-        entries: List[Tuple[str, int, ColdEntry]] = []
-        for name, row in todo:
-            with self._lock:
-                if self._shadow.get(row) != name:
-                    # row recycled again before this drain; the entry
-                    # stays cold for the next intern of the name
-                    continue
-                pend = self._pending_land.get(name)
-            if pend is not None:
-                # force-land THIS rec directly — a queue-level
-                # _land_all would no-op if the tiering thread already
-                # dequeued it but hasn't finished landing, and the
-                # promote below would then pop a missing entry and
-                # silently serve a zeroed row; _land_one's per-rec
-                # lock instead blocks until the in-flight land is done
-                self._land_one(pend, inline=True)
-            entry = self.cold.pop(name)
-            if entry is None:
-                continue            # dropped (bounded cold tier)
-            if entry.sec_counters.shape[0] != sn.spec.second.buckets:
+        with self._lock:
+            # a row recycled again before this drain is skipped; its
+            # state stays cold for the next intern of the name
+            todo = [(n, r) for n, r in todo if self._shadow.get(r) == n]
+            pending = self._pending_recs_locked(n for n, _r in todo)
+            reloads = list(self._reload_idxs)
+        for rec in pending:
+            # force-land THIS rec directly — a queue-level _land_all
+            # would no-op if the tiering thread already dequeued it but
+            # hasn't finished landing, and the pop below would then miss
+            # the name and silently serve a zeroed row; _land_one's
+            # per-rec lock instead blocks until the in-flight land is
+            # done
+            self._land_one(rec, inline=True)
+        rows = np.fromiter((r for _n, r in todo), np.int32, len(todo))
+        B = sn.spec.second.buckets
+        parts: List[Tuple[ColdBlock, np.ndarray, np.ndarray]] = []
+        replayed = 0
+        for block, src, js in self.cold.pop_rows([n for n, _r in todo]):
+            if block.second[1].shape[1] != B:
                 # extracted under a previous window geometry and missed
                 # by the geometry-change conversion (a straggler that
                 # landed after it): restoring would scatter mismatched
                 # shapes, and the cold-reset semantic says its second
-                # windows are void anyway — drop; the key re-enters
+                # windows are void anyway — drop; the keys re-enter
                 # fresh, exactly like a resident row post-change
                 continue
-            # replay the flow reloads this key slept through, each with
-            # THAT reload's now_idx — bit-parity with the resident settle
-            with self._lock:
-                idxs = self._reload_idxs[entry.reload_gen:]
-            for idx in idxs:
-                settle_entry_np(sn.spec.second.buckets, entry, idx, ev.PASS)
-            entries.append((name, row, entry))
-        if not entries:
+            missed = reloads[block.reload_gen:]
+            if not missed:
+                parts.append((block, src, rows[js]))
+                continue
+            # replay the flow reloads these keys slept through, each with
+            # THAT reload's now_idx — bit-parity with the resident
+            # settle; per name on a materialised row (no cell reloads
+            # rules while names are cold)
+            for i, j in zip(src.tolist(), js.tolist()):
+                entry = block.entry(i)
+                for idx in missed:
+                    settle_entry_np(B, entry, idx, ev.PASS)
+                parts.append((ColdBlock.of_entry(entry),
+                              np.zeros(1, np.int64), rows[j:j + 1]))
+            replayed += src.size
+        self._note_materialized(replayed)
+        n = sum(r.size for _b, _s, r in parts)
+        if not n:
             return 0
-        self._restore_locked(entries)
+        self._restore_locked(parts)
         if self._obs.enabled:
-            self._obs.counters.add(obs_keys.TIER_PROMOTED, len(entries))
+            self._obs.counters.add(obs_keys.TIER_PROMOTED, n)
         self.migration_hist.record(time.monotonic_ns() - t0)
-        return len(entries)
+        return n
 
-    def _restore_locked(self, entries, rows: int = 0) -> None:
+    def _pending_recs_locked(self, names) -> list:
+        """Manager lock held: the demote records still in flight that
+        hold any of ``names``, each once."""
+        if not self._pending_land:
+            return []
+        return list({id(rec): rec for rec in
+                     map(self._pending_land.get, names)
+                     if rec is not None}.values())
+
+    def _note_materialized(self, n: int) -> None:
+        if n and self._obs.enabled:
+            self._obs.counters.add(obs_keys.TIER_MATERIALIZED, n)
+
+    def _restore_locked(self, parts, rows: int = 0) -> None:
         """One jitted scatter for the whole promote batch, padded to a
         power of two (``rows``: at least that many — the warm-up's, with
-        no entry at all)."""
+        no part at all). ``parts``: ``(block, src, dst)`` — rows ``src``
+        of ``block`` go to table rows ``dst``; each payload column is
+        filled with one gather per block."""
         from sentinel_tpu.engine.pipeline import ResourceRowSlice
         from sentinel_tpu.runtime import _alt_hash
         from sentinel_tpu.stats.window import WindowState
         sn = self._sentinel
         spec, st = sn.spec, sn._state
-        kp = pad_pow2(max(len(entries), rows))
+        kp = pad_pow2(max(sum(d.size for _b, _s, d in parts), rows))
         B = spec.second.buckets
         ne = st.second.counters.shape[-1]
         brt = st.second.rt_sum.shape[1]
         # minute ring disabled: the demotion's placeholder slice, ignored
         mb, mbrt = st.minute.stamps.shape[1], st.minute.rt_sum.shape[1]
-        sec_c = np.zeros((kp, B, ne), np.int32)
-        sec_s = np.full((kp, B), NEVER, np.int32)
-        sec_rt = np.zeros((kp, brt), np.float32)
-        sec_mr = np.full((kp, brt), _I32MAX, np.int32)
-        min_c = np.zeros((kp, mb, ne), np.int32)
-        min_s = np.full((kp, mb), NEVER, np.int32)
-        min_rt = np.zeros((kp, mbrt), np.float32)
-        min_mr = np.full((kp, mbrt), _I32MAX, np.int32)
+        second = fresh_window(kp, B, ne, brt)
+        minute = fresh_window(kp, mb, ne, mbrt)
         thr = np.zeros(kp, np.int32)
         occ_c = np.zeros((kp, B + 1), np.float32)
         occ_w = np.full((kp, B + 1), NEVER, np.int32)
         hb = spec.hist_buckets
-        # zeros for entries that predate the histogram table (a cold
-        # entry demoted before the feature was enabled restores with an
-        # empty — not stale — tail view)
+        # zeros for rows that predate the histogram table (a row demoted
+        # before the feature was enabled restores with an empty — not
+        # stale — tail view)
         rt_h = np.zeros((kp, hb), np.int32) if hb else None
         rows_arr = np.full(kp, spec.rows, np.int32)
-        alt_rows: List[int] = []
-        alt_payload: List[tuple] = []
-        for i, (_name, row, e) in enumerate(entries):
-            rows_arr[i] = row
-            sec_c[i], sec_s[i] = e.sec_counters, e.sec_stamps
-            sec_rt[i], sec_mr[i] = e.sec_rt_sum, e.sec_min_rt
+        alt_slots: List[int] = []
+        alt_parts: List[Tuple[ColdBlock, List[int]]] = []
+        at = 0
+        for block, src, dst in parts:
+            to = slice(at, at + dst.size)
+            at = to.stop
+            rows_arr[to] = dst
+            for out, col in zip(second, block.second):
+                out[to] = col[src]
             if spec.minute:
-                min_c[i], min_s[i] = e.min_counters, e.min_stamps
-                min_rt[i], min_mr[i] = e.min_rt_sum, e.min_min_rt
-            thr[i] = e.threads
-            occ_c[i], occ_w[i] = e.occ_cnt, e.occ_win
-            if rt_h is not None and e.rt_hist is not None \
-                    and e.rt_hist.shape[0] == hb:
-                rt_h[i] = e.rt_hist
-            for (kind, key_id), alt in e.alts.items():
+                for out, col in zip(minute, block.minute):
+                    out[to] = col[src]
+            thr[to] = block.threads[src]
+            occ_c[to], occ_w[to] = block.occ_cnt[src], block.occ_win[src]
+            if rt_h is not None and block.rt_hist is not None \
+                    and block.rt_hist.shape[1] == hb:
+                rt_h[to] = block.rt_hist[src]
+            if not block.alt_ids:
+                continue
+            # alt slices re-hash onto the new rows' slots
+            row_of = dict(zip(src.tolist(), dst.tolist()))
+            alt_src: List[int] = []
+            for j, (vi, kind, key_id) in enumerate(block.alt_ids):
+                row = row_of.get(vi)
+                if row is None:
+                    continue
                 slot = _alt_hash(row, kind, key_id, spec.alt_rows)
                 slots = sn._alt_rows_by_row.setdefault(row, {})
                 if isinstance(slots, dict):
                     slots[slot] = (kind, key_id)
                 else:
                     slots.add(slot)
-                alt_rows.append(slot)
-                alt_payload.append(alt)
-        ka = pad_pow2(len(alt_rows))
+                alt_slots.append(slot)
+                alt_src.append(j)
+            alt_parts.append((block, alt_src))
+        ka = pad_pow2(len(alt_slots))
         alt_arr = np.full(ka, spec.alt_rows, np.int32)
-        alt_c = np.zeros((ka, B, ne), np.int32)
-        alt_s = np.full((ka, B), NEVER, np.int32)
-        alt_rt = np.zeros((ka, brt), np.float32)
-        alt_mr = np.full((ka, brt), _I32MAX, np.int32)
+        alt_arr[:len(alt_slots)] = alt_slots
+        alt_second = fresh_window(ka, B, ne, brt)
         alt_thr = np.zeros(ka, np.int32)
-        for j, alt in enumerate(alt_payload):
-            alt_arr[j] = alt_rows[j]
-            alt_c[j], alt_s[j], alt_rt[j], alt_mr[j], alt_thr[j] = alt
+        at = 0
+        for block, alt_src in alt_parts:
+            to = slice(at, at + len(alt_src))
+            at = to.stop
+            for out, col in zip(alt_second, block.alt_second):
+                out[to] = col[alt_src]
+            alt_thr[to] = block.alt_threads[alt_src]
         payload = ResourceRowSlice(
-            second=WindowState(jnp.asarray(sec_c), jnp.asarray(sec_s),
-                               jnp.asarray(sec_rt), jnp.asarray(sec_mr)),
-            minute=WindowState(jnp.asarray(min_c), jnp.asarray(min_s),
-                               jnp.asarray(min_rt), jnp.asarray(min_mr)),
+            second=WindowState(*map(jnp.asarray, second)),
+            minute=WindowState(*map(jnp.asarray, minute)),
             threads=jnp.asarray(thr),
             occ_cnt=jnp.asarray(occ_c), occ_win=jnp.asarray(occ_w),
-            alt_second=WindowState(jnp.asarray(alt_c), jnp.asarray(alt_s),
-                                   jnp.asarray(alt_rt), jnp.asarray(alt_mr)),
+            alt_second=WindowState(*map(jnp.asarray, alt_second)),
             alt_threads=jnp.asarray(alt_thr),
             rt_hist=jnp.asarray(rt_h) if rt_h is not None else None)
         # the engine's own step: donated, and on a mesh pinned to the
@@ -579,9 +615,9 @@ class TierManager:
         # per-rec lock: the engine side (post_invalidate_locked,
         # on_geometry_changed_locked) may force-land a rec the tiering
         # thread has already dequeued from _land_q — whoever arrives
-        # second blocks until the first fully lands (cold.put done),
-        # then no-ops, so a force-land always leaves the entry visible
-        # to the cold.pop that follows it
+        # second blocks until the first fully lands (put_block done),
+        # then no-ops, so a force-land always leaves every name visible
+        # to the pop_rows that follows it
         with rec["lock"]:
             if rec["landed"]:
                 return
@@ -593,35 +629,28 @@ class TierManager:
                 self._obs.counters.add(obs_keys.TIER_LAND_INLINE, k)
 
     def _land_one_held(self, rec) -> None:
+        """The record's host arrays become ONE block and its names index
+        into it: no copy and no object per victim, one acquisition of
+        the cold tier's lock and one of the manager's."""
         p = rec["payload"]
-        sec = tuple(np.asarray(x) for x in p.second)
-        mnt = tuple(np.asarray(x) for x in p.minute)
-        threads = np.asarray(p.threads)
-        occ_c, occ_w = np.asarray(p.occ_cnt), np.asarray(p.occ_win)
-        alt_sec = tuple(np.asarray(x) for x in p.alt_second)
-        alt_thr = np.asarray(p.alt_threads)
-        rh = np.asarray(p.rt_hist) if p.rt_hist is not None else None
-        for vi, (name, _row) in enumerate(rec["victims"]):
-            alts = {}
-            for j, (avi, kind, key_id) in enumerate(rec["alt_ids"]):
-                if avi == vi:
-                    alts[(kind, key_id)] = (
-                        alt_sec[0][j].copy(), alt_sec[1][j].copy(),
-                        alt_sec[2][j].copy(), alt_sec[3][j].copy(),
-                        int(alt_thr[j]))
-            entry = ColdEntry(
-                sec_counters=sec[0][vi].copy(), sec_stamps=sec[1][vi].copy(),
-                sec_rt_sum=sec[2][vi].copy(), sec_min_rt=sec[3][vi].copy(),
-                min_counters=mnt[0][vi].copy(), min_stamps=mnt[1][vi].copy(),
-                min_rt_sum=mnt[2][vi].copy(), min_min_rt=mnt[3][vi].copy(),
-                threads=int(threads[vi]),
-                occ_cnt=occ_c[vi].copy(), occ_win=occ_w[vi].copy(),
-                alts=alts, reload_gen=rec["gen"], demoted_ms=rec["now_ms"],
-                rt_hist=rh[vi].copy() if rh is not None else None)
-            self.cold.put(name, entry)
-            with self._lock:
-                if self._pending_land.get(name) is rec:
-                    del self._pending_land[name]
+        block = ColdBlock(
+            second=tuple(np.asarray(x) for x in p.second),
+            minute=tuple(np.asarray(x) for x in p.minute),
+            threads=np.asarray(p.threads),
+            occ_cnt=np.asarray(p.occ_cnt), occ_win=np.asarray(p.occ_win),
+            rt_hist=None if p.rt_hist is None else np.asarray(p.rt_hist),
+            alt_second=tuple(np.asarray(x) for x in p.alt_second),
+            alt_threads=np.asarray(p.alt_threads),
+            alt_ids=rec["alt_ids"],
+            reload_gen=rec["gen"], demoted_ms=rec["now_ms"])
+        names = [name for name, _row in rec["victims"]]
+        self.cold.put_block(block, names)
+        with self._lock:
+            pending = self._pending_land
+            for name in names:
+                if pending.get(name) is rec:
+                    del pending[name]
+        rec["payload"] = None       # the block holds the host arrays now
         rec["landed"] = True
 
     # ---- ticker -------------------------------------------------------
@@ -767,17 +796,34 @@ class TierManager:
     # ---- read surface -------------------------------------------------
 
     def cold_entry(self, name: str) -> Optional[ColdEntry]:
-        """A demoted name's state, left in the cold tier (by-name reads:
-        ``Sentinel.node_totals`` / ``rt_hist_by_name``); a demote payload
-        still in flight is landed first. None for a resident or unknown
-        name."""
+        """A demoted name's state as an entry of its own, the row left
+        in the cold tier (a by-name read: ``Sentinel.node_totals``); a
+        demote payload still in flight is landed first. None for a
+        resident or unknown name. ``tier.materialized`` ticks."""
         if not self.enabled:
             return None
         with self._lock:
             pend = self._pending_land.get(name)
         if pend is not None:
             self._land_one(pend)
-        return self.cold.get(name)
+        entry = self.cold.get(name)
+        if entry is not None:
+            self._note_materialized(1)
+        return entry
+
+    def cold_rt_hist(self, names, hist_buckets: int) -> np.ndarray:
+        """``int32[len(names), hist_buckets]``: the cumulative RT
+        histogram of each demoted name, read as ONE column out of the
+        blocks — no entry is built, whatever the number of names
+        (``Sentinel.rt_hist_by_name``); demote payloads still in flight
+        are landed first. Zeros for a resident or unknown name."""
+        if not self.enabled:
+            return np.zeros((len(names), hist_buckets), np.int32)
+        with self._lock:
+            pending = self._pending_recs_locked(names)
+        for rec in pending:
+            self._land_one(rec)
+        return self.cold.rt_hist_rows(names, hist_buckets)
 
     def warm_migration(self, sizes) -> None:
         """Compile the three migration programs (the demotion's gather,
@@ -821,6 +867,7 @@ class TierManager:
             "cold_miss": c.get(obs_keys.TIER_COLD_MISS),
             "promoted": c.get(obs_keys.TIER_PROMOTED),
             "demoted": c.get(obs_keys.TIER_DEMOTED),
+            "materialized": c.get(obs_keys.TIER_MATERIALIZED),
             "sketch_overflow": c.get(obs_keys.TIER_SKETCH_OVERFLOW),
             "migrate_p50_ms": None if p50 is None else p50 / 1e6,
             "migrate_p99_ms": None if p99 is None else p99 / 1e6,

@@ -20,7 +20,9 @@ from sentinel_tpu.stats.window import (
     INT32_MAX, NEVER, WindowSpec, WindowState, settle_occupied,
 )
 from sentinel_tpu.tiering import sketch as sk
-from sentinel_tpu.tiering.coldtier import ColdEntry, ColdTier, settle_entry_np
+from sentinel_tpu.tiering.coldtier import (
+    ColdBlock, ColdEntry, ColdTier, settle_entry_np,
+)
 
 
 # ---------------------------------------------------------------------------
@@ -440,7 +442,7 @@ def test_geometry_change_converts_cold_entries(monkeypatch):
         assert B == 4
         for name in ("a", "b"):                 # both landed + converted
             assert name in t.cold
-        e = t.cold._entries["a"]
+        e = t.cold.get("a")
         assert e.sec_counters.shape[0] == B
         assert e.occ_cnt.shape[0] == B + 1
         assert not t._pending_land and not t._land_q
@@ -506,7 +508,7 @@ def test_promote_force_lands_dequeued_record(monkeypatch):
         assert s.resources.evict_name("k")
         s.entry_batch(["x"], acquire=[1])
         t._land_all()
-        e = t.cold._entries["k"]
+        e = t.cold.get("k")
         assert int(e.sec_counters[:, ev.PASS].sum()) == 2
     finally:
         s.close()
@@ -540,3 +542,331 @@ def test_proactive_demote_rolls_back_when_evict_refused(monkeypatch):
             assert rb not in t._shadow
     finally:
         s.close()
+
+
+# ---------------------------------------------------------------------------
+# PR 34: a demotion record lands as ONE columnar block; the cold tier
+# indexes (block, row) and promotion gathers from the blocks
+# ---------------------------------------------------------------------------
+
+BLOCK_NAMES = [f"bk{i}" for i in range(16)]
+# three records over three drains, then a promotion that spans all three
+BLOCK_WAVES = (BLOCK_NAMES[0:6], BLOCK_NAMES[6:12], BLOCK_NAMES[12:16])
+BLOCK_BACK = ["bk2", "bk4", "bk7", "bk8", "bk13", "bk14"]
+
+
+def _row_state(s, name):
+    """Everything a demotion carries of ``name``'s row, read off the
+    device: both windows, the gauge, the booking ring, the histogram,
+    and the alt slices by their host identity (their slots differ with
+    the row)."""
+    row = s.resources.lookup(name)
+    st = s._state
+    out = {}
+    for ring in ("second", "minute"):
+        for f, x in zip(WindowState._fields, getattr(st, ring)):
+            out[f"{ring}.{f}"] = np.asarray(x)[row]
+    out["threads"] = np.asarray(st.threads)[row]
+    out["occ_cnt"] = np.asarray(st.flow_dyn.occupied_count)[row]
+    out["occ_win"] = np.asarray(st.flow_dyn.occupied_window)[row]
+    out["rt_hist"] = np.asarray(st.rt_hist)[row]
+    for slot, ident in s._alt_rows_by_row.get(row, {}).items():
+        for f, x in zip(WindowState._fields, st.alt_second):
+            out[f"alt{ident}.{f}"] = np.asarray(x)[slot]
+        out[f"alt{ident}.threads"] = np.asarray(st.alt_threads)[slot]
+    return out
+
+
+def _drive_blocks(evict, between=None):
+    """One engine under a fixed script that fills every column a
+    demotion carries — per-origin entries with timed exits and some left
+    open (histogram, minute ring, gauge, alt slices), then prioritized
+    batches over flow rules (bookings in the ring), then a reload that
+    lifts the rules' pins off BLOCK_NAMES — and, with ``evict``, demotes
+    BLOCK_WAVES over three drains, runs ``between(s)`` while they are
+    cold, and promotes BLOCK_BACK in one drain. → (the engine, the
+    batches' verdicts, what the landed blocks booked). The twin
+    (``evict=False``) sees the same calls and keeps every row."""
+    clk = ManualClock(start_ms=1_785_000_000_000)
+    s = Sentinel(load_config(max_resources=64, max_flow_rules=16,
+                             max_degrade_rules=16, max_authority_rules=16,
+                             host_fast_path=False, thread_gauge_always=True),
+                 clock=clk)
+    rng = np.random.default_rng(34)
+    verdicts = []
+
+    def batch(names, prioritized=False):
+        v = s.entry_batch(
+            names, acquire=[1] * len(names),
+            origins=list(rng.choice(["app-a", "app-b"], size=len(names))),
+            prioritized=[prioritized] * len(names))
+        verdicts.append((np.asarray(v.allow).copy(),
+                         np.asarray(v.reason).copy(),
+                         np.asarray(v.wait_ms).copy()))
+
+    held = []
+    for i, name in enumerate(BLOCK_NAMES):
+        e = s.entry(name, origin="app-a", sleep=False)
+        if i % 4 == 0:
+            held.append(e)              # a gauge that rides the move
+        else:
+            clk.advance_ms(1 + 37 * i)
+            e.exit()
+    s.load_flow_rules([stpu.FlowRule(resource=n, count=2.0)
+                       for n in BLOCK_NAMES[::2] + ["pin0", "pin1"]])
+    # a ruled name's count passes in one bucket; in the next a
+    # prioritized acquire finds the window full and books the bucket
+    # after: every ruled row's ring holds a pending booking
+    clk.advance_ms(520)
+    batch(BLOCK_NAMES + BLOCK_NAMES[::2] + ["pin0"])
+    clk.advance_ms(520)
+    batch(BLOCK_NAMES + ["pin0"], prioritized=True)
+    # BLOCK_NAMES lose their rules, and with them their pins; pending
+    # bookings ride the reload and then the demotion
+    s.load_flow_rules([stpu.FlowRule(resource="pin0", count=2.0)])
+    for wave in BLOCK_WAVES:
+        if evict:
+            for name in wave:
+                assert s.resources.evict_name(name)
+        batch(["other"])                # the drain: one record a wave
+        clk.advance_ms(40)
+    booked = 0.0
+    if evict:
+        s.tiering._land_all()
+        booked = sum(float(b.occ_cnt.sum())
+                     for b, _live in s.tiering.cold._blocks.values())
+    clk.advance_ms(600)                 # the bookings' window opens
+    if between is not None:
+        between(s)
+    # the names come back, and a drain restores them, with no decide on
+    # them yet: what is compared is what the promotion wrote
+    s.intern_resources(BLOCK_BACK)
+    batch(["other"])
+    return s, verdicts, booked
+
+
+def _assert_rows_equal(tiered, resident, names):
+    for name in names:
+        a, b = _row_state(tiered, name), _row_state(resident, name)
+        assert a.keys() == b.keys(), name
+        for k in a:
+            assert np.array_equal(a[k], b[k]), (name, k)
+
+
+def _reload(s):
+    s.load_flow_rules([stpu.FlowRule(resource="pin1", count=4.0)])
+
+
+def _regeometry(s):
+    s.update_window_geometry(sample_count=4)
+
+
+@pytest.mark.parametrize("between", [None, _reload, _regeometry],
+                         ids=["plain", "flow_reload", "geometry_change"])
+def test_block_promotion_restores_rows_bit_identical(monkeypatch, between):
+    """Three records demoted over three drains, a promotion whose names
+    span all three blocks — straight, across a flow-rule reload (the
+    replay the cold rows slept through), and across a geometry change
+    (every block cold-reset): each restored row reads bit for bit as the
+    row of the twin that never left the table, in every column a
+    demotion carries."""
+    monkeypatch.setenv("SENTINEL_TPU_NATIVE", "0")
+    tiered, tv, booked = _drive_blocks(True, between)
+    resident, rv, _ = _drive_blocks(False, between)
+    try:
+        _assert_parity(tv, rv)
+        assert booked > 0               # the ring moved with something in it
+        t = tiered.tiering
+        snap = t.snapshot()
+        assert snap["demoted"] == len(BLOCK_NAMES)
+        assert snap["promoted"] == len(BLOCK_BACK)
+        assert resident.tiering.snapshot()["demoted"] == 0
+        state = _row_state(resident, "bk7")
+        assert tiered.spec.minute and tiered.spec.hist_buckets
+        assert state["threads"] > 0 and state["rt_hist"].sum() > 0
+        assert any(k.startswith("alt") for k in state)
+        _assert_rows_equal(tiered, resident, BLOCK_BACK)
+        # the names left behind are still indexed, three blocks live
+        assert len(t.cold) == len(BLOCK_NAMES) - len(BLOCK_BACK)
+        assert len(t.cold._blocks) == 3
+        # only a reload replays, and only that builds entries
+        replayed = len(BLOCK_BACK) if between is _reload else 0
+        assert snap["materialized"] == replayed
+        # a by-name read of a name still cold answers what its row held
+        left = [n for n in BLOCK_NAMES if n not in BLOCK_BACK]
+        hist = tiered.rt_hist_by_name(left)
+        want = resident.rt_hist_by_name(left)
+        assert np.array_equal(hist, want) and hist.sum() > 0
+        assert t.snapshot()["materialized"] == replayed
+    finally:
+        tiered.close()
+        resident.close()
+
+
+def _block_of(n, tag=0):
+    """A landed record's stand-in: ``n`` rows, row ``i`` marked
+    ``tag + i`` in its gauge and histogram."""
+    z4 = (np.zeros((n, 2, ev.NUM_EVENTS), np.int32),
+          np.full((n, 2), NEVER, np.int32),
+          np.zeros((n, 2), np.float32), np.full((n, 2), INT32_MAX, np.int32))
+    return ColdBlock(
+        second=z4, minute=z4, threads=np.arange(tag, tag + n, dtype=np.int32),
+        occ_cnt=np.zeros((n, 3), np.float32),
+        occ_win=np.full((n, 3), NEVER, np.int32),
+        rt_hist=np.arange(tag, tag + n, dtype=np.int32)[:, None]
+        * np.ones((1, 4), np.int32),
+        alt_second=tuple(x[:0] for x in z4),
+        alt_threads=np.zeros(0, np.int32), alt_ids=[])
+
+
+def test_cold_max_drops_names_out_of_the_middle_of_a_block():
+    """The bound is on NAMES, oldest first, wherever their block is:
+    ``dropped`` and ``len()`` count names, a dropped name is unknown (it
+    re-enters fresh), the rest of its block still reads right."""
+    tier = ColdTier(max_entries=5)
+    tier.put_block(_block_of(4, 10), ["a0", "a1", "a2", "a3"])
+    tier.put_block(_block_of(4, 20), ["b0", "b1", "b2", "b3"])
+    assert len(tier) == 5 and tier.dropped == 3
+    assert [n in tier for n in ("a0", "a1", "a2", "a3")] == \
+        [False, False, False, True]
+    assert tier.pop("a1") is None and tier.get("a0") is None
+    assert tier.get("a3").threads == 13 and tier.get("b2").threads == 22
+    assert tier.names(2) == ["b3", "b2"]
+    # a re-demotion moves the name to the newer state and the newer end
+    tier.put_block(_block_of(2, 30), ["a3", "c1"])
+    assert len(tier) == 5 and tier.dropped == 4     # b0 went, a3 moved
+    assert "b0" not in tier and tier.get("a3").threads == 30
+    assert len(tier._blocks) == 2                   # a's block: no name left
+    assert np.array_equal(
+        tier.rt_hist_rows(["b1", "zz", "c1", "a3"], 4)[:, 0], [21, 0, 31, 30])
+    # the dropped name re-enters through put() like any first demotion
+    tier.put("a0", _dummy_entry())
+    assert "a0" in tier and len(tier) == 5 and tier.dropped == 5
+
+
+def test_block_released_once_its_last_name_is_popped():
+    tier = ColdTier(None)
+    tier.put_block(_block_of(3, 10), ["a0", "a1", "a2"])
+    tier.put_block(_block_of(2, 20), ["b0", "b1"])
+    assert len(tier._blocks) == 2
+    groups = tier.pop_rows(["b1", "a0", "nope", "a2", "a0"])
+    got = {int(block.threads[i]): int(j)
+           for block, rows, js in groups for i, j in zip(rows, js)}
+    assert got == {21: 0, 10: 1, 12: 3}      # second "a0": already taken
+    assert len(tier) == 2 and len(tier._blocks) == 2
+    assert tier.pop("a1").threads == 11
+    assert len(tier._blocks) == 1            # a's block went with a1
+    assert tier.pop("b0").threads == 20
+    assert len(tier) == 0 and not tier._blocks
+
+
+def test_landing_builds_no_entry_and_copies_no_row(monkeypatch):
+    """The mechanism, pinned: across demote → inline landing → promote
+    of one batch no ``ColdEntry`` is built (``tier.materialized`` stays
+    0) and the landed columns ARE the record's host arrays; one
+    ``cold_entry`` read builds one."""
+    monkeypatch.setenv("SENTINEL_TPU_NATIVE", "0")
+    built = []
+    init = ColdEntry.__init__
+
+    def counting_init(self, *a, **kw):
+        built.append(1)
+        init(self, *a, **kw)
+
+    monkeypatch.setattr(ColdEntry, "__init__", counting_init)
+    clk = ManualClock(start_ms=1_000_000)
+    s = Sentinel(load_config(max_resources=32, max_flow_rules=8,
+                             max_degrade_rules=8, max_authority_rules=8),
+                 clock=clk)
+    try:
+        t = s.tiering
+        names = [f"v{i}" for i in range(8)]
+        s.entry_batch(names, acquire=[1] * 8)
+        for name in names:
+            assert s.resources.evict_name(name)
+        s.entry_batch(["x"], acquire=[1])       # the drain: one record of 8
+        (rec,) = t._land_q
+        host = [np.asarray(x) for x in rec["payload"].second]
+        assert not rec["landed"] and "v3" in t._pending_land
+        # a victim asked for again: its promotion lands the WHOLE record
+        # inline, then gathers the one row
+        s.entry_batch(["v3"], acquire=[1])
+        snap = t.snapshot()
+        assert rec["landed"] and not t._pending_land
+        assert snap["promoted"] == 1 and snap["materialized"] == 0
+        assert s.obs.counters.get("tier.land_inline") == 8
+        assert not built
+        ((block, live),) = t.cold._blocks.values()
+        assert live == 7
+        for col, arr in zip(block.second, host):
+            assert np.shares_memory(col, arr)
+        assert len(t.cold) == 7 and "v3" not in t.cold
+        # the slow form, counted: a by-name read builds ONE entry
+        e = t.cold_entry("v5")
+        assert int(e.sec_counters[:, ev.PASS].sum()) == 1
+        assert len(built) == 1 and t.snapshot()["materialized"] == 1
+        assert s.obs.counters.get("tier.materialized") == 1
+        assert "v5" in t.cold                   # left where it is
+    finally:
+        s.close()
+
+
+def test_block_index_holds_under_concurrent_landing_and_promotion():
+    """Landings, promotions and column reads from three threads: every
+    name comes out exactly once, the live counts add up to the index,
+    and only blocks with a name left are kept."""
+    import sys
+    import threading
+    tier = ColdTier(None)
+    blocks, per = 120, 64
+    taken = []
+    stop = threading.Event()
+    errors = []
+
+    def guard(fn):
+        def run():
+            try:
+                fn()
+            except Exception as exc:     # surfaced by the assert below
+                errors.append(exc)
+        return threading.Thread(target=run)
+
+    def land():
+        for b in range(blocks):
+            tier.put_block(_block_of(per, b * per),
+                           [f"n{b}-{i}" for i in range(per)])
+
+    def promote():
+        rng = np.random.default_rng(7)
+        while not stop.is_set():
+            names = [f"n{rng.integers(blocks)}-{rng.integers(per)}"
+                     for _ in range(32)]
+            for block, rows, _js in tier.pop_rows(names):
+                taken.extend(block.threads[rows].tolist())
+
+    def read():
+        while not stop.is_set():
+            names = [f"n{b}-3" for b in range(0, blocks, 5)]
+            hist = tier.rt_hist_rows(names, 4)
+            for b, h in zip(range(0, blocks, 5), hist[:, 0].tolist()):
+                assert h in (0, b * per + 3)
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        workers = [guard(promote), guard(read), guard(promote)]
+        lander = guard(land)
+        for w in workers + [lander]:
+            w.start()
+        lander.join(timeout=60)
+        stop.set()
+        for w in workers:
+            w.join(timeout=60)
+        assert not lander.is_alive() and not any(w.is_alive() for w in workers)
+    finally:
+        sys.setswitchinterval(was)
+    assert not errors, errors
+    assert len(taken) == len(set(taken))            # no row came out twice
+    assert len(taken) + len(tier) == blocks * per
+    assert sum(live for _b, live in tier._blocks.values()) == len(tier)
+    assert set(tier._blocks) == {ref >> 32 for ref in tier._index.values()}
