@@ -38,6 +38,7 @@ from sentinel_tpu.core.registry import ENTRY_NODE_ROW
 from sentinel_tpu.rules import authority as auth_mod
 from sentinel_tpu.rules import degrade as deg_mod
 from sentinel_tpu.obs import resource_hist
+from sentinel_tpu.ops.segments import padded_table_gather
 from sentinel_tpu.rules import flow as flow_mod
 from sentinel_tpu.rules import param_flow as pf_mod
 from sentinel_tpu.rules import system as sys_mod
@@ -467,7 +468,6 @@ def decide_entries(
     if (scalar_flow or fast_flow) and rules.joint_idx is not None:
         # ONE random gather over the [R, Kf+Kd] joint table feeds both
         # slots (see RuleSet.joint_idx)
-        from sentinel_tpu.ops.segments import padded_table_gather
         Kf = rules.flow_idx.shape[1]
         NFs = rules.flow_table.active.shape[0] - 1
         NDs = rules.deg_table.active.shape[0] - 1
@@ -476,6 +476,18 @@ def decide_entries(
         flow_bk = jnp.where(in_r, joint[:, :Kf], NFs)
         deg_bk = jnp.where(in_r, joint[:, Kf:], NDs)
     sf_ovf = jnp.int32(0)
+    # what DegradeSlot will say to each event's resource, read BEFORE the
+    # flow slot ranks the resource's events: an event a breaker refuses is
+    # never counted as a pass, so it spends nothing of a count-based
+    # budget (rules/flow._spent_rank). One [B, Kd] gather, which the
+    # scalar entry check below reuses
+    if deg_bk is None:
+        deg_bk = padded_table_gather(
+            rules.deg_idx, batch.rows, rules.deg_table.active.shape[0] - 1)
+    gate_code, gate_closed, gate_probe = _scoped(
+        "decide.degrade.gate", deg_mod.degrade_gate, rules.deg_table,
+        state.breakers, deg_bk, rel_now_ms)
+    gate = (gate_closed, gate_probe)
     if scalar_flow:
         flow_dyn, flow_ok, wait_ms = _scoped(
             "decide.flow", flow_mod.flow_check_scalar, rules.flow_table,
@@ -484,13 +496,14 @@ def decide_entries(
             rel_now_ms, minute_spec=spec.minute,
             main_minute=state.minute if spec.minute else None,
             now_idx_m=now_idx_m, has_rate_limiter=scalar_has_rl,
-            rules_bk=flow_bk, occupy_base=enable_occupy, sortfree=sortfree)
+            rules_bk=flow_bk, occupy_base=enable_occupy, sortfree=sortfree,
+            gate=gate)
         occupied = jnp.zeros_like(flow_ok)
         live3 = live2 & flow_ok
         breakers, deg_ok = _scoped(
             "decide.degrade", deg_mod.degrade_entry_check_scalar,
             rules.deg_table, state.breakers, rules.deg_idx, batch.rows, live3,
-            rel_now_ms, rules_bk=deg_bk)
+            rel_now_ms, rules_bk=deg_bk, gate_code=gate_code)
     elif fast_flow:
         # fast general path: per-pair origin/row selection stays live, the
         # admission machinery collapses to rank closed forms; the degrade
@@ -514,7 +527,8 @@ def decide_entries(
                 now_idx_m=now_idx_m, in_win_ms=in_win_ms,
                 occupy_timeout_ms=spec.occupy_timeout_ms,
                 has_rate_limiter=scalar_has_rl,
-                has_thread_rules=not skip_threads, rules_bk=flow_bk)
+                has_thread_rules=not skip_threads, rules_bk=flow_bk,
+                gate=gate)
             if sortfree:
                 flow_dyn, flow_ok, wait_ms, occupied, sf_ovf = out
             else:
@@ -529,7 +543,8 @@ def decide_entries(
                 minute_spec=spec.minute,
                 main_minute=state.minute if spec.minute else None,
                 now_idx_m=now_idx_m, has_rate_limiter=scalar_has_rl,
-                has_thread_rules=not skip_threads, rules_bk=flow_bk)
+                has_thread_rules=not skip_threads, rules_bk=flow_bk,
+                gate=gate)
             if sortfree:
                 flow_dyn, flow_ok, wait_ms, sf_ovf = out
             else:
@@ -541,7 +556,8 @@ def decide_entries(
         breakers, deg_ok = _scoped(
             "decide.degrade", deg_mod.degrade_entry_check_scalar,
             rules.deg_table, state.breakers, rules.deg_idx, batch.rows,
-            live3 & ~occupied, rel_now_ms, rules_bk=deg_bk)
+            live3 & ~occupied, rel_now_ms, rules_bk=deg_bk,
+            gate_code=gate_code)
         deg_ok = deg_ok | occupied
     else:
         cl_fb = (batch.cluster_fallback if batch.cluster_fallback is not None
@@ -561,7 +577,8 @@ def decide_entries(
             main_minute=state.minute if spec.minute else None,
             now_idx_m=now_idx_m, in_win_ms=in_win_ms,
             occupy_timeout_ms=spec.occupy_timeout_ms,
-            enable_occupy=enable_occupy, has_thread_rules=not skip_threads)
+            enable_occupy=enable_occupy, has_thread_rules=not skip_threads,
+            gate=gate)
         if sortfree:
             flow_dyn, flow_ok, wait_ms, occupied, sf_ovf = out
         else:
@@ -845,7 +862,8 @@ def record_exits(
         else:
             alt_threads = state.alt_threads
 
-    breakers = deg_mod.degrade_exit_feed(
+    breakers = _scoped(
+        "exit.degrade.feed", deg_mod.degrade_exit_feed,
         rules.deg_table, state.breakers, rules.deg_idx, batch.rows,
         batch.rt_ms, batch.error, batch.valid, rel_now_ms)
 

@@ -50,6 +50,11 @@ Self-telemetry families (from ``Sentinel.obs`` — obs/; absent while
     sentinel_telemetry_total{event=...}    telemetry health: tick/readback_drop
                                            /hist_tick
     sentinel_exporter_label_overflow_total samples dropped at the label cap
+    sentinel_verdict_total{event=...}      admitted events: paced (wait_ms
+                                           > 0) / passed_now
+    sentinel_breaker_total{event=...}      circuit breakers by telemetry
+                                           tick: seen_open/seen_closed/
+                                           opened/half_opened/closed
 
 Label-cardinality guard: the per-resource gauge families cap the number
 of distinct ``resource`` label values per scrape
@@ -227,6 +232,19 @@ class SentinelCollector:
             "registry and tiering work per distinct name "
             "(runtime.Sentinel._intern_batch)",
             labels=["event"])
+        verdict = CounterMetricFamily(
+            f"{ns}_verdict_total",
+            "Admitted events of the batch door with (paced) and without "
+            "(passed_now) a wait_ms, counted when a batch's verdicts "
+            "settle, beside block_reason",
+            labels=["event"])
+        breaker = CounterMetricFamily(
+            f"{ns}_breaker_total",
+            "Circuit breakers as each telemetry tick reads them: active "
+            "breakers found not CLOSED / CLOSED (seen_open / seen_closed) "
+            "and tick-to-tick changes by the state entered (opened / "
+            "half_opened / closed); an arc faster than a tick is missed",
+            labels=["event"])
         if not describe_only and obs is not None and obs.enabled:
             from sentinel_tpu.obs import counters as ck
             counts = obs.counters.snapshot()
@@ -319,6 +337,15 @@ class SentinelCollector:
             for key, ev in ((ck.INTERN_NAMES, "names"),
                             (ck.INTERN_DISTINCT, "distinct")):
                 intern.add_metric([ev], counts.get(key, 0))
+            for key, ev in ((ck.VERDICT_PACED, "paced"),
+                            (ck.VERDICT_PASSED_NOW, "passed_now")):
+                verdict.add_metric([ev], counts.get(key, 0))
+            for key, ev in ((ck.BREAKER_SEEN_OPEN, "seen_open"),
+                            (ck.BREAKER_SEEN_CLOSED, "seen_closed"),
+                            (ck.BREAKER_OPENED, "opened"),
+                            (ck.BREAKER_HALF_OPENED, "half_opened"),
+                            (ck.BREAKER_CLOSED, "closed")):
+                breaker.add_metric([ev], counts.get(key, 0))
             # bounded by construction: at most telemetry.k ≤ MAX_K labels
             # (×3 quantile labels for res_rt — still top-K-bounded)
             telemetry = getattr(self.sentinel, "telemetry", None)
@@ -335,7 +362,7 @@ class SentinelCollector:
                     blocks, occupy, pipeline, frontend, fe_flush, wraps,
                     flight_pinned, flight_trig, sf_ovf, tune,
                     res_qps, res_rt, telem, label_ovf, tier, control,
-                    cluster_srv, intern)
+                    cluster_srv, intern, verdict, breaker)
 
     def collect(self):
         ns = self.namespace

@@ -48,6 +48,13 @@ dispatch stays within the 2% observability budget (benchmarks/ci_gate.py
 * ``block_reason.<ExceptionName>`` — per-reason denial breakdown keyed
   by the int8 verdict codes (``exception_name_for`` /
   ``slot_name_for_code`` for custom slots).
+* ``verdict.paced`` / ``verdict.passed_now`` — admitted events with
+  and without a ``wait_ms``, counted beside ``block_reason.*``.
+* ``breaker.*`` — the circuit breakers as each telemetry tick finds
+  them: ``seen_open`` / ``seen_closed`` (active breakers not CLOSED /
+  CLOSED, added at every tick) and ``opened`` / ``half_opened`` /
+  ``closed`` (tick-to-tick changes; an arc faster than a tick is
+  missed).
 * ``obs.span_ring_wrap`` — spans/links lost to per-thread ring wrap
   (a ring too small for the sustained span rate; previously a silent
   overwrite).
@@ -203,6 +210,32 @@ TIER_LAND_INLINE = "tier.land_inline"
 # traffic that neither reads cold keys by name nor reloads rules.
 TIER_MATERIALIZED = "tier.materialized"
 
+# PR 35 — what the verdicts and the breakers did, beside the reasons
+# (``block_reason.*``). ``verdict.paced`` / ``verdict.passed_now``:
+# admitted events of the batch door with and without a wait (``wait_ms``
+# > 0: a RateLimiter or WarmUpRateLimiter rule spaced the event out, or
+# an occupy booking landed it in the next window), counted where
+# ``block_reason.*`` is, when a batch's verdicts settle; their sum is the
+# admitted events. ``breaker.seen_open`` / ``breaker.seen_closed``: each
+# telemetry tick (``obs/telemetry.py``, on the scheduler's thread) reads
+# the breaker-state column once and adds the active breakers it found
+# not CLOSED (OPEN or HALF_OPEN) / CLOSED — ``seen_open / (seen_open +
+# seen_closed)`` is the share of breaker x tick readings that found the
+# dependency cut off. ``breaker.opened`` / ``half_opened`` / ``closed``:
+# breakers whose state differs from the previous tick's reading, by the
+# state they are in now — an arc that starts and ends between two ticks
+# (a probe that fails at once: OPEN → HALF_OPEN → OPEN) is missed, and
+# the counts restart with a rule reload. Exported as
+# ``sentinel_verdict_total{event=...}`` and
+# ``sentinel_breaker_total{event=...}``.
+VERDICT_PACED = "verdict.paced"
+VERDICT_PASSED_NOW = "verdict.passed_now"
+BREAKER_SEEN_OPEN = "breaker.seen_open"
+BREAKER_SEEN_CLOSED = "breaker.seen_closed"
+BREAKER_OPENED = "breaker.opened"
+BREAKER_HALF_OPENED = "breaker.half_opened"
+BREAKER_CLOSED = "breaker.closed"
+
 # ``pipeline.dispatches`` counts DEVICE DISPATCHES issued by the
 # serving hot path and its tickers (decide = 1, split = 2, exit = 1, a
 # standalone sketch observe = 1, a telemetry or tiering tick = 1;
@@ -305,6 +338,9 @@ CATALOG = (
     INTERN_NAMES, INTERN_DISTINCT,
     TIER_FIRST_SIGHT, TIER_LAND_INLINE,
     TIER_MATERIALIZED,
+    VERDICT_PACED, VERDICT_PASSED_NOW,
+    BREAKER_SEEN_OPEN, BREAKER_SEEN_CLOSED,
+    BREAKER_OPENED, BREAKER_HALF_OPENED, BREAKER_CLOSED,
 )
 
 

@@ -244,6 +244,9 @@ class HotTelemetry:
             maxlen=HOT_TIMELINE_CAP)
         self._last_raw: Optional[Tuple[np.ndarray, np.ndarray]] = None
         self._last_ts_ms = 0
+        # the breaker-state column as the previous tick read it, with the
+        # compiled rule set it belongs to (a reload starts the diff anew)
+        self._breakers_prev: Optional[Tuple[object, np.ndarray]] = None
         # the first completed second is the one the clock is currently in
         # minus one; earlier seconds pre-date this service
         self._last_sec = sentinel.clock.now_ms() // 1000 - 1
@@ -316,10 +319,15 @@ class HotTelemetry:
             outs, self._ring = self._tick_fn(
                 sn._state.second, sn._state.minute, sn._state.rt_hist,
                 self._ring, idx_s, sec_idx_m, np.int32(append))
+            # the breakers, once a tick: one small column (4 bytes a rule
+            # of capacity), read only while degrade rules are loaded
+            deg = sn._deg
+            breakers = (deg, sn._breaker_snapshot_locked()) \
+                if deg.num_active else None
         if append:
             self._last_sec = sec
         with self._lock:
-            self._pending.append((now_ms, sec, append, outs))
+            self._pending.append((now_ms, sec, append, outs, breakers))
             self._ticks += 1
             self._last_tick_ms = int(now_ms)
         self._obs.counters.add(obs_keys.TELEMETRY_TICK)
@@ -349,12 +357,39 @@ class HotTelemetry:
         with self._lock:
             batch = list(self._pending)
             self._pending.clear()
-        for now_ms, sec, append, outs in batch:
+        for now_ms, sec, append, outs, breakers in batch:
             host = tuple(np.asarray(o) for o in outs)
             with self._obs.phase("telemetry.land",
                                  n=int(np.count_nonzero(host[0] > 0))):
                 self._land(now_ms, sec, append, host)
+            if breakers is not None:
+                self._count_breakers(*breakers)
         return len(batch)
+
+    def _count_breakers(self, deg, column) -> None:
+        """One tick's reading of the breaker-state column into the
+        ``breaker.*`` counters: how many active breakers are not CLOSED /
+        CLOSED now, and how many entered each state since the previous
+        tick's reading of the same rule set. A breaker that left a state
+        and came back between two ticks reads as unchanged."""
+        from sentinel_tpu.rules.degrade import (
+            STATE_CLOSED, STATE_HALF_OPEN, STATE_OPEN,
+        )
+        # rules are packed from slot 0: the active ones come first
+        state = np.asarray(column)[: deg.num_active]
+        counters = self._obs.counters
+        n_open = int(np.count_nonzero(state != STATE_CLOSED))
+        counters.add(obs_keys.BREAKER_SEEN_OPEN, n_open)
+        counters.add(obs_keys.BREAKER_SEEN_CLOSED, state.size - n_open)
+        prev = self._breakers_prev
+        self._breakers_prev = (deg, state)
+        if prev is None or prev[0] is not deg:
+            return
+        entered = state[state != prev[1]]
+        for key, code in ((obs_keys.BREAKER_OPENED, STATE_OPEN),
+                          (obs_keys.BREAKER_HALF_OPENED, STATE_HALF_OPEN),
+                          (obs_keys.BREAKER_CLOSED, STATE_CLOSED)):
+            counters.add(key, int(np.count_nonzero(entered == code)))
 
     def _land(self, now_ms: int, sec: int, append: int, outs) -> None:
         """Land one readback into the host view. O(K): only the loaded

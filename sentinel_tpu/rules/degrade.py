@@ -24,6 +24,7 @@ winner analog).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -78,6 +79,14 @@ class DegradeRuleTable(NamedTuple):
     min_request: jnp.ndarray         # int32
     interval_ms: jnp.ndarray         # int32
     ratio_threshold: jnp.ndarray     # float32 (slow ratio or error ratio or count)
+    # the ratio threshold as num/den where it IS a fraction of small whole
+    # numbers (0.6 = 3/5, 0.5 = 1/2: what rules are written with), else
+    # den 0. ``bad/total > threshold`` is then ``bad*den > num*total`` in
+    # integers: the original compares doubles, float32 rounds 3/5 and 0.6
+    # apart on a device whose division is not correctly rounded, and a
+    # sick dependency sits exactly on its threshold often
+    ratio_num: jnp.ndarray           # int32
+    ratio_den: jnp.ndarray           # int32
 
 
 class BreakerState(NamedTuple):
@@ -100,6 +109,27 @@ class CompiledDegradeRules(NamedTuple):
     # assembly (used-slot slicing + joint-gather concat) runs host-side
     # — two fewer programs to compile or load per process
     rule_idx_np: Optional["np.ndarray"] = None
+
+
+#: largest denominator a threshold is taken as a fraction with, and the
+#: counts up to which the integer products then stay inside int32
+_FRACTION_DEN_MAX = 1000
+_FRACTION_COUNT_MAX = (2 ** 31 - 1) // _FRACTION_DEN_MAX
+
+
+def _threshold_of(r: DegradeRule) -> float:
+    return r.slow_ratio_threshold if r.grade == GRADE_RT else r.count
+
+
+@functools.lru_cache(maxsize=None)
+def _as_small_fraction(x: float) -> Tuple[int, int]:
+    """(num, den) with ``num / den == x`` as doubles and ``den`` at most
+    ``_FRACTION_DEN_MAX``, or (0, 0) where ``x`` is no such fraction."""
+    from fractions import Fraction
+    f = Fraction(x).limit_denominator(_FRACTION_DEN_MAX)
+    if 0.0 <= x <= 1.0 and f.numerator / f.denominator == x:
+        return f.numerator, f.denominator
+    return 0, 0
 
 
 def init_breaker_state(nd: int) -> BreakerState:
@@ -126,6 +156,8 @@ def compile_degrade_rules(rules: Sequence[DegradeRule], *, resource_registry,
     minreq = np.full(nd + 1, 1, np.int32)
     interval = np.full(nd + 1, 1000, np.int32)
     ratio = np.zeros(nd + 1, np.float32)
+    ratio_num = np.zeros(nd + 1, np.int32)
+    ratio_den = np.zeros(nd + 1, np.int32)
     rule_idx = np.full((num_rows, k_per_resource), nd, np.int32)
     slots_used = {}
     for j, r in enumerate(valid):
@@ -148,11 +180,14 @@ def compile_degrade_rules(rules: Sequence[DegradeRule], *, resource_registry,
             ratio[j] = r.count
         else:
             ratio[j] = r.count  # absolute error count
+        if r.grade != GRADE_EXCEPTION_COUNT:
+            ratio_num[j], ratio_den[j] = _as_small_fraction(_threshold_of(r))
     table = DegradeRuleTable(
         active=jnp.asarray(active), grade=jnp.asarray(grade),
         count=jnp.asarray(count), retry_timeout_ms=jnp.asarray(retry),
         min_request=jnp.asarray(minreq), interval_ms=jnp.asarray(interval),
         ratio_threshold=jnp.asarray(ratio),
+        ratio_num=jnp.asarray(ratio_num), ratio_den=jnp.asarray(ratio_den),
     )
     return CompiledDegradeRules(table=table, rule_idx=jnp.asarray(rule_idx),
                                 rules=tuple(valid), num_active=len(valid),
@@ -213,11 +248,53 @@ def degrade_entry_check(
     return st, allow | ~valid
 
 
+def _open_due(table: DegradeRuleTable, st: BreakerState,
+              rel_now_ms: jnp.ndarray) -> jnp.ndarray:
+    """bool[ND+1]: the rules that are OPEN with their retry due."""
+    return ((st.state == STATE_OPEN)
+            & ((rel_now_ms - st.next_retry_ms) >= 0)
+            & table.active)
+
+
+def degrade_gate(
+    table: DegradeRuleTable, st: BreakerState, rules_bk: jnp.ndarray,
+    rel_now_ms: jnp.ndarray,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """What the degrade slot WILL say to the events of each resource, read
+    before the flow slot decides → (code int32[B, Kd], closed bool[B],
+    probe bool[B]).
+
+    In sequence (``FlowSlot`` → ``DegradeSlot`` → ``StatisticSlot``) an
+    event the breaker refuses is never counted as a pass, so it spends
+    nothing of a count-based flow budget. The flow check needs to know
+    that while it ranks a resource's events, and it can: a breaker belongs
+    to one resource, so every event of a resource meets the same
+    breakers. ``closed``: all of them let everything through — the flow
+    rank is the arrival rank. ``probe``: each is CLOSED or OPEN with its
+    retry due, at least one the latter — the first event the flow slot
+    admits is the probe and passes, nothing after it does. Neither:
+    nothing passes. ``code`` is the ONE per-pair gather the entry check
+    needs too (bit 0 the rule passes everything, bit 1 its probe is due);
+    hand it to :func:`degrade_entry_check_scalar`."""
+    ND = table.active.shape[0] - 1
+    open_due = _open_due(table, st, rel_now_ms)
+    # an INACTIVE rule is structurally CLOSED (trip and probe both require
+    # active); the sentinel never blocks
+    pass_rule = ((st.state == STATE_CLOSED) | ~table.active).at[ND].set(True)
+    code = (pass_rule.astype(jnp.int32)
+            | (open_due.astype(jnp.int32) << 1))[rules_bk]
+    closed = jnp.all((code & 1) != 0, axis=1)
+    probe = jnp.all(code != 0, axis=1) & ~closed
+    return code, closed, probe
+
+
 def degrade_entry_check_scalar(
     table: DegradeRuleTable, st: BreakerState, rule_idx: jnp.ndarray,
     rows: jnp.ndarray, valid: jnp.ndarray, rel_now_ms: jnp.ndarray,
     rules_bk: Optional[jnp.ndarray] = None,   # pre-gathered [B, Kd] rule
     # ids (the pipeline's joint flow+degrade gather); None = gather here
+    gate_code: Optional[jnp.ndarray] = None,  # degrade_gate's code for
+    # the same rules_bk and state; None = gather here
 ) -> Tuple[BreakerState, jnp.ndarray]:
     """Sort-free :func:`degrade_entry_check` → (state', allow bool[B]).
 
@@ -234,46 +311,43 @@ def degrade_entry_check_scalar(
     B = rows.shape[0]
     Kd = rule_idx.shape[1]
     ND = table.active.shape[0] - 1
-    R = rule_idx.shape[0]
     BK = B * Kd
 
     if rules_bk is None:
         rules_bk = seg.padded_table_gather(rule_idx, rows, ND)
+    if gate_code is None:
+        gate_code, _, _ = degrade_gate(table, st, rules_bk, rel_now_ms)
     rj = rules_bk.reshape(-1)
-    # no active[rj] gather: an INACTIVE rule is structurally CLOSED (its
-    # state never leaves CLOSED — trip and probe both require active), so
-    # its pairs pass via pass_rule and can never win a probe; only event
-    # VALIDITY must exclude pairs from probe election
+    code = gate_code.reshape(-1)
+    # only event VALIDITY must exclude pairs from probe election: an
+    # invalid event's pairs pass and share the sentinel's key
     valid_bk = jnp.repeat(valid, Kd)
     key = jnp.where(valid_bk, rj, ND)
 
-    open_due = ((st.state == STATE_OPEN)
-                & ((rel_now_ms - st.next_retry_ms) >= 0)
-                & table.active)
-    pass_rule = (st.state == STATE_CLOSED) | ~table.active
-    pass_rule = pass_rule.at[ND].set(True)       # sentinel never blocks
+    open_due = _open_due(table, st, rel_now_ms)
     # the base verdict is needed by BOTH cond branches: hoisting it keeps
     # the common no-probe branch a pure pass-through. (Measured: running
     # the election UNCONDITIONALLY costs ~6 ms/step more than this cond —
     # the [B]→[ND] scatter-min is the expensive part, not the branch.)
-    pair_base = pass_rule[key]
+    pair_base = ((code & 1) != 0) | ~valid_bk
 
     def _no_probe(_):
         return st.state, jnp.all(pair_base.reshape(B, Kd), axis=1)
 
     def _probe(_):
-        idx = jnp.arange(BK, dtype=jnp.int32)
-        win = seg.first_index_by_key(key, ND + 1)
-        winner_pair = (idx == win[key]) & open_due[key]
-        pair_pass = pair_base | winner_pair
-        allow_ev = jnp.all(pair_pass.reshape(B, Kd), axis=1)
-        # OPEN→HALF_OPEN only when the probe's event is admitted by ALL
-        # breakers of its resource (general-path comment at
-        # degrade_entry_check for why)
-        winner_ev = jnp.minimum(win // Kd, B - 1)
-        ok = open_due & (win < BK) & allow_ev[winner_ev]
-        new_state = jnp.where(ok, STATE_HALF_OPEN, st.state)
-        return new_state, allow_ev
+        with jax.named_scope("decide.degrade.probe"):
+            idx = jnp.arange(BK, dtype=jnp.int32)
+            win = seg.first_index_by_key(key, ND + 1)
+            winner_pair = (idx == win[key]) & ((code & 2) != 0) & valid_bk
+            pair_pass = pair_base | winner_pair
+            allow_ev = jnp.all(pair_pass.reshape(B, Kd), axis=1)
+            # OPEN→HALF_OPEN only when the probe's event is admitted by
+            # ALL breakers of its resource (general-path comment at
+            # degrade_entry_check for why)
+            winner_ev = jnp.minimum(win // Kd, B - 1)
+            ok = open_due & (win < BK) & allow_ev[winner_ev]
+            new_state = jnp.where(ok, STATE_HALF_OPEN, st.state)
+            return new_state, allow_ev
 
     new_state, allow_ev = jax.lax.cond(
         jnp.any(open_due), _probe, _no_probe, None)
@@ -357,7 +431,11 @@ def degrade_exit_feed(
     bads = st.bad.astype(jnp.float32)
     enough = st.total >= table.min_request
     ratio = bads / jnp.maximum(totals, 1.0)
-    trip_ratio = enough & (ratio > table.ratio_threshold)
+    exact = (table.ratio_den > 0) & (st.total <= _FRACTION_COUNT_MAX)
+    over = jnp.where(exact,
+                     st.bad * table.ratio_den > table.ratio_num * st.total,
+                     ratio > table.ratio_threshold)
+    trip_ratio = enough & over
     # RT grade: reference also trips when ratio threshold >= 1 means never
     trip_count = bads >= table.ratio_threshold
     trip = jnp.where(grade == GRADE_EXCEPTION_COUNT, enough & trip_count, trip_ratio)

@@ -36,7 +36,7 @@ Blocking behaviors return ``wait_ms`` verdicts instead of sleeping the caller
 from __future__ import annotations
 
 import dataclasses
-from typing import List, NamedTuple, Optional, Sequence, Tuple
+from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
@@ -122,9 +122,24 @@ class FlowRuleTable(NamedTuple):
     warning_token: jnp.ndarray   # float32
     max_token: jnp.ndarray       # float32
     slope: jnp.ndarray           # float32
-    cold_factor: jnp.ndarray     # float32
     sync_row: jnp.ndarray        # int32 — main-table row used for token sync
     cluster_mode: jnp.ndarray    # bool
+    # What the original computes in doubles, computed in float64 at rule
+    # load (the device has float32): the warm-up refill threshold
+    # ``(int)count / coldFactor`` (an INTEGER division there), the pacing
+    # cost of one token ``Math.round(1.0 / count * 1000)``, and per
+    # warm-up rule one row of ``wu_tab`` for each value ``aboveToken`` can
+    # take (``storedTokens`` is a long, so 0..maxToken-warningToken):
+    # column 0 ``floor(nextUp(1 / (aboveToken*slope + 1/count)))`` — the
+    # most ``passQps + acquire`` may be — and column 1 the pacing cost of
+    # one token at that rate. Rules with the same (count, period) share
+    # their rows; ``wu_tab`` is one dummy row while no warm-up rule is
+    # loaded, and the lookups compile away.
+    refill_below: jnp.ndarray    # float32
+    rl_cost1: jnp.ndarray        # int32
+    wu_off: jnp.ndarray          # int32 — first row of the rule's levels
+    wu_levels: jnp.ndarray       # int32 — maxToken - warningToken
+    wu_tab: jnp.ndarray          # int32[T, 2]
 
 
 class FlowDynState(NamedTuple):
@@ -172,6 +187,35 @@ def init_flow_dyn(nf: int, buckets: int = 2, rows: int = 1) -> FlowDynState:
     )
 
 
+#: rows ``wu_tab`` may have (8 bytes each): the sum over the DISTINCT
+#: (count, period) pairs of warm-up rules of maxToken - warningToken + 1;
+#: past it the load fails loudly, as past ``capacity``
+_WU_TAB_MAX = 1 << 24
+_I32_MAX = 2 ** 31 - 1
+
+
+def _java_round(x: float) -> int:
+    """``Math.round(double)`` — floor(x + 0.5) — held to int32."""
+    return int(min(max(np.floor(x + 0.5), 0.0), _I32_MAX))
+
+
+def _warmup_levels(count: float, slope: float, levels: int) -> np.ndarray:
+    """int32[levels + 1, 2]: for aboveToken = 0..levels, what
+    ``WarmUpController.canPass`` / ``WarmUpRateLimiterController.canPass``
+    compute from it in doubles — ``warningQps = Math.nextUp(1.0 /
+    (aboveToken * slope + 1.0 / count))`` as the most ``passQps +
+    acquireCount`` may be, and ``Math.round(1.0 / warningQps * 1000)``."""
+    above = np.arange(levels + 1, dtype=np.float64)
+    count = np.float64(count)
+    with np.errstate(divide="ignore", invalid="ignore", over="ignore"):
+        qps = np.nextafter(1.0 / (above * slope + 1.0 / count), np.inf)
+        cost = np.floor(1.0 / qps * 1000.0 + 0.5)
+    out = np.empty((levels + 1, 2), np.int32)
+    out[:, 0] = np.clip(np.nan_to_num(np.floor(qps), nan=0.0), 0, _I32_MAX)
+    out[:, 1] = np.clip(np.nan_to_num(cost, nan=_I32_MAX), 0, _I32_MAX)
+    return out
+
+
 def compile_flow_rules(rules: Sequence[FlowRule], *, resource_registry,
                        context_registry, capacity: int, k_per_resource: int,
                        num_rows: int, cold_factor: float = 3.0,
@@ -202,9 +246,16 @@ def compile_flow_rules(rules: Sequence[FlowRule], *, resource_registry,
     warning_token = np.zeros(nf + 1, np.float32)
     max_token = np.zeros(nf + 1, np.float32)
     slope = np.zeros(nf + 1, np.float32)
-    cold_f = np.full(nf + 1, cold_factor, np.float32)
     sync_row = np.full(nf + 1, num_rows, np.int32)
     cluster_mode = np.zeros(nf + 1, np.bool_)
+    refill_below = np.zeros(nf + 1, np.float32)
+    rl_cost1 = np.zeros(nf + 1, np.int32)
+    wu_off = np.zeros(nf + 1, np.int32)
+    wu_levels = np.zeros(nf + 1, np.int32)
+    wu_rows: List[np.ndarray] = [np.zeros((1, 2), np.int32)]
+    wu_seen = {}
+    wu_total = 1
+    cold_i = max(int(cold_factor), 2)    # SentinelConfig: an int above 1
 
     rule_idx = np.full((num_rows, k_per_resource), nf, np.int32)
     slots_used = {}
@@ -251,13 +302,34 @@ def compile_flow_rules(rules: Sequence[FlowRule], *, resource_registry,
             # (FlowRuleChecker.java:137-141,154-158)
             sel_kind[j] = SEL_ORIGIN
 
+        if r.count > 0:
+            rl_cost1[j] = _java_round(1.0 / r.count * 1000.0)
         if r.control_behavior in (BEHAVIOR_WARM_UP, BEHAVIOR_WARM_UP_RATE_LIMITER):
-            # WarmUpController.java:66-90 constructor math
-            wt = (r.warm_up_period_sec * r.count) / (cold_factor - 1.0)
-            mt = wt + 2.0 * r.warm_up_period_sec * r.count / (1.0 + cold_factor)
+            # WarmUpController.java:66-90 constructor math: warningToken
+            # and maxToken are ints, coldFactor is an int, and the first
+            # division is an integer division
+            wt = int(r.warm_up_period_sec * r.count) // (cold_i - 1)
+            mt = wt + int(2.0 * r.warm_up_period_sec * r.count
+                          / (1.0 + cold_i))
             warning_token[j] = wt
             max_token[j] = mt
-            slope[j] = (cold_factor - 1.0) / r.count / max(mt - wt, 1e-9)
+            slope64 = (cold_i - 1.0) / max(r.count, 1e-300) / max(mt - wt, 1e-9)
+            slope[j] = slope64
+            refill_below[j] = int(r.count) // cold_i
+            wu_levels[j] = mt - wt
+            shape = (float(r.count), mt - wt)
+            if shape not in wu_seen:
+                wu_seen[shape] = wu_total
+                wu_rows.append(_warmup_levels(float(r.count), slope64,
+                                              mt - wt))
+                wu_total += mt - wt + 1
+                if wu_total > _WU_TAB_MAX:
+                    raise ValueError(
+                        f"warm-up rules need {wu_total} token levels in "
+                        f"all, more than {_WU_TAB_MAX}: count x "
+                        f"warm_up_period_sec of {r.resource!r} is too "
+                        f"large")
+            wu_off[j] = wu_seen[shape]
 
     table = FlowRuleTable(
         active=jnp.asarray(active), grade=jnp.asarray(grade),
@@ -268,8 +340,12 @@ def compile_flow_rules(rules: Sequence[FlowRule], *, resource_registry,
         max_queue_ms=jnp.asarray(max_queue_ms),
         warning_token=jnp.asarray(warning_token),
         max_token=jnp.asarray(max_token), slope=jnp.asarray(slope),
-        cold_factor=jnp.asarray(cold_f), sync_row=jnp.asarray(sync_row),
+        sync_row=jnp.asarray(sync_row),
         cluster_mode=jnp.asarray(cluster_mode),
+        refill_below=jnp.asarray(refill_below),
+        rl_cost1=jnp.asarray(rl_cost1), wu_off=jnp.asarray(wu_off),
+        wu_levels=jnp.asarray(wu_levels),
+        wu_tab=jnp.asarray(np.concatenate(wu_rows)),
     )
     return CompiledFlowRules(table=table, rule_idx=jnp.asarray(rule_idx),
                              rules=tuple(valid), num_active=len(valid),
@@ -327,6 +403,10 @@ def flow_check(
     # results are bit-identical either way (the runtime's
     # SENTINEL_SORTFREE routing flips this; flow_check_sortfree also
     # surfaces the overflow count)
+    gate: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,  # (closed,
+    # probe) bool[B] of rules/degrade.degrade_gate: what DegradeSlot will
+    # say to each event's resource, so that an event it refuses spends
+    # nothing of a count-based budget (see _spent_rank)
 ) -> Tuple[FlowDynState, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """→ (dyn', allow bool[B], wait_ms int32[B], occupied bool[B]).
 
@@ -341,7 +421,7 @@ def flow_check(
         table, dyn, rule_idx, spec, main_second, alt_second, main_threads,
         alt_threads, batch, now_idx_s, rel_now_ms, minute_spec, main_minute,
         now_idx_m, in_win_ms, occupy_timeout_ms, enable_occupy,
-        has_thread_rules, sortfree)
+        has_thread_rules, sortfree, gate)
     return dyn, allow, wait_ms, occupied
 
 
@@ -364,6 +444,7 @@ def flow_check_sortfree(
     occupy_timeout_ms: int = 500,
     enable_occupy: bool = True,
     has_thread_rules: bool = True,
+    gate: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
 ) -> Tuple[FlowDynState, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """:func:`flow_check` with ``sortfree=True``, additionally returning
     the claim-cascade overflow count (int32 scalar — elements that fell
@@ -373,14 +454,14 @@ def flow_check_sortfree(
         table, dyn, rule_idx, spec, main_second, alt_second, main_threads,
         alt_threads, batch, now_idx_s, rel_now_ms, minute_spec, main_minute,
         now_idx_m, in_win_ms, occupy_timeout_ms, enable_occupy,
-        has_thread_rules, True)
+        has_thread_rules, True, gate)
 
 
 def _flow_check_impl(
     table, dyn, rule_idx, spec, main_second, alt_second, main_threads,
     alt_threads, batch, now_idx_s, rel_now_ms, minute_spec, main_minute,
     now_idx_m, in_win_ms, occupy_timeout_ms, enable_occupy,
-    has_thread_rules, sortfree,
+    has_thread_rules, sortfree, gate=None,
 ) -> Tuple[FlowDynState, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     B = batch.rows.shape[0]
     K = rule_idx.shape[1]
@@ -460,15 +541,17 @@ def _flow_check_impl(
     else:
         cur_thr = jnp.zeros_like(cur_pass)   # no THREAD-grade rule reads it
 
-    # --- warm-up token sync (vector over rules, once per step) ---
-    dyn, eff_limit_per_rule = _warmup_sync_and_limits(
-        table, dyn, spec, main_second, now_idx_s, rel_now_ms,
-        minute_spec, main_minute, now_idx_m)
-    eff_limit = eff_limit_per_rule[rj]                                       # [BK]
-
     # --- greedy segment admission ---
     acq_bk = jnp.repeat(batch.acquire, K).astype(jnp.float32)
     valid_bk = jnp.repeat(batch.valid, K) & applicable
+
+    # --- warm-up token sync (vector over rules; a rule syncs when a pair
+    # it applies to arrives) ---
+    dyn, shaping = _warmup_sync_and_limits(
+        table, dyn, spec, main_second, now_idx_s, rel_now_ms,
+        minute_spec, main_minute, now_idx_m,
+        lambda: _rules_reached(rj, valid_bk, NF))
+    eff_limit = shaping.limit[rj]                                            # [BK]
     # inapplicable pairs get the sentinel rule NF so they share one segment
     # that never blocks; their acquire contributes nothing.
     rj_seg = jnp.where(valid_bk, rj, NF)
@@ -548,14 +631,31 @@ def _flow_check_impl(
     behavior_s = g_s[:, 6]
 
     pass_default_s = seg.greedy_admit(base_s, acq_s, limit_s, starts, leader)
+    if gate is not None:
+        # an event DegradeSlot refuses is never counted as a pass
+        # (_spent_rank, for amounts that differ): where nothing will pass
+        # each pair is checked against the window alone; where a probe is
+        # due the first pair the window admits passes, and only its
+        # amount stands against the pairs after it
+        closed, probe = gate
+        closed_s = jnp.repeat(closed, K)[order]
+        probe_s = jnp.repeat(probe, K)[order]
+        alone_s = base_s + acq_s <= limit_s
+        earlier, _ = seg.segment_prefix_sum(
+            alone_s.astype(jnp.int32), starts, leader)
+        spent, _ = seg.segment_prefix_sum(
+            jnp.where(alone_s & (earlier == 0), acq_s, 0.0), starts, leader)
+        pass_default_s = jnp.where(
+            closed_s, pass_default_s,
+            jnp.where(probe_s, base_s + spent + acq_s <= limit_s, alone_s))
 
     # --- rate limiter (paced queue) ---
     # Shaped behaviors apply only to QPS-grade rules (FlowRuleUtil
     # .generateRater falls back to DefaultController for THREAD grade).
-    # cost per element in ms: round(acquire / count * 1000)
+    # cost per element in ms: Math.round(acquire / qps * 1000)
     raw_count_s = table.count[rj_s]
-    count_s = jnp.maximum(raw_count_s, 1e-9)
-    cost_s = jnp.round(acq_s / count_s * 1000.0).astype(jnp.int32)
+    cost_s = _pacing_cost(acq_s.astype(jnp.int32), shaping.cost1[rj_s],
+                          shaping.rate[rj_s])
     c_first = seg.segment_broadcast_first(cost_s, leader)
     L0 = dyn.latest_passed_ms[rj_s]
     due = (L0 + c_first - rel_now_ms) <= 0
@@ -712,6 +812,8 @@ def flow_check_scalar(
     # ranks by identity-bucketed scatter (ops/sortfree.ranks2d_ident —
     # keys are already dense rule ids, so no hashing and no overflow)
     # instead of the batched stable sort; exact, not probabilistic
+    gate: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,  # (closed,
+    # probe) bool[B] of rules/degrade.degrade_gate — see _spent_rank
 ) -> Tuple[FlowDynState, jnp.ndarray, jnp.ndarray]:
     """Scalar-path flow check → (dyn', allow bool[B], wait_ms int32[B]).
 
@@ -750,9 +852,13 @@ def flow_check_scalar(
     R = rule_idx.shape[0]
 
     # ---- per-rule admission state ([NF+1]-sized, negligible) ----
-    dyn, eff_limit = _warmup_sync_and_limits(
+    if rules_bk is None:
+        rules_bk = seg.padded_table_gather(rule_idx, rows, NF)
+    dyn, shaping = _warmup_sync_and_limits(
         table, dyn, spec, main_second, now_idx_s, rel_now_ms,
-        minute_spec, main_minute, now_idx_m)
+        minute_spec, main_minute, now_idx_m,
+        lambda: _rules_reached(rules_bk, valid[:, None], NF))
+    eff_limit = shaping.limit
     sel_row = jnp.minimum(table.sync_row, R - 1)
     base_pass = window_sum_rows(spec, main_second, sel_row, ev.PASS,
                                 now_idx_s).astype(jnp.float32)
@@ -785,11 +891,9 @@ def flow_check_scalar(
                   | (table.behavior == BEHAVIOR_WARM_UP_RATE_LIMITER))
                  & (table.grade == GRADE_QPS))
         base_time, cost, max_k = _rl_closed_form(
-            table, dyn, acq_of_rule, rel_now_ms)
+            table, dyn, shaping, acq_of_rule, rel_now_ms)
 
     # ---- per-pair work ----
-    if rules_bk is None:
-        rules_bk = seg.padded_table_gather(rule_idx, rows, NF)
     rj = rules_bk.reshape(-1)                                # [BK]
     valid_bk = jnp.repeat(valid, K)
     # INVALID pairs share the sentinel segment (they must not consume
@@ -825,7 +929,8 @@ def flow_check_scalar(
     g = vt[key]                                              # [BK, C]
     base_pair = lax.bitcast_convert_type(g[:, 0], jnp.float32)
     limit_pair = lax.bitcast_convert_type(g[:, 1], jnp.float32)
-    rankf = rank.astype(jnp.float32)
+    rankf = _spent_rank(rank.reshape(B, K), gate).reshape(-1).astype(
+        jnp.float32)
 
     pass_default = (base_pair + rankf * a_bk) + a_bk <= limit_pair
     if has_rate_limiter:
@@ -869,6 +974,39 @@ def flow_check_scalar(
     return dyn, allow, wait_ms
 
 
+def _rules_reached(rules: jnp.ndarray, reached: jnp.ndarray,
+                   nf: int) -> jnp.ndarray:
+    """bool[NF+1]: the rules some pair of ``rules`` (any shape) names where
+    ``reached`` (same shape) holds — for ``_warmup_sync_and_limits``."""
+    key = jnp.where(reached, rules, nf).reshape(-1)
+    return jnp.zeros((nf + 1,), jnp.bool_).at[key].set(True, mode="drop")
+
+
+def _spent_rank(rank: jnp.ndarray,
+                gate: Optional[Tuple[jnp.ndarray, jnp.ndarray]]
+                ) -> jnp.ndarray:
+    """How many earlier events of a pair's segment a COUNT-based
+    controller (Default, WarmUp, THREAD grade) has counted as passes when
+    the pair is checked → int32[B, K], from the arrival rank [B, K].
+
+    ``StatisticSlot`` counts a pass once the whole chain has passed, so
+    an event ``DegradeSlot`` refuses spends nothing of the budget. With
+    ``gate = (closed, probe)`` from ``rules/degrade.degrade_gate``: where
+    every breaker of the event's resource is CLOSED each admitted earlier
+    event passed, the rank itself; where a probe is due exactly one event
+    passes — the first the flow slot admits — so at most 1; otherwise
+    none did, 0. (The first event of a probe resource is checked at 0; if
+    it fails so does every later one, which is why ``min(rank, 1)`` needs
+    no second pass.) The pacing controllers do not ask: they move
+    ``latestPassedTime`` in ``canPass``, before ``DegradeSlot`` is
+    reached, and keep the arrival rank."""
+    if gate is None:
+        return rank
+    closed, probe = gate
+    return jnp.where(closed[:, None], rank,
+                     jnp.where(probe[:, None], jnp.minimum(rank, 1), 0))
+
+
 def _landed_per_rule(dyn: FlowDynState, sel_row: jnp.ndarray,
                      spec: WindowSpec, now_idx_s: jnp.ndarray) -> jnp.ndarray:
     """LANDED occupy bookings per rule → float32[NF+1]: sum of bookings on
@@ -904,6 +1042,8 @@ def flow_check_fast(
     sortfree: bool = False,           # STATIC: per-slot ranks via the
     # hashed claim cascade (ops/sortfree.ranks2d_hashed) with a lax.cond
     # sorted fallback on claim overflow — bit-exact either way
+    gate: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,  # see
+    # _spent_rank
 ) -> Tuple[FlowDynState, jnp.ndarray, jnp.ndarray]:
     """Fast GENERAL-path flow check → (dyn', allow bool[B], wait_ms int32[B]).
 
@@ -944,7 +1084,7 @@ def flow_check_fast(
         alt_threads, batch, now_idx_s, rel_now_ms, minute_spec, main_minute,
         now_idx_m, has_rate_limiter, has_thread_rules, rules_bk,
         enable_occupy=False, in_win_ms=None, occupy_timeout_ms=0,
-        sortfree=sortfree)
+        sortfree=sortfree, gate=gate)
     return dyn, allow, wait_ms
 
 
@@ -952,7 +1092,7 @@ def flow_check_fast_sortfree(
     table, dyn, rule_idx, spec, main_second, alt_second, main_threads,
     alt_threads, batch, now_idx_s, rel_now_ms, minute_spec=None,
     main_minute=None, now_idx_m=None, has_rate_limiter=True,
-    has_thread_rules=True, rules_bk=None,
+    has_thread_rules=True, rules_bk=None, gate=None,
 ) -> Tuple[FlowDynState, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """:func:`flow_check_fast` with ``sortfree=True``, additionally
     returning the claim-cascade overflow count (int32 scalar) →
@@ -962,7 +1102,7 @@ def flow_check_fast_sortfree(
         alt_threads, batch, now_idx_s, rel_now_ms, minute_spec, main_minute,
         now_idx_m, has_rate_limiter, has_thread_rules, rules_bk,
         enable_occupy=False, in_win_ms=None, occupy_timeout_ms=0,
-        sortfree=True)
+        sortfree=True, gate=gate)
     return dyn, allow, wait_ms, sf_overflow
 
 
@@ -987,6 +1127,8 @@ def flow_check_fast_occupy(
     has_thread_rules: bool = True,    # STATIC: see flow_check
     rules_bk: Optional[jnp.ndarray] = None,   # [B, K] pre-gathered rule ids
     sortfree: bool = False,           # STATIC: see flow_check_fast
+    gate: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,  # see
+    # _spent_rank
 ) -> Tuple[FlowDynState, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Occupy-capable fast general path → (dyn', allow, wait_ms, occupied).
 
@@ -1026,7 +1168,7 @@ def flow_check_fast_occupy(
         alt_threads, batch, now_idx_s, rel_now_ms, minute_spec, main_minute,
         now_idx_m, has_rate_limiter, has_thread_rules, rules_bk,
         enable_occupy=True, in_win_ms=in_win_ms,
-        occupy_timeout_ms=occupy_timeout_ms, sortfree=sortfree)
+        occupy_timeout_ms=occupy_timeout_ms, sortfree=sortfree, gate=gate)
     return dyn, allow, wait_ms, occupied
 
 
@@ -1034,7 +1176,7 @@ def flow_check_fast_occupy_sortfree(
     table, dyn, rule_idx, spec, main_second, alt_second, main_threads,
     alt_threads, batch, now_idx_s, rel_now_ms, minute_spec=None,
     main_minute=None, now_idx_m=None, in_win_ms=None, occupy_timeout_ms=500,
-    has_rate_limiter=True, has_thread_rules=True, rules_bk=None,
+    has_rate_limiter=True, has_thread_rules=True, rules_bk=None, gate=None,
 ) -> Tuple[FlowDynState, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """:func:`flow_check_fast_occupy` with ``sortfree=True``, additionally
     returning the claim-cascade overflow count (int32 scalar) →
@@ -1046,14 +1188,14 @@ def flow_check_fast_occupy_sortfree(
         alt_threads, batch, now_idx_s, rel_now_ms, minute_spec, main_minute,
         now_idx_m, has_rate_limiter, has_thread_rules, rules_bk,
         enable_occupy=True, in_win_ms=in_win_ms,
-        occupy_timeout_ms=occupy_timeout_ms, sortfree=True)
+        occupy_timeout_ms=occupy_timeout_ms, sortfree=True, gate=gate)
 
 
 def _flow_check_fast_impl(
     table, dyn, rule_idx, spec, main_second, alt_second, main_threads,
     alt_threads, batch, now_idx_s, rel_now_ms, minute_spec, main_minute,
     now_idx_m, has_rate_limiter, has_thread_rules, rules_bk,
-    enable_occupy, in_win_ms, occupy_timeout_ms, sortfree=False,
+    enable_occupy, in_win_ms, occupy_timeout_ms, sortfree=False, gate=None,
 ) -> Tuple[FlowDynState, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     B = batch.rows.shape[0]
     K = rule_idx.shape[1]
@@ -1070,9 +1212,15 @@ def _flow_check_fast_impl(
         rules_bk = seg.padded_table_gather(rule_idx, batch.rows, NF)  # [B,K]
 
     # ---- per-rule step state ----
-    dyn, eff_limit = _warmup_sync_and_limits(
+    # (a warm-up rule syncs when a live event of its RESOURCE arrives:
+    # whether the rule applies to the event's origin is read from the
+    # gather below, which needs this step's limits — an origin-specific
+    # warm-up rule may sync a call earlier than the original's would)
+    dyn, shaping = _warmup_sync_and_limits(
         table, dyn, spec, main_second, now_idx_s, rel_now_ms,
-        minute_spec, main_minute, now_idx_m)
+        minute_spec, main_minute, now_idx_m,
+        lambda: _rules_reached(rules_bk, batch.valid[:, None], NF))
+    eff_limit = shaping.limit
     acq_of_rule = jnp.float32(0) + jnp.max(
         jnp.where(batch.valid, batch.acquire, 0)).astype(jnp.float32)
     if has_rate_limiter:
@@ -1080,7 +1228,7 @@ def _flow_check_fast_impl(
                        | (table.behavior == BEHAVIOR_WARM_UP_RATE_LIMITER))
                       & (table.grade == GRADE_QPS))
         base_time, cost, max_k = _rl_closed_form(
-            table, dyn, acq_of_rule, rel_now_ms)
+            table, dyn, shaping, acq_of_rule, rel_now_ms)
 
     # ---- stat reads. MAIN/REF rows are PER-RULE quantities: a valid
     # (event, rule) pair always has rule.sync_row == the event's row (the
@@ -1212,7 +1360,7 @@ def _flow_check_fast_impl(
 
     # ---- admission (closed forms) ----
     a_f = acq_of_rule                       # the uniform acquire, float32
-    rankf = rank.astype(jnp.float32)
+    rankf = _spent_rank(rank, gate).astype(jnp.float32)
     limit_pair = lax.bitcast_convert_type(g[..., 5], jnp.float32)
     pass_default = (base + rankf * a_f) + a_f <= limit_pair
     if has_rate_limiter:
@@ -1351,11 +1499,35 @@ def _flow_check_fast_impl(
     return dyn, allow, wait_ms.astype(jnp.int32), occupied, sf_ovf
 
 
+class _Shaping(NamedTuple):
+    """Per-rule [NF+1] columns of one step, from
+    :func:`_warmup_sync_and_limits`."""
+
+    limit: jnp.ndarray      # float32 — the most passQps + acquire may be
+    cost1: jnp.ndarray      # int32 — pacing cost of ONE token, ms
+    rate: jnp.ndarray       # float32 — the QPS a pacing rule paces at
+    # (for an acquire other than 1, whose cost is computed in float32)
+
+
+def _pacing_cost(acquire: jnp.ndarray, shaping_cost1: jnp.ndarray,
+                 shaping_rate: jnp.ndarray) -> jnp.ndarray:
+    """``Math.round(1.0 * acquire / qps * 1000)`` → int32. Exact for
+    ``acquire == 1`` (the load-time float64 column); in float32, rounded
+    half up as the original rounds, for any other."""
+    other = jnp.floor(acquire.astype(jnp.float32)
+                      / jnp.maximum(shaping_rate, 1e-9) * 1000.0 + 0.5)
+    return jnp.where(acquire == 1, shaping_cost1,
+                     jnp.minimum(other, 2.0 ** 30).astype(jnp.int32))
+
+
 def _rl_closed_form(table: FlowRuleTable, dyn: FlowDynState,
-                    acq_of_rule: jnp.ndarray, rel_now_ms: jnp.ndarray):
+                    shaping: _Shaping, acq_of_rule: jnp.ndarray,
+                    rel_now_ms: jnp.ndarray):
     """Per-rule RATE_LIMITER closed form → (base_time, cost, max_k),
     shared bit-exactly by the scalar and fast paths (cost is per-rule
-    for uniform acquire — RateLimiterController.java:30-90).
+    for uniform acquire — RateLimiterController.java:30-90; a
+    WarmUpRateLimiter's cost is the warm-up rate's while its tokens are
+    above the warning line).
 
     All arithmetic stays per-RULE and BOUNDED: the admitted-rank budget
     ``max_k = (now + maxq - base_time) // cost`` has numerator in
@@ -1365,19 +1537,20 @@ def _rl_closed_form(table: FlowRuleTable, dyn: FlowDynState,
     ``cost == 0`` (huge count): every rank shares one wait =
     ``max(base - now, 0)``, matching the general path's uniform-latest
     case. ``count <= 0`` RL blocks everything."""
-    count_safe = jnp.maximum(table.count, 1e-9)
-    cost = jnp.round(acq_of_rule / count_safe * 1000.0).astype(jnp.int32)
-    L0 = dyn.latest_passed_ms
-    due = (L0 + cost - rel_now_ms) <= 0
-    base_time = jnp.where(due, rel_now_ms - cost, L0)
-    maxq_eff = jnp.where(table.count > 0, table.max_queue_ms,
-                         jnp.int32(-1))
-    rl_numer = rel_now_ms + maxq_eff - base_time
-    max_k = jnp.maximum(rl_numer // jnp.maximum(cost, 1), 0)
-    wait0_ok = jnp.maximum(base_time - rel_now_ms, 0) <= maxq_eff
-    max_k = jnp.where(cost > 0, max_k,
-                      jnp.where(wait0_ok, jnp.int32(2 ** 30), 0))
-    max_k = jnp.where(table.count > 0, max_k, 0)
+    with jax.named_scope("decide.flow.rl"):
+        cost = _pacing_cost(acq_of_rule.astype(jnp.int32), shaping.cost1,
+                            shaping.rate)
+        L0 = dyn.latest_passed_ms
+        due = (L0 + cost - rel_now_ms) <= 0
+        base_time = jnp.where(due, rel_now_ms - cost, L0)
+        maxq_eff = jnp.where(table.count > 0, table.max_queue_ms,
+                             jnp.int32(-1))
+        rl_numer = rel_now_ms + maxq_eff - base_time
+        max_k = jnp.maximum(rl_numer // jnp.maximum(cost, 1), 0)
+        wait0_ok = jnp.maximum(base_time - rel_now_ms, 0) <= maxq_eff
+        max_k = jnp.where(cost > 0, max_k,
+                          jnp.where(wait0_ok, jnp.int32(2 ** 30), 0))
+        max_k = jnp.where(table.count > 0, max_k, 0)
     return base_time, cost, max_k
 
 
@@ -1386,9 +1559,19 @@ def _warmup_sync_and_limits(
     main_second: WindowState, now_idx_s: jnp.ndarray, rel_now_ms: jnp.ndarray,
     minute_spec: Optional[WindowSpec], main_minute: Optional[WindowState],
     now_idx_m: Optional[jnp.ndarray],
-) -> Tuple[FlowDynState, jnp.ndarray]:
-    """Once-per-step warm-up token refill (WarmUpController.syncToken) and the
-    per-rule effective QPS limit for this step.
+    touched: Callable[[], jnp.ndarray],
+) -> Tuple[FlowDynState, _Shaping]:
+    """Warm-up token refill (WarmUpController.syncToken), once a second for
+    each rule an event of this step reaches, and each rule's limit and
+    pacing cost for this step.
+
+    ``touched()`` → bool[NF+1]: the rules ``canPass`` is called on in this
+    step (asked for only while a warm-up rule is loaded). The original
+    syncs inside ``canPass``, so a rule nobody asks keeps its tokens and
+    its ``lastFilledTime``: after three idle seconds ONE sync refills three
+    seconds' worth and takes off the one previous second's passes. A sync
+    of every rule at every step would take off each idle second's
+    predecessor too and end elsewhere.
 
     Non-warm-up rules get their plain ``count``. Token state syncs against the
     rule's ``sync_row``, using the previous *second's* pass count — the
@@ -1397,35 +1580,54 @@ def _warmup_sync_and_limits(
     so the minute window is the canonical source; without it we fall back to
     the second window's previous (sub-second) bucket, which under-counts and
     makes the ramp slower (conservative).
+
+    ``stored_tokens`` holds whole numbers, as the original's long does (a
+    refill is truncated), so ``aboveToken`` indexes the rule's rows of
+    ``wu_tab``: the limit and the cost there are the original's doubles,
+    not float32's. Exact while a rule's maxToken stays under 2**24.
     """
-    is_wu = ((table.behavior == BEHAVIOR_WARM_UP)
-             | (table.behavior == BEHAVIOR_WARM_UP_RATE_LIMITER)) & (
-        table.grade == GRADE_QPS)
-    R = main_second.stamps.shape[0]
-    srow = jnp.minimum(table.sync_row, R - 1)
-    if minute_spec is not None and main_minute is not None:
-        pass_prev = prev_window_sum_rows(minute_spec, main_minute, srow, ev.PASS,
-                                         now_idx_m).astype(jnp.float32)
-    else:
-        pass_prev = prev_window_sum_rows(spec, main_second, srow, ev.PASS,
-                                         now_idx_s).astype(jnp.float32)
+    if table.wu_tab.shape[0] == 1:      # STATIC: no warm-up rule loaded
+        return dyn, _Shaping(table.count, table.rl_cost1, table.count)
+    with jax.named_scope("decide.flow.warmup"):
+        is_wu = ((table.behavior == BEHAVIOR_WARM_UP)
+                 | (table.behavior == BEHAVIOR_WARM_UP_RATE_LIMITER)) & (
+            table.grade == GRADE_QPS)
+        R = main_second.stamps.shape[0]
+        srow = jnp.minimum(table.sync_row, R - 1)
+        if minute_spec is not None and main_minute is not None:
+            pass_prev = prev_window_sum_rows(
+                minute_spec, main_minute, srow, ev.PASS,
+                now_idx_m).astype(jnp.float32)
+        else:
+            pass_prev = prev_window_sum_rows(
+                spec, main_second, srow, ev.PASS,
+                now_idx_s).astype(jnp.float32)
 
-    now_sec = rel_now_ms // 1000
-    should_sync = is_wu & (now_sec > dyn.last_filled_sec)
-    old = dyn.stored_tokens
-    elapsed_s = (now_sec - dyn.last_filled_sec).astype(jnp.float32)
-    refill_ok = (old < table.warning_token) | (
-        (old > table.warning_token)
-        & (pass_prev < table.count / jnp.maximum(table.cold_factor, 1.001)))
-    refilled = jnp.minimum(old + elapsed_s * table.count, table.max_token)
-    new_tokens = jnp.where(refill_ok, refilled, old)
-    new_tokens = jnp.maximum(new_tokens - pass_prev, 0.0)
-    stored = jnp.where(should_sync, new_tokens, old)
-    last_filled = jnp.where(should_sync, now_sec, dyn.last_filled_sec)
-    dyn = dyn._replace(stored_tokens=stored, last_filled_sec=last_filled)
+        now_sec = rel_now_ms // 1000
+        should_sync = is_wu & (now_sec > dyn.last_filled_sec) & touched()
+        old = dyn.stored_tokens
+        elapsed_s = (now_sec - dyn.last_filled_sec).astype(jnp.float32)
+        # coolDownTokens: below the warning line always refill; above it
+        # only while the previous second passed fewer than
+        # (int)count / coldFactor
+        refill_ok = (old < table.warning_token) | (
+            (old > table.warning_token) & (pass_prev < table.refill_below))
+        refilled = jnp.minimum(jnp.floor(old + elapsed_s * table.count),
+                               table.max_token)
+        new_tokens = jnp.where(refill_ok, refilled, old)
+        new_tokens = jnp.maximum(new_tokens - pass_prev, 0.0)
+        stored = jnp.where(should_sync, new_tokens, old)
+        last_filled = jnp.where(should_sync, now_sec, dyn.last_filled_sec)
+        dyn = dyn._replace(stored_tokens=stored, last_filled_sec=last_filled)
 
-    above = jnp.maximum(stored - table.warning_token, 0.0)
-    warning_qps = 1.0 / (above * table.slope + 1.0 / jnp.maximum(table.count, 1e-9))
-    eff = jnp.where(is_wu & (stored >= table.warning_token),
-                    warning_qps, table.count)
-    return dyn, eff
+        warm = is_wu & (stored >= table.warning_token)
+        above = jnp.clip((stored - table.warning_token).astype(jnp.int32),
+                         0, table.wu_levels)
+        level = table.wu_tab[jnp.where(warm, table.wu_off + above, 0)]
+        limit = jnp.where(warm, level[:, 0].astype(jnp.float32), table.count)
+        cost1 = jnp.where(warm, level[:, 1], table.rl_cost1)
+        rate = jnp.where(
+            warm, 1.0 / (above.astype(jnp.float32) * table.slope
+                         + 1.0 / jnp.maximum(table.count, 1e-9)),
+            table.count)
+    return dyn, _Shaping(limit, cost1, rate)

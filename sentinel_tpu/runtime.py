@@ -2298,6 +2298,12 @@ class Sentinel:
                     if obs_on:
                         self._obs_block(res, rcode, origin, cnt)
             if obs_on:
+                allowed = np.asarray(verdicts.allow)
+                paced = int(np.count_nonzero(
+                    allowed & (np.asarray(verdicts.wait_ms) > 0)))
+                obs.counters.add(obs_keys.VERDICT_PACED, paced)
+                obs.counters.add(obs_keys.VERDICT_PASSED_NOW,
+                                 int(np.count_nonzero(allowed)) - paced)
                 t_end = obs.spans.now_ns()
                 obs.hist_entry.record(t_end - t0)
                 if tr:

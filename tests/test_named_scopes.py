@@ -82,8 +82,11 @@ def test_the_decide_step_names_each_stage(engine, scalar):
         sph._ruleset, sph._state, batch, times,
         jnp.asarray(np.zeros(2, np.float32)), **flags).compile()
     want = {"decide." + s for s in STAGES | (set() if scalar else ALT)}
-    assert scopes_of(compiled, "decide.") == want | {
-        "decide.flow", "decide.degrade"}
+    # the gate reads the breakers before the flow slot ranks (PR 35); the
+    # probe election is a branch of the scalar entry check alone
+    want |= {"decide.flow", "decide.degrade", "decide.degrade.gate"}
+    assert scopes_of(compiled, "decide.") == want | (
+        {"decide.degrade.probe"} if scalar else set())
 
 
 def test_the_exit_and_record_blocks_steps_name_each_stage(engine):
@@ -97,8 +100,8 @@ def test_the_exit_and_record_blocks_steps_name_each_stage(engine):
     compiled = sph._jit_exit.lower(
         sph._ruleset, sph._state, xbatch, times,
         skip_threads=sph._skip_threads).compile()
-    assert scopes_of(compiled, "exit.") == {"exit." + s
-                                            for s in STAGES | ALT}
+    assert scopes_of(compiled, "exit.") == {
+        "exit." + s for s in STAGES | ALT} | {"exit.degrade.feed"}
     compiled = sph._jit_record_blocks.lower(
         sph._state, rows, pad, pad, np.ones(N, np.int32), np.ones(N, bool),
         np.ones(N, bool), times).compile()

@@ -448,20 +448,28 @@ def test_catalog_vector_roundtrip():
     assert ck.vector_counts(longer) == back
 
 
-def test_catalog_is_append_only_with_pr34_key_last():
+def test_catalog_is_append_only_with_pr35_keys_last():
     """The multihost allgather aggregates CATALOG by POSITION (prefix
     compatibility with older peers), so the catalog may only ever grow at
-    the tail. Pin the newest (PR 34 ``tier.materialized``) key to the
-    end, with the PR 33 tier first-sight / inline-landing, PR 32 batch-dedup, PR 26 cluster-server cycle, round-20 resource-histogram, round-17
+    the tail. Pin the newest (PR 35 ``verdict.*`` / ``breaker.*``) keys to
+    the end, with the PR 34 ``tier.materialized``, the PR 33 tier first-sight / inline-landing, PR 32 batch-dedup, PR 26 cluster-server cycle, round-20 resource-histogram, round-17
     overload-controller, round-16 single-dispatch, round-15 tiering,
     round-12 telemetry/exporter, round-11 tune, round-10 sortfree and
     round-9 mesh keys immediately above them — an insertion above any
     group (or a re-ordering) would silently mis-attribute every counter
     on a mixed-version fleet."""
-    assert ck.CATALOG[-1] == ck.TIER_MATERIALIZED == "tier.materialized"
-    assert ck.CATALOG[-3:-1] == (ck.TIER_FIRST_SIGHT, ck.TIER_LAND_INLINE) \
+    assert ck.CATALOG[-7:] == (
+        ck.VERDICT_PACED, ck.VERDICT_PASSED_NOW, ck.BREAKER_SEEN_OPEN,
+        ck.BREAKER_SEEN_CLOSED, ck.BREAKER_OPENED, ck.BREAKER_HALF_OPENED,
+        ck.BREAKER_CLOSED) == (
+        "verdict.paced", "verdict.passed_now", "breaker.seen_open",
+        "breaker.seen_closed", "breaker.opened", "breaker.half_opened",
+        "breaker.closed")
+    catalog = ck.CATALOG[:-7]
+    assert catalog[-1] == ck.TIER_MATERIALIZED == "tier.materialized"
+    assert catalog[-3:-1] == (ck.TIER_FIRST_SIGHT, ck.TIER_LAND_INLINE) \
         == ("tier.first_sight", "tier.land_inline")
-    catalog = ck.CATALOG[:-3]
+    catalog = catalog[:-3]
     assert catalog[-2:] == (ck.INTERN_NAMES, ck.INTERN_DISTINCT)
     assert (ck.INTERN_NAMES, ck.INTERN_DISTINCT) == (
         "intern.names", "intern.distinct")
