@@ -320,7 +320,9 @@ class SentinelCollector:
                             (ck.TIER_SKETCH_OVERFLOW, "sketch_overflow"),
                             (ck.TIER_FIRST_SIGHT, "first_sight"),
                             (ck.TIER_LAND_INLINE, "land_inline"),
-                            (ck.TIER_MATERIALIZED, "materialized")):
+                            (ck.TIER_MATERIALIZED, "materialized"),
+                            (ck.TIER_TICK, "tick"),
+                            (ck.TIER_TICK_ESTIMATE, "tick_estimate")):
                 tier.add_metric([ev], counts.get(key, 0))
             for key, ev in ((ck.CONTROL_TICK, "tick"),
                             (ck.CONTROL_SHED_ACTION, "shed_rate"),

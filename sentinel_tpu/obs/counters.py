@@ -236,9 +236,20 @@ BREAKER_OPENED = "breaker.opened"
 BREAKER_HALF_OPENED = "breaker.half_opened"
 BREAKER_CLOSED = "breaker.closed"
 
+# PR 36 — what the tiering tick dispatched: ``tier.tick`` counts the
+# ticks (sketch decay + the read of its largest counter, a 64 KB
+# program whatever the table's size); ``tier.tick_estimate`` counts
+# those that ALSO dispatched every row's estimate (``SR x R`` gathered
+# lanes and an ``int32[R]`` readback) — only when proactive demotion
+# can use it: ``SENTINEL_HOT_ROWS`` set on the Python registry. 0 on a
+# default deployment.
+TIER_TICK = "tier.tick"
+TIER_TICK_ESTIMATE = "tier.tick_estimate"
+
 # ``pipeline.dispatches`` counts DEVICE DISPATCHES issued by the
 # serving hot path and its tickers (decide = 1, split = 2, exit = 1, a
-# standalone sketch observe = 1, a telemetry or tiering tick = 1;
+# standalone sketch observe = 1, a telemetry or tiering tick = 1, one
+# more for a tiering tick that also dispatches every row's estimate;
 # cold-path programs — invalidation drains, promotions/restores, rule
 # reloads — are deliberately NOT counted: the key exists so
 # dispatches-per-batch is measurable from obs plumbing alone, and the
@@ -341,6 +352,7 @@ CATALOG = (
     VERDICT_PACED, VERDICT_PASSED_NOW,
     BREAKER_SEEN_OPEN, BREAKER_SEEN_CLOSED,
     BREAKER_OPENED, BREAKER_HALF_OPENED, BREAKER_CLOSED,
+    TIER_TICK, TIER_TICK_ESTIMATE,
 )
 
 

@@ -278,7 +278,7 @@ def _build_sd_steps(spec: EngineSpec, custom_slots: tuple, shardings=None,
                 sortfree=sortfree)
             # the overflow flag is dropped exactly like observe_locked's
             # (self-clamping halve happens inside update_sketch; the
-            # COUNTER is ticked from the ticker's estimate readback)
+            # COUNTER is ticked from the ticker's largest-counter readback)
             sketch, _overflow = sk_mod.update_sketch(
                 sketch, batch.rows, batch.valid)
             return state, verdicts, sketch

@@ -232,9 +232,10 @@ class CadenceScheduler:
     period is 1.5 × the configured interval plus up to one poll: at the
     defaults ≈ 0.35 s for the tiering tick (1.5 × 200 ms + ≤ 100 ms) and
     ≈ 1.52 s for the telemetry tick. Choosing another factor is a
-    measurement question — it moves the tick's share of the device (31 %
-    of busy time on one chip, 79 % on the four-chip mesh: ledger PR 30
-    ``breakdown``) — so it is a constant here, not a knob.
+    measurement question — it moves how often the telemetry tick's
+    top-K pass and, where proactive demotion is on, the tiering tick's
+    estimate of every row hold the device — so it is a constant here,
+    not a knob.
 
     ``poll()`` is the thread body and is callable directly in tests;
     start/stop are idempotent and ``stop`` is registered with

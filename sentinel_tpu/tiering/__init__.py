@@ -14,8 +14,9 @@ Hot-set discovery runs on-device: a conservative-update count-min
 sketch (:mod:`~sentinel_tpu.tiering.sketch`) is updated from each
 batch's resource rows under the engine lock (dispatch-only, no sync),
 and the tiering ticker thread — modeled on the round-12 telemetry
-ticker — drains estimates asynchronously and proactively demotes
-low-estimate rows so LRU pressure never lands on a hot row.
+ticker — decays it and, with a ``SENTINEL_HOT_ROWS`` target set, reads
+the rows' estimates and proactively demotes low-estimate rows so LRU
+pressure never lands on a hot row.
 
 See docs/OPERATIONS.md "Tiered resource state (round 15)" for the
 operational runbook and the slow-path caveat.

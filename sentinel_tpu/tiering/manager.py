@@ -34,13 +34,14 @@ Hot-set discovery: a conservative-update count-min sketch
 (:mod:`~sentinel_tpu.tiering.sketch`) over the batch's resource rows,
 updated under the engine lock inside the decide paths (dispatch-only).
 The ticker (modeled on the round-12 telemetry ticker: dispatch under
-the lock, land off-lock) decays the sketch, reads every row's estimate,
-and demotes the lowest-estimate unpinned rows whenever the resident
-count exceeds the ``SENTINEL_HOT_ROWS`` target — so LRU pressure from
-new keys lands on sketch-cold rows, never on the measured hot set.
-Proactive demotion requires the Python registry's ``evict_name``; on
-the native C++ table only LRU-overflow demotion runs (documented in
-OPERATIONS.md).
+the lock, land off-lock) decays the sketch and reads back its largest
+counter (the overflow accounting). With a ``SENTINEL_HOT_ROWS`` target
+set it also reads every row's estimate and demotes the lowest-estimate
+unpinned rows whenever the resident count exceeds the target — so LRU
+pressure from new keys lands on sketch-cold rows, never on the
+measured hot set. Proactive demotion requires the Python registry's
+``evict_name``; on the native C++ table only LRU-overflow demotion
+runs, and no estimate is computed (documented in OPERATIONS.md).
 
 Demotion attribution: the registry eviction queue carries row IDS (the
 name is already gone by then), so the manager keeps a shadow
@@ -167,14 +168,14 @@ class TierManager:
         # names whose demote payload is dispatched but not yet landed
         self._pending_land: Dict[str, dict] = {}
         self._land_q: "collections.deque" = collections.deque()
-        self._est_q: "collections.deque" = collections.deque()
+        # one (largest counter, every row's estimate or None) per tick
+        self._tick_q: "collections.deque" = collections.deque()
         # flow-rule reload log: second-window now_idx per reload; a cold
         # entry replays the tail it slept through at promote time
         self._reload_idxs: List[int] = []
         self._sketch = None
         self._sketch_update = None
         self._ticks = 0
-        self._last_est: Optional[np.ndarray] = None
         self._last_tick_ms = int(sentinel.clock.now_ms())
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -277,7 +278,8 @@ class TierManager:
         crosses the overflow cap, so counters stay bounded even on an
         engine that never starts the ticker; the flag is dropped here
         (syncing it would stall the decide) and the overflow COUNTER is
-        ticked host-side from the ticker's estimate readback.
+        ticked host-side from the ticker's readback of the largest
+        counter.
 
         Round 16: this standalone dispatch is the DISABLED/FALLBACK path
         — with ``SENTINEL_SINGLE_DISPATCH`` on, the runtime fuses the
@@ -656,44 +658,56 @@ class TierManager:
     # ---- ticker -------------------------------------------------------
 
     def tick(self) -> bool:
-        """Dispatch one sketch decay + full-table estimate read under
-        the engine lock (no sync); queue the readback."""
+        """Dispatch one sketch decay + the read of its largest counter
+        under the engine lock (no sync) and queue the readback; every
+        row's estimate is dispatched with it only when proactive
+        demotion will rank by it."""
         if not self.enabled or self._closed or self._sketch is None:  # graftlint: disable=LOCK002 -- lock-free early-out; a stale read only skips one tick and the next tick re-reads
             return False
         sn = self._sentinel
+        est = None
         with sn._lock:
-            self._sketch, est = sk.jit_tick_read(sn.spec.rows)(self._sketch)
-        start_host_copy((est,))
+            self._sketch, top = sk.jit_tick_read(self._sketch)
+            # the estimate's one reader is _demote_cold_rows, which needs
+            # a hot-rows target and a registry that evicts by name
+            if (self.hot_rows is not None
+                    and hasattr(sn.resources, "evict_name")):
+                est = sk.jit_estimate_all(self._sketch, n_rows=sn.spec.rows)
+        start_host_copy((top,) if est is None else (top, est))
         if self._obs.enabled:
-            self._obs.counters.add(obs_keys.PIPE_DISPATCH)
+            c = self._obs.counters
+            c.add(obs_keys.PIPE_DISPATCH, 1 if est is None else 2)
+            c.add(obs_keys.TIER_TICK)
+            if est is not None:
+                c.add(obs_keys.TIER_TICK_ESTIMATE)
         with self._lock:
-            self._est_q.append(est)
+            self._tick_q.append((top, est))
             self._ticks += 1
             self._last_tick_ms = int(sn.clock.now_ms())
         return True
 
     def drain(self) -> int:
-        """Land queued demote payloads + sketch estimates OFF the
-        engine lock; handle sketch overflow; run proactive demotion
-        against the hot-rows target."""
+        """Land queued demote payloads + tick readbacks OFF the engine
+        lock; handle sketch overflow; run proactive demotion against
+        the hot-rows target."""
         n = self._land_all()
         with self._lock:
-            ests = list(self._est_q)
-            self._est_q.clear()
-        if ests:
-            est = np.asarray(ests[-1])
-            self._last_est = est
+            ticks = list(self._tick_q)
+            self._tick_q.clear()
+        if ticks:
+            top, est = ticks[-1]
             # update_sketch already halved inline at the cap (decide
-            # paths never sync); an estimate still >= cap/2 means an
+            # paths never sync); a counter still >= cap/2 means an
             # overflow happened since the last tick — tick the counter
             # and halve again to keep headroom
-            if est.size and int(est.max()) >= sk.OVERFLOW_CAP // 2:
+            if int(top) >= sk.OVERFLOW_CAP // 2:
                 with self._sentinel._lock:
                     self._sketch = sk._jit_halve(self._sketch)
                 if self._obs.enabled:
                     self._obs.counters.add(obs_keys.TIER_SKETCH_OVERFLOW)
-            self._demote_cold_rows(est)
-        return n + len(ests)
+            if est is not None:
+                self._demote_cold_rows(np.asarray(est))
+        return n + len(ticks)
 
     def _demote_cold_rows(self, est: np.ndarray) -> None:
         """Evict the lowest-estimate unpinned residents down to the
@@ -869,6 +883,7 @@ class TierManager:
             "demoted": c.get(obs_keys.TIER_DEMOTED),
             "materialized": c.get(obs_keys.TIER_MATERIALIZED),
             "sketch_overflow": c.get(obs_keys.TIER_SKETCH_OVERFLOW),
+            "tick_estimates": c.get(obs_keys.TIER_TICK_ESTIMATE),
             "migrate_p50_ms": None if p50 is None else p50 / 1e6,
             "migrate_p99_ms": None if p99 is None else p99 / 1e6,
         }

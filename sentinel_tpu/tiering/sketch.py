@@ -6,8 +6,9 @@ new minimum estimate, which tightens over-estimation for skewed
 streams). The sketch is tiny — ``SR`` hash rows × ``W = 2**bits``
 buckets of int32 — and is updated from each decide batch's row array
 UNDER the engine lock as a dispatch-only jitted op (no host sync, the
-telemetry-tick discipline); the tiering ticker reads estimates
-asynchronously.
+telemetry-tick discipline); the tiering ticker decays it and reads
+back its largest counter, and every row's estimate only for the
+proactive demotion that ranks by it.
 
 Access shape honesty (the ops/pallas_kernels.py methodology): the
 update is a scatter-max of ``N`` batch elements into an ``[SR, W]``
@@ -33,8 +34,8 @@ the whole table INSIDE the jitted op whenever any estimate crosses
 :data:`OVERFLOW_CAP` (frequencies are relative, halving preserves
 ranking), so counters are bounded — and can never wrap int32 — even on
 an engine that never starts the ticker; the returned overflow flag and
-the ticker's estimate readback only drive the ``tier.sketch_overflow``
-accounting.
+the ticker's readback of the largest counter only drive the
+``tier.sketch_overflow`` accounting.
 """
 
 from __future__ import annotations
@@ -157,8 +158,9 @@ def halve_sketch(counts: jnp.ndarray) -> jnp.ndarray:
 
 def estimate_all(counts: jnp.ndarray, n_rows: int) -> jnp.ndarray:
     """Estimates for every main-table row id [0, n_rows) → int32[R] —
-    the ticker's demotion-ranking read (dispatched under the engine
-    lock, landed off-lock)."""
+    the ranking proactive demotion evicts by, and its only reader: a
+    gather of ``SR x R`` lanes, so the ticker dispatches it only when
+    that demotion can run (:meth:`TierManager.tick`)."""
     items = jnp.arange(n_rows, dtype=jnp.int32)
     return _estimates(counts, _bucket_idx(counts, items))
 
@@ -168,18 +170,23 @@ def jit_update(impl: str = DEFAULT_IMPL):
     return jax.jit(functools.partial(update_sketch, impl=impl))
 
 
-def tick_read(counts: jnp.ndarray, n_rows: int
-              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One ticker read as pure math: decay, then estimate every row
-    (jitted by :func:`jit_tick_read`)."""
+def tick_read(counts: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """One ticker read as pure math: decay, then the table's largest
+    counter → ``(counts', int32 scalar)``.
+
+    That scalar IS the largest estimate :func:`estimate_all` would find
+    over the rows the sketch was fed, without the ``SR x R`` gather: a
+    conservative update writes a bucket as ``est(x) + 1`` and leaves
+    every other bucket of ``x`` at or above that value, and the update's
+    max, this decay and :func:`halve_sketch` are monotone — so whichever
+    row last wrote the table's largest bucket still has all its buckets
+    at that value (tests/test_tiering.py holds the two equal)."""
     counts = decay_sketch(counts)
-    return counts, estimate_all(counts, n_rows)
+    return counts, jnp.max(counts)
 
 
-@functools.lru_cache(maxsize=None)
-def jit_tick_read(n_rows: int):
-    """Fused ticker read: decay then estimate every row (fresh buffers)."""
-    return jax.jit(functools.partial(tick_read, n_rows=n_rows))
+jit_tick_read = jax.jit(tick_read)
+jit_estimate_all = jax.jit(estimate_all, static_argnames="n_rows")
 
 
 _jit_halve = jax.jit(halve_sketch)

@@ -33,6 +33,7 @@ from sentinel_tpu.engine import pipeline
 from sentinel_tpu.parallel.local_shard import (
     MESH_AXIS, state_shardings, verdict_shardings,
 )
+from sentinel_tpu.tiering import sketch as sk
 
 ROWS, BATCH = 16_384, 1_024
 CELL_ROWS, CELL_BATCH = 4 << 20, 65_536     # `mesh-4m.batch-scalar`
@@ -136,6 +137,36 @@ def test_at_the_cells_size_no_chip_holds_a_ring_sized_temp(compiled, step):
                for dims in re.findall(r"\[([\d,]+)\]",
                                       line.split(" while(")[0])]
     assert max(carried, default=0) < CELL_ROWS // 4 * 60 * 8   # a shard's ring
+
+
+def _largest_array(program) -> int:
+    """Elements of the largest array the compiled program names: an
+    operand, a result or a temporary."""
+    return max(int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+               for dims in re.findall(r"\b[a-z]+\d+\[([\d,]*)\]",
+                                      program.as_text()))
+
+
+def test_the_tiering_tick_holds_nothing_of_the_tables_size(topo):
+    """The default tick on the cell's engine — the ``int32[4, 4096]``
+    sketch replicated, as the sketch-fused decide hands it back: decay and
+    one maximum, the same on every chip, so no collective and no array
+    beyond the sketch. Until PR 36 it estimated all 4,194,304 rows on
+    every chip (``SR x R`` gathered lanes, 76 % of the cell's device
+    time) for a reader no default deployment has; the control is that
+    estimate, which proactive demotion still dispatches, and shows here
+    as a result of ``CELL_ROWS`` elements."""
+    mesh = Mesh(np.array(topo.devices), (MESH_AXIS,))
+    sketch = jax.ShapeDtypeStruct(
+        (sk.DEFAULT_ROWS, 1 << sk.DEFAULT_BITS), jnp.int32,
+        sharding=NamedSharding(mesh, P()))
+    tick = sk.jit_tick_read.lower(sketch).compile()
+    assert _largest_collective(tick) == 0
+    assert _largest_array(tick) == sk.DEFAULT_ROWS << sk.DEFAULT_BITS
+    mem = tick.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < CELL_ROWS
+    estimate = sk.jit_estimate_all.lower(sketch, n_rows=CELL_ROWS).compile()
+    assert _largest_array(estimate) >= CELL_ROWS
 
 
 def test_a_one_row_update_of_the_sharded_ring_gathers_it_whole(topo):

@@ -448,24 +448,27 @@ def test_catalog_vector_roundtrip():
     assert ck.vector_counts(longer) == back
 
 
-def test_catalog_is_append_only_with_pr35_keys_last():
+def test_catalog_is_append_only_with_pr36_keys_last():
     """The multihost allgather aggregates CATALOG by POSITION (prefix
     compatibility with older peers), so the catalog may only ever grow at
-    the tail. Pin the newest (PR 35 ``verdict.*`` / ``breaker.*``) keys to
-    the end, with the PR 34 ``tier.materialized``, the PR 33 tier first-sight / inline-landing, PR 32 batch-dedup, PR 26 cluster-server cycle, round-20 resource-histogram, round-17
+    the tail. Pin the newest (PR 36 ``tier.tick`` / ``tier.tick_estimate``)
+    keys to the end, with the PR 35 ``verdict.*`` / ``breaker.*``, the PR 34 ``tier.materialized``, the PR 33 tier first-sight / inline-landing, PR 32 batch-dedup, PR 26 cluster-server cycle, round-20 resource-histogram, round-17
     overload-controller, round-16 single-dispatch, round-15 tiering,
     round-12 telemetry/exporter, round-11 tune, round-10 sortfree and
     round-9 mesh keys immediately above them — an insertion above any
     group (or a re-ordering) would silently mis-attribute every counter
     on a mixed-version fleet."""
-    assert ck.CATALOG[-7:] == (
+    assert ck.CATALOG[-2:] == (ck.TIER_TICK, ck.TIER_TICK_ESTIMATE) == (
+        "tier.tick", "tier.tick_estimate")
+    catalog = ck.CATALOG[:-2]
+    assert catalog[-7:] == (
         ck.VERDICT_PACED, ck.VERDICT_PASSED_NOW, ck.BREAKER_SEEN_OPEN,
         ck.BREAKER_SEEN_CLOSED, ck.BREAKER_OPENED, ck.BREAKER_HALF_OPENED,
         ck.BREAKER_CLOSED) == (
         "verdict.paced", "verdict.passed_now", "breaker.seen_open",
         "breaker.seen_closed", "breaker.opened", "breaker.half_opened",
         "breaker.closed")
-    catalog = ck.CATALOG[:-7]
+    catalog = catalog[:-7]
     assert catalog[-1] == ck.TIER_MATERIALIZED == "tier.materialized"
     assert catalog[-3:-1] == (ck.TIER_FIRST_SIGHT, ck.TIER_LAND_INLINE) \
         == ("tier.first_sight", "tier.land_inline")

@@ -6,6 +6,8 @@ verdicts to an all-resident engine under churn, flow rules, occupy
 bookings, per-origin alt rows, and a mid-run rule reload.
 """
 
+import re
+
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -870,3 +872,149 @@ def test_block_index_holds_under_concurrent_landing_and_promotion():
     assert len(taken) + len(tier) == blocks * per
     assert sum(live for _b, live in tier._blocks.values()) == len(tier)
     assert set(tier._blocks) == {ref >> 32 for ref in tier._index.values()}
+
+
+# ---------------------------------------------------------------------------
+# PR 36: the tick decays the sketch and reads back ONE number, its
+# largest counter; every row's estimate runs only for proactive demotion
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape", [(2, 4, 64), (4, 12, 1 << 14)],
+                         ids=["collides", "default"])
+@pytest.mark.parametrize("impl", sorted(sk.SKETCH_IMPLS))
+def test_largest_counter_is_the_largest_estimate(impl, shape):
+    """What lets the tick drop the gather: after every update (invalid
+    lanes, duplicates within a batch), decay and halving of a seeded
+    stream, the largest estimate over the rows equals the table's
+    largest counter — on a table of 16 buckets a hash row, where 64 rows
+    collide all the time, and on the default ``[4, 4096]``."""
+    sketch_rows, bits, n_rows = shape
+    rng = np.random.default_rng(36 + bits)
+    counts = sk.init_sketch(sketch_rows, bits)
+    update = sk.jit_update(impl)
+    hot = rng.permutation(n_rows)[:24]
+    for step in range(60):
+        op = rng.random()
+        if op < 0.7:
+            # a skewed batch: most lanes from 24 hot rows, so duplicates
+            # are the rule, a fifth of the lanes invalid
+            items = np.where(rng.random(48) < 0.7, rng.choice(hot, 48),
+                             rng.integers(0, n_rows, 48))
+            counts, _ = update(counts, jnp.asarray(items, jnp.int32),
+                               jnp.asarray(rng.random(48) < 0.8))
+        elif op < 0.9:
+            counts = sk.decay_sketch(counts)
+        else:
+            counts = sk.halve_sketch(counts)
+        est = sk.jit_estimate_all(counts, n_rows=n_rows)
+        assert int(est.max()) == int(counts.max()), (impl, shape, step)
+    assert int(counts.max()) > 0
+
+
+def _tick_engine(monkeypatch, registry="native", rows=256):
+    monkeypatch.setenv("SENTINEL_TPU_NATIVE",
+                       "1" if registry == "native" else "0")
+    monkeypatch.setenv("SENTINEL_SKETCH_BITS", "4")    # int32[4, 16]
+    s = Sentinel(load_config(max_resources=rows, max_flow_rules=8,
+                             max_degrade_rules=8, max_authority_rules=8),
+                 clock=ManualClock(start_ms=1_000_000))
+    assert type(s.resources).__name__ == (
+        "NativeRegistry" if registry == "native" else "Registry")
+    return s
+
+
+def test_default_tick_reads_back_one_number(monkeypatch):
+    """On an engine with every default the tick dispatches the decay and
+    a scalar — no estimate, nothing of the table's size in its program."""
+    s = _tick_engine(monkeypatch)
+    monkeypatch.setattr(sk, "jit_estimate_all", None)   # a call would raise
+    try:
+        t = s.tiering
+        names = [f"r{i}" for i in range(40)]
+        for k in range(1, 4):
+            s.entry_batch(names[:13 * k], acquire=[1] * (13 * k))
+        before = np.asarray(t._sketch)
+        assert before.max() > 0
+        assert t.tick()
+        (top, est), = t._tick_q
+        assert est is None and top.shape == () and top.dtype == jnp.int32
+        after = np.asarray(sk.decay_sketch(jnp.asarray(before)))
+        np.testing.assert_array_equal(np.asarray(t._sketch), after)
+        assert int(top) == after.max()
+        snap = t.snapshot()
+        assert snap["ticks"] == 1 and snap["tick_estimates"] == 0
+        assert s.obs.counters.get("tier.tick") == 1
+        assert s.obs.counters.get("tier.tick_estimate") == 0
+        assert t.drain() == 1 and not t._tick_q
+        # no operand, temporary or result beyond the [4, 16] sketch:
+        # R = 264 rows and SR x R lanes would both show
+        text = sk.jit_tick_read.lower(t._sketch).as_text()
+        sizes = [int(np.prod([int(d) for d in dims.split("x") if d] or [1]))
+                 for dims in re.findall(r"tensor<((?:\d+x)*)i\d+>", text)]
+        assert sizes and max(sizes) == before.size < s.spec.rows
+    finally:
+        s.close()
+
+
+@pytest.mark.parametrize("over", [True, False], ids=["at-half-cap", "under"])
+def test_drain_counts_an_overflow_from_the_largest_counter(monkeypatch, over):
+    """A row whose buckets the tick leaves at ``OVERFLOW_CAP // 2`` makes
+    ``drain`` halve the table and tick ``tier.sketch_overflow`` once;
+    one count less and it does neither."""
+    s = _tick_engine(monkeypatch)
+    try:
+        t = s.tiering
+        half = sk.OVERFLOW_CAP // 2
+        near = np.arange(half * 8 // 7 - 8, half * 8 // 7 + 8, dtype=np.int32)
+        decayed = np.asarray(sk.decay_sketch(jnp.asarray(near)))
+        first = int(near[np.argmax(decayed >= half)])
+        planted = first if over else first - 1
+        idx = np.asarray(sk._bucket_idx(t._sketch, jnp.asarray([7])))[:, 0]
+        table = np.zeros(t._sketch.shape, np.int32)
+        table[np.arange(len(idx)), idx] = planted       # row 7's buckets
+        with s._lock:
+            t._sketch = jnp.asarray(table)
+        want = sk.decay_sketch(jnp.asarray(table))
+        assert int(want.max()) == (half if over else half - 1)
+        assert t.poll() == 1
+        if over:
+            want = sk.halve_sketch(want)
+        np.testing.assert_array_equal(np.asarray(t._sketch), np.asarray(want))
+        assert s.obs.counters.get("tier.sketch_overflow") == int(over)
+        assert t.snapshot()["sketch_overflow"] == int(over)
+    finally:
+        s.close()
+
+
+@pytest.mark.parametrize("registry", ["python", "native"])
+def test_estimate_is_dispatched_only_for_proactive_demotion(monkeypatch,
+                                                            registry):
+    """With a hot-rows target the Python registry's tick also reads every
+    row's estimate and ``drain`` demotes the coldest rows by it; the
+    native registry cannot evict by name, so it dispatches none."""
+    s = _tick_engine(monkeypatch, registry, rows=32)
+    try:
+        t = s.tiering
+        names = [f"r{i}" for i in range(6)]
+        for k in range(6, 0, -1):                   # r0 hottest … r5 coldest
+            s.entry_batch(names[:k], acquire=[1] * k)
+        t.hot_rows = len(s.resources) - 2
+        resident = len(s.resources)
+        assert t.tick()
+        (top, est), = t._tick_q
+        python = registry == "python"
+        assert s.obs.counters.get("tier.tick") == 1
+        assert s.obs.counters.get("tier.tick_estimate") == int(python)
+        assert t.snapshot()["tick_estimates"] == int(python)
+        if python:
+            want = sk.estimate_all(t._sketch, s.spec.rows)
+            np.testing.assert_array_equal(np.asarray(est), np.asarray(want))
+            assert int(top) == int(want.max())
+        else:
+            assert est is None
+        t.drain()
+        gone = [n for n in names if s.resources.lookup(n) is None]
+        assert gone == (["r4", "r5"] if python else [])
+        assert len(s.resources) == resident - len(gone)
+    finally:
+        s.close()
