@@ -44,6 +44,11 @@ Shutdown: the batcher registers with ``Sentinel.register_shutdown``, so
 device work settles through ``DispatchPipeline.flush()`` so engine
 bookkeeping stays consistent.
 
+Collector: from its construction to its close a batcher holds the
+set-up heap out of the collector (frontend/heap.py: the first live
+batcher of the process freezes it, the last to close thaws it), so a
+full collection on the served path walks only what serving allocated.
+
 Env knobs (read at construction; constructor kwargs override):
 
 * ``SENTINEL_FRONTEND_BATCH`` — B_max, default 256;
@@ -85,6 +90,7 @@ from typing import Dict, List, NamedTuple, Optional
 import numpy as np
 
 from sentinel_tpu.core import errors as err_mod
+from sentinel_tpu.frontend import heap
 from sentinel_tpu.obs import counters as obs_keys
 from sentinel_tpu.serving import DispatchPipeline
 
@@ -346,6 +352,9 @@ class AdaptiveBatcher:
         reg = getattr(sentinel, "register_shutdown", None)
         if reg is not None:
             reg(self)
+        # before any request: the one full collection must not stall a
+        # loop that is already serving
+        heap.serve(sentinel.obs)
 
     # ------------------------------------------------------------------
     # client API
@@ -675,7 +684,10 @@ class AdaptiveBatcher:
                 loop.call_soon_threadsafe(self._shutdown_on_loop)
         # settle every dispatched batch (the settle task is dying with
         # the loop) — blocking, but terminal; bookkeeping must land
-        self._pipe.flush()
+        try:
+            self._pipe.flush()
+        finally:
+            heap.release(self._s.obs)
 
     def _shutdown_on_loop(self) -> None:
         for task in (self._run_task, self._settle_task):

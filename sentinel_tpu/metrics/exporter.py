@@ -245,6 +245,12 @@ class SentinelCollector:
             "and tick-to-tick changes by the state entered (opened / "
             "half_opened / closed); an arc faster than a tick is missed",
             labels=["event"])
+        gc_pause = CounterMetricFamily(
+            f"{ns}_gc_total",
+            "Full (generation 2) collections while a front end lives "
+            "(gen2) and their summed pause in microseconds (gen2_us) "
+            "(frontend/heap.py)",
+            labels=["event"])
         if not describe_only and obs is not None and obs.enabled:
             from sentinel_tpu.obs import counters as ck
             counts = obs.counters.snapshot()
@@ -348,6 +354,9 @@ class SentinelCollector:
                             (ck.BREAKER_HALF_OPENED, "half_opened"),
                             (ck.BREAKER_CLOSED, "closed")):
                 breaker.add_metric([ev], counts.get(key, 0))
+            for key, ev in ((ck.GC_GEN2, "gen2"),
+                            (ck.GC_GEN2_US, "gen2_us")):
+                gc_pause.add_metric([ev], counts.get(key, 0))
             # bounded by construction: at most telemetry.k ≤ MAX_K labels
             # (×3 quantile labels for res_rt — still top-K-bounded)
             telemetry = getattr(self.sentinel, "telemetry", None)
@@ -364,7 +373,7 @@ class SentinelCollector:
                     blocks, occupy, pipeline, frontend, fe_flush, wraps,
                     flight_pinned, flight_trig, sf_ovf, tune,
                     res_qps, res_rt, telem, label_ovf, tier, control,
-                    cluster_srv, intern, verdict, breaker)
+                    cluster_srv, intern, verdict, breaker, gc_pause)
 
     def collect(self):
         ns = self.namespace

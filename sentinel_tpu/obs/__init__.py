@@ -97,8 +97,9 @@ class _Phase:
     """One open :meth:`RuntimeObs.phase`: the same enter and exit write
     the interval to the profiler's trace and to the span recorder, so
     the two records of a phase can never disagree about its extent.
-    ``n`` and ``note`` may be set while it is open (a route or a count
-    known only at the end)."""
+    ``n``, ``note`` and ``trace`` may be set while it is open (a route,
+    a count or the chain it belongs to, known only at the end; trace 0
+    records no span)."""
 
     __slots__ = ("spans", "name", "n", "trace", "note", "id", "parent",
                  "_obs", "_t0", "_prev", "_ann")

@@ -83,6 +83,8 @@ dispatch stays within the 2% observability budget (benchmarks/ci_gate.py
 * ``exporter.label_overflow`` — Prometheus label-cardinality guard
   (metrics/exporter.py): resource-labeled samples dropped at the
   per-family label cap.
+* ``gc.gen2`` / ``gc.gen2_us`` — full collections while a front end
+  lives, and their summed pause (frontend/heap.py).
 
 :data:`CATALOG` is the fixed, ordered multihost-aggregatable key set:
 every process packs its snapshot into one int64 vector
@@ -313,6 +315,17 @@ CLUSTER_SERVER_QUEUE_WAIT_US = "cluster.server.queue_wait_us"
 INTERN_NAMES = "intern.names"
 INTERN_DISTINCT = "intern.distinct"
 
+# The collector's full (generation 2) collections while a front end
+# lives (frontend/heap.py): ``gc.gen2`` counts them and
+# ``gc.gen2_us`` sums their pauses in microseconds, from a
+# ``gc.callbacks`` hook. A full collection holds the interpreter lock
+# for its whole walk, so every thread of the served path waits it out;
+# the front end freezes the set-up heap so the walk covers only what
+# serving allocated. Mean pause = ``gen2_us / gen2``. Exported as
+# ``sentinel_gc_total{event=...}``.
+GC_GEN2 = "gc.gen2"
+GC_GEN2_US = "gc.gen2_us"
+
 #: Fixed aggregation catalog (order is the wire format of the multihost
 #: counter vector — append only, never reorder).
 CATALOG = (
@@ -353,6 +366,7 @@ CATALOG = (
     BREAKER_SEEN_OPEN, BREAKER_SEEN_CLOSED,
     BREAKER_OPENED, BREAKER_HALF_OPENED, BREAKER_CLOSED,
     TIER_TICK, TIER_TICK_ESTIMATE,
+    GC_GEN2, GC_GEN2_US,
 )
 
 
@@ -361,12 +375,14 @@ class CounterSet:
 
     One uncontended ``lock + dict.get + add`` per increment; increments
     happen once per batch on the dispatch path, so the cost is amortized
-    over thousands of events."""
+    over thousands of events. The lock is re-entrant: the collector's
+    hook (frontend/heap.py) adds from whatever thread a collection ran
+    in, between any two bytecodes, also of a thread inside :meth:`add`."""
 
     __slots__ = ("_lock", "_c")
 
     def __init__(self) -> None:
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._c: Dict[str, int] = {}
 
     def add(self, key: str, n: int = 1) -> None:

@@ -25,6 +25,11 @@ A trigger pins the offending chain(s): the causal closure
 (``SpanRecorder.causal`` — spans + fan-in/fan-out links) of the
 triggering trace, or of the most recent traces inside the retro window
 (``SENTINEL_FLIGHT_WINDOW_MS``) when the trigger has no specific root.
+A pin reads each span and link ring once (``SpanRecorder.view``), walks
+the window's rings back only as far as the window reaches, and builds
+dicts for the chains it keeps alone, so it costs what it keeps and not
+what the rings hold; it is the phase ``flight.pin`` (``n`` = spans
+pinned, note = the trigger kind).
 Pinned records buffer in memory (:meth:`snapshot` — the transport /
 dashboard view) and persist through the same
 :class:`~sentinel_tpu.metrics.writer.MetricWriter` rotation machinery as
@@ -36,7 +41,7 @@ the trigger code (:data:`TRIGGER_CODES`), ``rt`` the overrun/worst ms.
 
 Triggers are rate-limited per kind to one pin per window so a trigger
 storm (every request of a flash crowd missing its deadline) costs one
-snapshot, not thousands. Env knobs (construction time; kwargs override):
+pin, not thousands. Env knobs (construction time; kwargs override):
 ``SENTINEL_FLIGHT_DISABLE`` — off entirely;
 ``SENTINEL_FLIGHT_WINDOW_MS`` — retro window AND per-kind re-trigger
 gap, default 2000; ``SENTINEL_FLIGHT_P99_MS`` — p99 budget, default 0 =
@@ -53,6 +58,7 @@ import threading
 from typing import Dict, List, Optional
 
 from sentinel_tpu.obs import counters as obs_keys
+from sentinel_tpu.obs.spans import link_dict, span_dict
 
 FLIGHT_DISABLE_ENV = "SENTINEL_FLIGHT_DISABLE"
 FLIGHT_WINDOW_ENV = "SENTINEL_FLIGHT_WINDOW_MS"
@@ -147,8 +153,7 @@ class FlightRecorder:
         already cooldown-limited upstream and every one must leave a pin."""
         if not self.active or self._closed:
             return False
-        spans = self._obs.spans
-        now_ns = spans.now_ns()
+        now_ns = self._obs.spans.now_ns()
         gap_ns = int(self.window_ms * 1e6)
         with self._lock:
             if not force:
@@ -156,7 +161,24 @@ class FlightRecorder:
                 if last is not None and now_ns - last < gap_ns:
                     return False
             self._last_pin_ns[kind] = now_ns
-        roots = [int(root)] if root else self._window_roots(now_ns)
+        # the pin is a phase of its own: how often it engages and what it
+        # costs, beside the work it interrupts. Its span files under the
+        # first chain it pins (none when it pins nothing), so a pin never
+        # starts a trace that a later rootless pin would take for traffic
+        with self._obs.phase("flight.pin", trace=int(root),
+                             note=kind) as phase:
+            return self._pin(phase, kind, root, note, worst_ms, now_ns)
+
+    def _pin(self, phase, kind: str, root: int, note: str,
+             worst_ms: float, now_ns: int) -> bool:
+        view = self._obs.spans.view()
+        if root:
+            roots = [int(root)]
+        else:   # the most recent traces inside the retro window
+            cutoff = now_ns - int(self.window_ms * 1e6)
+            roots = sorted(view.traces_since(cutoff),
+                           reverse=True)[:MAX_WINDOW_ROOTS]
+        phase.trace = 0
         if not roots:
             return False
         counters = self._obs.counters
@@ -169,16 +191,15 @@ class FlightRecorder:
             except Exception:   # telemetry must not break a pin
                 hot = []
         pinned = 0
-        for r in roots:
-            causal = spans.causal(r)
-            if not causal["spans"]:
+        for r, chain, links in view.chains(roots):
+            if not chain:
                 continue
             rec = {
                 "ts_ms": now_ms, "kind": kind, "root": r, "note": note,
                 "worst_ms": round(float(worst_ms), 3),
-                "spans": causal["spans"][:MAX_CHAIN_SPANS],
-                "links": causal["links"],
-                "truncated": len(causal["spans"]) > MAX_CHAIN_SPANS,
+                "spans": [span_dict(s) for s in chain[:MAX_CHAIN_SPANS]],
+                "links": [link_dict(e) for e in links],
+                "truncated": len(chain) > MAX_CHAIN_SPANS,
                 "hot": hot,
             }
             with self._lock:
@@ -187,6 +208,8 @@ class FlightRecorder:
                 if len(self._pending) > PENDING_CAP:
                     del self._pending[:len(self._pending) - PENDING_CAP]
             pinned += 1
+            phase.n += len(rec["spans"])
+            phase.trace = phase.trace or r
         if pinned:
             counters.add(obs_keys.FLIGHT_PINNED, pinned)
         return pinned > 0
@@ -218,14 +241,6 @@ class FlightRecorder:
             self._block_n = -(1 << 30)   # once per second; rate limiter too
             self.trigger("block_burst",
                          note=f"blocks_1s>={self.block_burst}")
-
-    def _window_roots(self, now_ns: int) -> List[int]:
-        """Most recent trace ids with a span starting inside the retro
-        window (rootless triggers: p99 breach, block burst)."""
-        cutoff = now_ns - int(self.window_ms * 1e6)
-        ids = {s["trace"] for s in self._obs.spans.snapshot()
-               if s["start_ns"] >= cutoff}
-        return sorted(ids, reverse=True)[:MAX_WINDOW_ROOTS]
 
     def _obs_now_ms(self) -> float:
         clock = getattr(self._obs, "clock", None)

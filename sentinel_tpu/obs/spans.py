@@ -216,10 +216,7 @@ class SpanRecorder:
         spans.sort(key=lambda s: (s[0], s[2]))
         if limit is not None and len(spans) > limit:
             spans = spans[-limit:]
-        return [{"trace": s[0], "name": s[1], "start_ns": s[2],
-                 "end_ns": s[3], "dur_ns": s[3] - s[2], "thread": s[4],
-                 "n": s[5], "note": s[6], "id": s[7], "parent": s[8]}
-                for s in spans]
+        return [span_dict(s) for s in spans]
 
     def links_snapshot(self, limit: Optional[int] = None) -> List[Dict]:
         """All recorded causal edges, ts-ordered."""
@@ -227,8 +224,7 @@ class SpanRecorder:
         links.sort(key=lambda e: e[3])
         if limit is not None and len(links) > limit:
             links = links[-limit:]
-        return [{"src": e[0], "dst": e[1], "kind": e[2], "ts_ns": e[3]}
-                for e in links]
+        return [link_dict(e) for e in links]
 
     def _raw_links(self) -> list:
         with self._rings_lock:
@@ -238,32 +234,29 @@ class SpanRecorder:
             links.extend(list(ring.buf))   # atomic-enough copy (see module)
         return links
 
+    def view(self) -> "RingView":
+        """One read of every span ring and every link ring: what a
+        flight-recorder pin and :meth:`causal` compute from."""
+        with self._rings_lock:
+            rings = list(self._rings)
+        # the write index before the entries: a span appended between the
+        # two reads is the tear-by-one of the module docstring, never a
+        # misordered ring
+        return RingView([(ring.idx, list(ring.buf)) for ring in rings],
+                        self._raw_links())
+
     def causal(self, trace_id: int) -> Dict:
         """The causal closure of one trace: ``{"root", "spans", "links"}``.
 
-        Follows recorded edges forward from ``trace_id`` to a fixpoint.
-        ``verdict`` (fan-out) edges expand only from the root itself:
-        from a request root, the flush edge reaches the batch and the
-        batch's verdict edge BACK to this request is kept (both endpoints
-        are in the closure) while sibling requests stay out; from a batch
-        root, the fan-out to every request it settled is the point."""
-        raw = self._raw_links()
-        ids = {int(trace_id)}
-        changed = True
-        while changed:
-            changed = False
-            for src, dst, kind, _ts in raw:
-                if (src in ids and dst not in ids
-                        and (kind != LINK_VERDICT or src == trace_id)):
-                    ids.add(dst)
-                    changed = True
-        spans = self.snapshot()
-        spans = [s for s in spans if s["trace"] in ids]
-        spans.sort(key=lambda s: s["start_ns"])
-        links = [{"src": e[0], "dst": e[1], "kind": e[2], "ts_ns": e[3]}
-                 for e in sorted(raw, key=lambda e: e[3])
-                 if e[0] in ids and e[1] in ids]
-        return {"root": int(trace_id), "spans": spans, "links": links}
+        Follows recorded edges forward from ``trace_id``. ``verdict``
+        (fan-out) edges expand only from the root itself: from a request
+        root, the flush edge reaches the batch and the batch's verdict
+        edge BACK to this request is kept (both endpoints are in the
+        closure) while sibling requests stay out; from a batch root, the
+        fan-out to every request it settled is the point."""
+        ((root, spans, links),) = self.view().chains([int(trace_id)])
+        return {"root": root, "spans": [span_dict(s) for s in spans],
+                "links": [link_dict(e) for e in links]}
 
     def chain(self, trace_id: int) -> List[Dict]:
         """All spans reachable from one trace id, start-ordered: the
@@ -301,3 +294,87 @@ class SpanRecorder:
         call from Sentinel.close() repeatedly)."""
         self.enabled = False
         self.clear()
+
+
+def span_dict(s: tuple) -> Dict:
+    """One ring entry as the span schema's dict (module docstring)."""
+    return {"trace": s[0], "name": s[1], "start_ns": s[2], "end_ns": s[3],
+            "dur_ns": s[3] - s[2], "thread": s[4], "n": s[5],
+            "note": s[6], "id": s[7], "parent": s[8]}
+
+
+def link_dict(e: tuple) -> Dict:
+    return {"src": e[0], "dst": e[1], "kind": e[2], "ts_ns": e[3]}
+
+
+class RingView:
+    """One read of a recorder's rings (:meth:`SpanRecorder.view`). What
+    is computed from it costs what it keeps: entries stay raw tuples
+    until they are known to belong to a chain, and only a chain is
+    sorted."""
+
+    __slots__ = ("rings", "links", "_by_src")
+
+    def __init__(self, rings: List[tuple], links: List[tuple]) -> None:
+        self.rings = rings          # (write index, entries) per span ring
+        self.links = links          # every link, in ring order
+        self._by_src: Optional[Dict[int, list]] = None
+
+    def traces_since(self, cutoff_ns: int) -> set:
+        """Trace ids with a span that started at or after ``cutoff_ns``.
+
+        A ring holds its spans in the order they ended (each site records
+        a span when it ends, from its own thread, on a clock that does
+        not go back), and a span starts no later than it ends. So each
+        ring is walked back from its newest entry and left at the first
+        that ended before the cutoff: every older one started before it
+        too."""
+        ids = set()
+        for idx, buf in self.rings:
+            n = len(buf)
+            if not n:
+                continue
+            head = idx % n if idx >= n else 0     # oldest entry's slot
+            for s in itertools.chain(reversed(buf[:head]),
+                                     reversed(buf[head:])):
+                if s[3] < cutoff_ns:
+                    break
+                if s[2] >= cutoff_ns:
+                    ids.add(s[0])
+        return ids
+
+    def closure(self, root: int) -> set:
+        """Trace ids reachable from ``root`` over the links, ``verdict``
+        edges only out of the root (:meth:`SpanRecorder.causal`): one
+        breadth-first walk over the links indexed by source."""
+        by_src = self._by_src
+        if by_src is None:
+            by_src = self._by_src = {}
+            for src, dst, kind, _ts_ns in self.links:
+                by_src.setdefault(src, []).append((dst, kind))
+        ids = {root}
+        frontier = [root]
+        for src in frontier:            # grows while it is walked
+            for dst, kind in by_src.get(src, ()):
+                if dst not in ids and (kind != LINK_VERDICT or src == root):
+                    ids.add(dst)
+                    frontier.append(dst)
+        return ids
+
+    def chains(self, roots: List[int]) -> List[tuple]:
+        """``(root, spans, links)`` per root, raw tuples: the closure's
+        spans by start (then trace id, then ring order), its links by
+        time. One pass over the rings serves every root."""
+        closures = [(r, self.closure(r)) for r in roots]
+        every = set().union(*(ids for _, ids in closures))
+        found = [s for _, buf in self.rings for s in buf if s[0] in every]
+        links = sorted((e for e in self.links
+                        if e[0] in every and e[1] in every),
+                       key=lambda e: e[3])
+        out = []
+        for r, ids in closures:
+            spans = [s for s in found if s[0] in ids]
+            spans.sort(key=lambda s: (s[2], s[0]))
+            out.append((r, spans, [e for e in links
+                                   if e[0] in ids and e[1] in ids]))
+        return out

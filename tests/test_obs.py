@@ -451,16 +451,19 @@ def test_catalog_vector_roundtrip():
 def test_catalog_is_append_only_with_pr36_keys_last():
     """The multihost allgather aggregates CATALOG by POSITION (prefix
     compatibility with older peers), so the catalog may only ever grow at
-    the tail. Pin the newest (PR 36 ``tier.tick`` / ``tier.tick_estimate``)
+    the tail. Pin the newest (``gc.gen2`` / ``gc.gen2_us``, then ``tier.tick`` / ``tier.tick_estimate``)
     keys to the end, with the PR 35 ``verdict.*`` / ``breaker.*``, the PR 34 ``tier.materialized``, the PR 33 tier first-sight / inline-landing, PR 32 batch-dedup, PR 26 cluster-server cycle, round-20 resource-histogram, round-17
     overload-controller, round-16 single-dispatch, round-15 tiering,
     round-12 telemetry/exporter, round-11 tune, round-10 sortfree and
     round-9 mesh keys immediately above them — an insertion above any
     group (or a re-ordering) would silently mis-attribute every counter
     on a mixed-version fleet."""
-    assert ck.CATALOG[-2:] == (ck.TIER_TICK, ck.TIER_TICK_ESTIMATE) == (
-        "tier.tick", "tier.tick_estimate")
+    assert ck.CATALOG[-2:] == (ck.GC_GEN2, ck.GC_GEN2_US) == (
+        "gc.gen2", "gc.gen2_us")
     catalog = ck.CATALOG[:-2]
+    assert catalog[-2:] == (ck.TIER_TICK, ck.TIER_TICK_ESTIMATE) == (
+        "tier.tick", "tier.tick_estimate")
+    catalog = catalog[:-2]
     assert catalog[-7:] == (
         ck.VERDICT_PACED, ck.VERDICT_PASSED_NOW, ck.BREAKER_SEEN_OPEN,
         ck.BREAKER_SEEN_CLOSED, ck.BREAKER_OPENED, ck.BREAKER_HALF_OPENED,

@@ -23,6 +23,7 @@ All quick-tier, CPU; virtual-time policy values ride the ManualClock.
 
 import asyncio
 import json
+import threading
 
 import numpy as np
 import pytest
@@ -33,7 +34,9 @@ from sentinel_tpu.frontend.batcher import AdaptiveBatcher
 from sentinel_tpu.obs import RuntimeObs
 from sentinel_tpu.obs import counters as ck
 from sentinel_tpu.obs import traceexport
-from sentinel_tpu.obs.flight import FlightRecorder, load_pinned
+from sentinel_tpu.obs.flight import (MAX_CHAIN_SPANS, MAX_WINDOW_ROOTS,
+                                     RECENT_CAP, FlightRecorder,
+                                     load_pinned)
 from sentinel_tpu.obs.spans import LINK_FLUSH, LINK_VERDICT, SpanRecorder
 
 pytestmark = pytest.mark.quick
@@ -216,6 +219,186 @@ def test_flight_rootless_trigger_pins_window_and_payload(clk):
     # payload() carries the metadata view for the dashboard
     meta = obs.payload()["flight"]
     assert meta["active"] and meta["pinned"][-1]["kind"] == "block_burst"
+    obs.close()
+
+
+# ---------------------------------------------------------------------------
+# a pin costs what it keeps, and keeps what the snapshot-then-filter
+# closure kept: the reference below is that closure, as it was written
+# ---------------------------------------------------------------------------
+
+def _ref_snapshot(rec):
+    with rec._rings_lock:
+        rings = list(rec._rings)
+    spans = []
+    for ring in rings:
+        spans.extend(list(ring.buf))
+    spans.sort(key=lambda s: (s[0], s[2]))
+    return [{"trace": s[0], "name": s[1], "start_ns": s[2],
+             "end_ns": s[3], "dur_ns": s[3] - s[2], "thread": s[4],
+             "n": s[5], "note": s[6], "id": s[7], "parent": s[8]}
+            for s in spans]
+
+
+def _ref_causal(rec, trace_id):
+    with rec._rings_lock:
+        link_rings = list(rec._link_rings)
+    raw = []
+    for ring in link_rings:
+        raw.extend(list(ring.buf))
+    ids = {int(trace_id)}
+    changed = True
+    while changed:
+        changed = False
+        for src, dst, kind, _ts in raw:
+            if (src in ids and dst not in ids
+                    and (kind != LINK_VERDICT or src == trace_id)):
+                ids.add(dst)
+                changed = True
+    spans = [s for s in _ref_snapshot(rec) if s["trace"] in ids]
+    spans.sort(key=lambda s: s["start_ns"])
+    links = [{"src": e[0], "dst": e[1], "kind": e[2], "ts_ns": e[3]}
+             for e in sorted(raw, key=lambda e: e[3])
+             if e[0] in ids and e[1] in ids]
+    return {"root": int(trace_id), "spans": spans, "links": links}
+
+
+def _ref_pins(obs, root):
+    """What one trigger pinned, by the reference: (root, spans, links,
+    truncated) per pinned chain."""
+    if root:
+        roots = [root]
+    else:
+        cutoff = obs.spans.now_ns() - int(obs.flight.window_ms * 1e6)
+        roots = sorted({s["trace"] for s in _ref_snapshot(obs.spans)
+                        if s["start_ns"] >= cutoff},
+                       reverse=True)[:MAX_WINDOW_ROOTS]
+    out = []
+    for r in roots:
+        c = _ref_causal(obs.spans, r)
+        if c["spans"]:
+            out.append((r, c["spans"][:MAX_CHAIN_SPANS], c["links"],
+                        len(c["spans"]) > MAX_CHAIN_SPANS))
+    return out
+
+
+def _run_in_thread(fn):
+    t = threading.Thread(target=fn)
+    t.start()
+    t.join(timeout=60)
+    assert not t.is_alive()
+
+
+def _busy_recorder(clk):
+    """A recorder as a serving process leaves it: thousands of unrelated
+    spans over six threads, a batch ring and a request ring that wrapped,
+    links both ways (fan-in, fan-out, a cycle), one chain longer than a
+    pin keeps, and traces that straddle the retro window's edge."""
+    obs = RuntimeObs(clock=clk)
+    rec = obs.spans
+    now = rec.now_ns()
+    ms = 1_000_000
+
+    def traffic(k):
+        def body():
+            for i in range(1200):
+                end = now - 3000 * ms + i * 2 * ms + k
+                rec.record(rec.mint(), f"work.{k}", end - 4 * ms, end, n=i)
+        return body
+
+    for k in range(6):
+        _run_in_thread(traffic(k))
+
+    # one thread whose batch ring (8192) and request ring (2048) wrap;
+    # equal starts across traces test the (start, trace) order
+    def wrapping():
+        for i in range(9000):
+            end = now - 2900 * ms + i * 320_000
+            rec.record(rec.mint(), "wrap.batch", end - ms, end)
+        for i in range(2500):
+            end = now - 2500 * ms + i * ms
+            rec.record(rec.mint(), "wrap.req", end - 3 * ms, end,
+                       request=True)
+    _run_in_thread(wrapping)
+
+    # the chains, minted last so that they are the window's newest
+    def chains():
+        t = now - 1500 * ms
+        for r in (req_a, req_b):
+            rec.record(r, "frontend.enqueue", t, t + ms, request=True)
+            rec.link(r, batch, LINK_FLUSH)
+        for i in range(250):            # longer than MAX_CHAIN_SPANS
+            rec.record(batch, f"step.{i % 7}", t + ms, t + 2 * ms + i)
+        rec.link(batch, echo, LINK_FLUSH)       # onward, and back: a cycle
+        rec.link(echo, batch, LINK_FLUSH)
+        rec.record(echo, "echo", t + 2 * ms, t + 3 * ms)
+        for r in (req_a, req_b):
+            rec.link(batch, r, LINK_VERDICT)
+            rec.record(r, "frontend.settle", t, t + 4 * ms, request=True)
+        rec.link(batch2, req_a, LINK_VERDICT)   # a second fan-out
+        rec.record(batch2, "frontend.flush", t + 5 * ms, t + 6 * ms)
+        # the newest trace of all started before the window: no root
+        rec.record(straddle, "straddle", now - 2001 * ms, now)
+
+    req_a, req_b, batch, batch2, echo, straddle = (rec.mint()
+                                                   for _ in range(6))
+    _run_in_thread(chains)
+    return obs, (req_a, req_b, batch, batch2, echo, straddle)
+
+
+def _pinned_since(obs, k):
+    recs = obs.flight.snapshot(limit=RECENT_CAP, full=True)
+    return [(r["root"], r["spans"], r["links"], r["truncated"])
+            for r in recs[len(recs) - k:]] if k else []
+
+
+def test_pins_equal_the_snapshot_closure_rooted_and_rootless(clk):
+    obs, (req_a, req_b, batch, batch2, echo, straddle) = \
+        _busy_recorder(clk)
+    assert obs.counters.get(ck.SPAN_RING_WRAP) > 0     # rings did wrap
+    cutoff = obs.spans.now_ns() - int(obs.flight.window_ms * 1e6)
+    assert obs.spans.view().traces_since(cutoff) == {
+        s["trace"] for s in _ref_snapshot(obs.spans)
+        if s["start_ns"] >= cutoff}
+    want = _ref_pins(obs, 0)
+    # the newest traces with a span that STARTED in the window: not
+    # `straddle`, whose span began before it
+    assert [w[0] for w in want] == [echo, batch2, batch, req_b]
+    assert obs.flight.trigger("block_burst", note="blocks_1s>=512")
+    assert _pinned_since(obs, len(want)) == want
+    for root in (req_a, batch, batch2, echo, req_b, 10 ** 9):
+        assert obs.spans.causal(root) == _ref_causal(obs.spans, root)
+        want = _ref_pins(obs, root)
+        got = obs.flight.trigger("deadline_miss", root=root, force=True)
+        assert got == bool(want)
+        assert _pinned_since(obs, len(want)) == want
+    assert _ref_pins(obs, batch)[0][3]                  # truncated
+    obs.close()
+
+
+def test_a_pin_never_takes_a_snapshot(clk, monkeypatch):
+    obs, (req_a, *_rest) = _busy_recorder(clk)
+
+    def boom(*a, **k):
+        raise AssertionError("a pin walked every span of every ring")
+
+    monkeypatch.setattr(SpanRecorder, "snapshot", boom)
+    assert obs.flight.trigger("deadline_miss", root=req_a)
+    assert obs.flight.trigger("block_burst")
+    assert obs.counters.get(ck.FLIGHT_PINNED) == 1 + MAX_WINDOW_ROOTS
+    obs.close()
+
+
+def test_the_pin_is_a_phase_that_counts_what_it_pinned(clk):
+    obs, (req_a, req_b, batch, *_rest) = _busy_recorder(clk)
+    assert obs.flight.trigger("deadline_miss", root=batch)
+    (rec,) = _pinned_since(obs, 1)
+    (pin,) = [s for s in obs.spans.snapshot() if s["name"] == "flight.pin"]
+    assert pin["n"] == len(rec[1]) == MAX_CHAIN_SPANS
+    assert pin["note"] == "deadline_miss" and pin["trace"] == batch
+    # rate-limited: no second pin, no second phase
+    assert not obs.flight.trigger("deadline_miss", root=req_a)
+    assert [s["name"] for s in obs.spans.snapshot()].count("flight.pin") == 1
     obs.close()
 
 
